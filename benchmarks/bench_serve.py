@@ -8,9 +8,10 @@ decorated phase — `serving.metrics.PhaseLedger`, with
 profile):
 
   * ``first_request`` — wall seconds from process-cold service creation to
-    the first answered request (trace + compile + factorize + solve). With
-    ``SPIN_COMPILE_CACHE`` pointing at a persistent XLA compilation cache,
-    a SECOND process run of this benchmark must show this number collapse
+    the first answered request (trace + compile + factorize + solve). The
+    service keeps a persistent XLA compilation cache
+    (``$JAX_COMPILATION_CACHE_DIR`` or `<checkout>/.jax_cache`), so a
+    SECOND process run of this benchmark must show this number collapse
     to ~zero retrace — that delta IS the warm-restart story, and CI runs
     the benchmark twice to assert it;
   * ``solve_recursion`` — requests/sec of the exact coalesced-`spin_solve`
@@ -86,7 +87,7 @@ def run(emit, *, n: int = N, requests: int = REQUESTS, slots: int = SLOTS,
 
     # -- cold start → first answer (the number a warm compile cache cuts) ---
     with ledger.profile("first_request"):
-        svc = SpinService(slots=slots)       # honors $SPIN_COMPILE_CACHE
+        svc = SpinService(slots=slots)       # persistent compile cache on
         st = svc.add_matrix("bench", a)
         first = svc.solve("bench", panels[0])
         svc.run_until_done()

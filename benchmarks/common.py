@@ -58,6 +58,12 @@ def csv_row(name: str, seconds: float, derived: str = "") -> str:
 
 
 def emit_header(emit=print) -> None:
+    """Open a standalone run: turn on the persistent compilation cache
+    ($JAX_COMPILATION_CACHE_DIR or the in-checkout default, see
+    `repro.compat.enable_compilation_cache`) and print the CSV header."""
+    from repro.compat import enable_compilation_cache
+
+    enable_compilation_cache()
     emit(CSV_HEADER)
 
 

@@ -15,10 +15,13 @@ container's physical cores, so measured speedup reflects scheduling
 overhead, not parallel speedup; the paper's 3-node cluster genuinely
 parallelizes.
 
-Standalone usage (the CI distributed job):
+It is a CPU sweep by construction: each device count runs in a child
+process that imports JAX on forced host devices. On a host whose default
+backend is an accelerator it refuses to run — children would fight the
+parent for the chip. Standalone usage (the CI distributed job):
 
-    PYTHONPATH=src python -m benchmarks.fig5_scaling --reduced \
-        --json BENCH_scaling.json
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.fig5_scaling \
+        --reduced --json BENCH_scaling.json
 """
 
 from __future__ import annotations
@@ -81,6 +84,7 @@ def _run_child(n: int, bs: int, d: int) -> dict[str, float]:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={d}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(repo, "src")
     code = _CHILD.format(n=n, bs=bs, d=d)
     res = subprocess.run([sys.executable, "-c", code], env=env,
@@ -96,8 +100,21 @@ def _run_child(n: int, bs: int, d: int) -> dict[str, float]:
     return out
 
 
+def _require_cpu_backend() -> None:
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise SystemExit(
+            f"fig5_scaling is a forced-host-device CPU sweep that starts a "
+            f"JAX child per device count; the default backend here is "
+            f"{backend!r}, whose chip the children cannot share. Run it "
+            f"with JAX_PLATFORMS=cpu.")
+
+
 def run(emit, *, n: int = N, grid: int = B, devices=DEVICES,
         json_path: str | None = None) -> dict:
+    _require_cpu_backend()
     measured: dict[str, dict[int, float]] = {"dense": {}, "sharded": {}}
     errors: dict[int, str] = {}
     for d in devices:

@@ -1,11 +1,12 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=16")
-
-# Distributed SPIN on a 4x4 device mesh (fake host devices on CPU; the same
-# code runs on a real TPU mesh) with the double-buffered ring SUMMA engine,
-# plus the TPU roofline projection for a production-scale inversion.
+# Distributed SPIN on a mesh built from the devices present (one chip, a
+# four-chip 2x2 host, or fake CPU host devices), plus the TPU roofline
+# projection for a production-scale inversion.
 #
 #     PYTHONPATH=src python examples/invert_at_scale.py --n 2048 --block 128
+#
+# On CPU, give it several devices to shard over:
+#     XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
+#         PYTHONPATH=src python examples/invert_at_scale.py --sharded
 
 import argparse
 import time
@@ -14,9 +15,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import AxisType, make_mesh, set_mesh
+from repro.compat import set_mesh
 from repro.core import (BlockMatrix, multiply_engine, spin_inverse, testing)
 from repro.core.costmodel import tpu_roofline_cost
+from repro.launch.mesh import make_worker_mesh
 from repro.parallel import ShardedBlockMatrix, inverse_program
 from repro.planner import get_plan
 
@@ -39,13 +41,11 @@ def main() -> None:
                          "mesh, no inter-level gathers")
     args = ap.parse_args()
 
-    mesh = make_mesh((4, 4), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2,
-                     devices=jax.devices()[:16])
-    # Plan INSIDE the mesh context: the signature then carries both the 16
-    # (fake) devices — so the candidate space includes the allgather/ring
-    # SUMMA engines — and the mesh topology, so the cached plan is keyed to
-    # this (4, 4) mesh and never recalled for a different one.
+    mesh = make_worker_mesh()        # the squarest 2-axis mesh present
+    # Plan INSIDE the mesh context: the signature then carries both the
+    # device count — so with several devices the candidate space includes
+    # the allgather/ring SUMMA engines — and the mesh topology, so the
+    # cached plan is keyed to this mesh and never recalled for another.
     if args.block is None or args.engine is None:
         with set_mesh(mesh):
             plan = get_plan("inverse", args.n, jnp.float32,
@@ -75,8 +75,9 @@ def main() -> None:
             t0 = time.perf_counter()
             inv = jax.block_until_ready(f(blocks))
             dt = time.perf_counter() - t0
-    resid = jnp.linalg.norm(BlockMatrix(inv).to_dense() @ a
-                            - jnp.eye(args.n)) / args.n ** 0.5
+    prod = jnp.matmul(a, BlockMatrix(inv).to_dense(),
+                      precision=jax.lax.Precision.HIGHEST)
+    resid = jnp.linalg.norm(prod - jnp.eye(args.n)) / args.n ** 0.5
     print(f"inverted in {dt * 1e3:.0f} ms  residual {float(resid):.2e}")
 
     # what this would cost on the production pod (roofline projection)

@@ -1,202 +1,78 @@
-"""Version-portable shims over the JAX API drift between 0.4.x and ≥0.6.
+"""The mesh/sharding API names the codebase uses, plus two runtime helpers.
 
-The sharding-in-types work moved every mesh-context / shard_map / collective
--axis API the codebase needs. Import these names from here, never from jax
-directly (DESIGN.md §"JAX-version compatibility contract"):
+The repo targets one installed JAX (0.9.x, see DESIGN.md §"JAX runtime
+contract"). These names are plain aliases of that version's public API,
+kept in one module so the mesh vocabulary the sharded code speaks is
+listed in one place:
 
-  name here          new JAX (≥0.6)                    0.4.x fallback
-  -----------------  --------------------------------  ------------------------
-  get_abstract_mesh  jax.sharding.get_abstract_mesh()  mesh context thread-local
-  shard_map          jax.shard_map(check_vma=…)        experimental (check_rep)
-  pvary              jax.lax.pvary                     identity (no vma typing)
-  set_mesh           jax.set_mesh(mesh)                `with mesh:` context
-  make_mesh          jax.make_mesh(axis_types=…)       drop axis_types kwarg
-  AxisType           jax.sharding.AxisType             shim enum
-  axis_size          jax.lax.axis_size(name)           lax.psum(1, name)
-  jit_shardings      PartitionSpecs pass through       wrap in NamedSharding
-
-Semantics preserved by the fallbacks:
-
-* ``get_abstract_mesh`` returns None (or an empty-shape mesh) outside any
-  mesh context; callers must handle both (``mesh is None or not mesh.shape``).
-* On 0.4.x the legacy ``check_rep`` replication checker predates the vma type
-  system and raises false positives on tiled all-gathers, so the fallback
-  always disables it; ``check_vma`` is honoured verbatim on new JAX.
-* ``pvary`` only exists to satisfy the new varying-manual-axes type checker;
-  identity is exactly correct where the checker does not exist.
-* ``axis_size`` relies on ``lax.psum`` of a Python scalar folding to the
-  static axis size — a documented JAX invariant on every version we support.
+  get_abstract_mesh  jax.sharding.get_abstract_mesh()  (empty-shape mesh
+                     outside a mesh context: test ``not mesh.shape``)
+  shard_map          jax.shard_map (check_vma honoured)
+  pvary              jax.lax.pcast(..., to="varying")
+  set_mesh           jax.set_mesh
+  make_mesh          jax.make_mesh
+  AxisType           jax.sharding.AxisType
+  axis_size          jax.lax.axis_size
 """
 
 from __future__ import annotations
 
-import contextlib
-import enum
 import functools
 import os
+import pathlib
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec
 
 __all__ = [
     "get_abstract_mesh", "shard_map", "pvary", "set_mesh", "make_mesh",
-    "AxisType", "axis_size", "jit_shardings", "pallas_tpu_compiler_params",
-    "enable_compilation_cache", "supports_float8",
+    "AxisType", "axis_size", "enable_compilation_cache",
+    "DEFAULT_COMPILATION_CACHE_DIR", "supports_float8",
 ]
 
-_HAS_GET_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-_HAS_JAX_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_PVARY = hasattr(jax.lax, "pvary")
-_HAS_SET_MESH = hasattr(jax, "set_mesh")
-_HAS_AXIS_SIZE = hasattr(jax.lax, "axis_size")
-# Bare PartitionSpec leaves in jit in/out_shardings landed with set_mesh.
-_JIT_TAKES_PSPECS = _HAS_SET_MESH
-
-
-def get_abstract_mesh():
-    """The mesh of the innermost active mesh context, or None outside one."""
-    if _HAS_GET_ABSTRACT_MESH:
-        return jax.sharding.get_abstract_mesh()
-    from jax._src.mesh import thread_resources
-
-    mesh = thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
-
-
-def shard_map(f, *, mesh=None, in_specs, out_specs, check_vma=True):
-    """jax.shard_map with the 0.4.x experimental module as fallback."""
-    if _HAS_JAX_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if mesh is None:
-        mesh = get_abstract_mesh()
-    # check_rep (the pre-vma replication checker) false-positives on tiled
-    # all-gather outputs; the code this layer serves was written against the
-    # vma checker, so disable the legacy one unconditionally.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+get_abstract_mesh = jax.sharding.get_abstract_mesh
+shard_map = jax.shard_map
+set_mesh = jax.set_mesh
+make_mesh = jax.make_mesh
+AxisType = jax.sharding.AxisType
+axis_size = jax.lax.axis_size
 
 
 def pvary(x, axis_names):
-    """Mark `x` device-varying over `axis_names` (identity without vma)."""
-    if _HAS_PVARY:
-        return jax.lax.pvary(x, axis_names)
-    return x
+    """Mark `x` device-varying over `axis_names` (vma typing in shard_map)."""
+    return jax.lax.pcast(x, axis_names, to="varying")
 
 
-@contextlib.contextmanager
-def _legacy_mesh_context(mesh):
-    with mesh:
-        yield mesh
+# <checkout>/.jax_cache: a fixed path (the path is part of the cache key, so
+# a directory that moved would never hit), listed in .gitignore.
+DEFAULT_COMPILATION_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
 
 
-def set_mesh(mesh):
-    """Context manager installing `mesh` as the ambient mesh."""
-    if _HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    return _legacy_mesh_context(mesh)
+def enable_compilation_cache() -> str:
+    """Turn on XLA's persistent compilation cache; return its directory.
 
-
-if hasattr(jax.sharding, "AxisType"):
-    AxisType = jax.sharding.AxisType
-else:
-    class AxisType(enum.Enum):
-        """Stand-in for jax.sharding.AxisType (0.4.x meshes are all Auto)."""
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-try:
-    import inspect as _inspect
-    _MAKE_MESH_TAKES_AXIS_TYPES = (
-        "axis_types" in _inspect.signature(jax.make_mesh).parameters)
-except (TypeError, ValueError):
-    _MAKE_MESH_TAKES_AXIS_TYPES = False
-
-
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """jax.make_mesh, dropping `axis_types` where the kwarg doesn't exist."""
-    if _MAKE_MESH_TAKES_AXIS_TYPES:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=axis_types, devices=devices)
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-def axis_size(name) -> int:
-    """Static size of a manual (shard_map/pmap) axis, inside the mapped fn."""
-    if _HAS_AXIS_SIZE:
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
-def _is_pspec(x) -> bool:
-    return isinstance(x, PartitionSpec)
-
-
-def jit_shardings(tree, mesh=None):
-    """Make a pytree of PartitionSpecs acceptable to jit in/out_shardings.
-
-    New JAX takes bare specs under a mesh context; 0.4.x rejects them, so
-    wrap each spec leaf in NamedSharding against the ambient mesh. None
-    subtrees (unconstrained outputs) pass through on both.
+    Warm restarts (DESIGN.md §9) and cold chip runs both pay every compile
+    again without it. The directory is ``$JAX_COMPILATION_CACHE_DIR`` when
+    that is set — the deployment decides, and no other directory is set in
+    code — and otherwise the fixed in-checkout
+    `DEFAULT_COMPILATION_CACHE_DIR`. The eviction thresholds are lowered to
+    "cache everything": serving programs are many and individually small,
+    and the defaults skip sub-second compiles, which is exactly the retrace
+    cost a restart pays N times over.
     """
-    if _JIT_TAKES_PSPECS:
-        return tree
-    if mesh is None:
-        mesh = get_abstract_mesh()
-    if mesh is None:
-        return tree
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s) if _is_pspec(s) else s,
-        tree, is_leaf=_is_pspec)
-
-
-def enable_compilation_cache(cache_dir: str | None = None) -> str | None:
-    """Point XLA's persistent compilation cache at a directory.
-
-    Serving's warm-restart story (DESIGN.md §9): a restarted server would
-    otherwise re-trace and re-compile every jitted program before its
-    first answer. With the persistent cache enabled, the second process
-    loads compiled executables from disk and the first-request latency
-    drops to ~steady-state.
-
-    `cache_dir=None` reads ``$SPIN_COMPILE_CACHE``; when that is unset
-    too, this is a no-op returning None — callers opt in per-deployment,
-    never accidentally. The eviction thresholds are lowered to "cache
-    everything" (serving programs are many and individually small; the
-    defaults skip sub-second compiles, which is exactly the retrace cost
-    a restart pays N times over). Config names drifted across JAX
-    versions, so each update is tolerated individually — on a version
-    missing a knob the cache still works with that default.
-    """
-    from repro import envconfig
-
-    cache_dir = cache_dir or envconfig.env_str("SPIN_COMPILE_CACHE")
-    if not cache_dir:
-        return None
+    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                 or DEFAULT_COMPILATION_CACHE_DIR)
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for name, value in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(name, value)
-        except AttributeError:                         # pragma: no cover
-            pass                 # knob absent on this version; defaults hold
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # The cache module latches its state at the FIRST compilation: enabling
     # the dir after anything has jitted (service constructed mid-process,
-    # after planner/test warmup) would silently no-op. Reset so the new dir
+    # after planner/test warmup) would silently no-op. Reset so the dir
     # takes effect from the next compile.
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
+    from jax.experimental.compilation_cache import compilation_cache as _cc
 
-        _cc.reset_cache()
-    except Exception:                                  # pragma: no cover
-        pass                     # module moved/absent; dir applies at init
+    _cc.reset_cache()
     return cache_dir
 
 
@@ -220,17 +96,3 @@ def supports_float8() -> bool:
         return bool((roundtrip == x).all())
     except Exception:                                  # pragma: no cover
         return False
-
-
-def pallas_tpu_compiler_params(**kwargs):
-    """pltpu.CompilerParams on new JAX, pltpu.TPUCompilerParams on 0.4.x.
-
-    Same dataclass either way (dimension_semantics, has_side_effects, …);
-    only the public name moved.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)

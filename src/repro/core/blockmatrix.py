@@ -27,7 +27,6 @@ __all__ = [
     "count_ops",
     "current_counts",
     "block_sharding",
-    "constrain_grid",
     "assemble_quadrants",
 ]
 
@@ -94,15 +93,6 @@ def _bump(field: str, by: int = 1) -> None:
 def block_sharding(mesh, grid_axes=("data", "model")) -> NamedSharding:
     """Sharding that puts the block *grid* over the mesh, blocks replicated."""
     return NamedSharding(mesh, P(*grid_axes, None, None))
-
-
-def constrain_grid(blocks: jax.Array, grid_axes=("data", "model")) -> jax.Array:
-    """Attach a grid-over-mesh sharding constraint inside jit (no-op outside)."""
-    try:
-        return jax.lax.with_sharding_constraint(blocks, P(*grid_axes, None, None))
-    except (ValueError, RuntimeError):
-        # Outside a mesh context (single-device tests) constraints don't apply.
-        return blocks
 
 
 def assemble_quadrants(c11: jax.Array, c12: jax.Array, c21: jax.Array,
@@ -242,6 +232,3 @@ class BlockMatrix:
     @classmethod
     def zeros(cls, grid: int, block_size: int, dtype=jnp.float32) -> "BlockMatrix":
         return cls(jnp.zeros((grid, grid, block_size, block_size), dtype=dtype))
-
-    def with_grid_sharding(self, grid_axes=("data", "model")) -> "BlockMatrix":
-        return BlockMatrix(constrain_grid(self.blocks, grid_axes))

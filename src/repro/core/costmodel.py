@@ -26,6 +26,7 @@ import numpy as np
 __all__ = [
     "CostParams", "spin_cost", "lu_cost", "spin_schedule",
     "tpu_roofline_cost", "apply_inverse_cost", "fit_scale", "DTYPE_BYTES",
+    "TPU_PEAKS", "tpu_peaks",
     "coded_work_multiplier", "coded_completion_cost", "plan_redundancy",
     "STRASSEN_CUTOFF", "strassen_multiply_counts", "strassen_cost",
     "strassen_crossover_n",
@@ -316,6 +317,22 @@ def plan_redundancy(workers: int, *, straggler_prob: float = 0.05,
 # ---------------------------------------------------------------------------
 
 TPU_V5E = dict(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9)
+
+# Per-chip peaks keyed by `jax.devices()[0].device_kind`. v5e: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM); ici_bw is the
+# model's per-link share. A kind missing here is an error, never v5e.
+TPU_PEAKS = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def tpu_peaks(device_kind: str) -> dict:
+    """The peak table row for a TPU `device_kind`; unknown kinds raise."""
+    try:
+        return TPU_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for TPU device kind {device_kind!r}; add "
+            f"its row to costmodel.TPU_PEAKS (known: {sorted(TPU_PEAKS)})"
+        ) from None
 
 
 def tpu_roofline_cost(n: int, b: int, chips: int, *, dtype_bytes: int = 2,

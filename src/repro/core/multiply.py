@@ -34,7 +34,9 @@ block's operands land on one node. On a TPU mesh we provide three engines:
                     Off-TPU the kernels run in interpret mode (tests/CI).
 
 All engines accumulate in f32 (`preferred_element_type`) so bf16 inputs hit
-the MXU with f32 accumulation — the TPU analogue of JBlas dgemm.
+the MXU with f32 accumulation — the TPU analogue of JBlas dgemm — and f32
+operands multiply at f32 precision (`precision.dot_precision`), not in the
+single bf16 pass a TPU gives an f32 dot by default.
 
 Grid-to-mesh contract for the shard_map engines:
     A grid (i, k): i over 'data', k over 'model'
@@ -56,6 +58,7 @@ from jax.sharding import PartitionSpec as P
 from repro import compat
 
 from .blockmatrix import BlockMatrix, _bump
+from .precision import dot_precision
 
 __all__ = ["multiply", "multiply_engine", "current_engine", "validate_engine",
            "multiply_blocks", "matmul_blocks_einsum", "matmul_blocks_pallas",
@@ -113,7 +116,8 @@ def _accum_dtype(dtype) -> jnp.dtype:
 def matmul_blocks_einsum(a: jax.Array, b: jax.Array) -> jax.Array:
     """C[i,j] = sum_k A[i,k] @ B[k,j] over (bi,bk,bs,bs)×(bk,bj,bs,bs) grids."""
     acc = _accum_dtype(a.dtype)
-    out = jnp.einsum("ikab,kjbc->ijac", a, b, preferred_element_type=acc)
+    out = jnp.einsum("ikab,kjbc->ijac", a, b, preferred_element_type=acc,
+                     precision=dot_precision(a.dtype, b.dtype))
     return out.astype(a.dtype)
 
 
@@ -204,6 +208,10 @@ def _local_matmul(engine: str):
     return matmul_blocks_pallas if engine == "pallas" else matmul_blocks_einsum
 
 
+# A Pallas kernel inside shard_map runs with the vma check off: its VMEM
+# scratch refs carry no manual-axis typing, so the kernel body's first
+# `scratch += loaded_operand` fails the check though every value is
+# per-shard by construction (each shard's kernel reads only its own panels).
 def _shard_map_multiply(a: jax.Array, b: jax.Array, engine: str) -> jax.Array:
     mesh = compat.get_abstract_mesh()
     axes = _mesh_axes_for(mesh, (a.shape[0], a.shape[1]),
@@ -220,6 +228,7 @@ def _shard_map_multiply(a: jax.Array, b: jax.Array, engine: str) -> jax.Array:
         in_specs=(P(data_axis, model_axis, None, None),
                   P(data_axis, model_axis, None, None)),
         out_specs=P(data_axis, model_axis, None, None),
+        check_vma=engine != "pallas",
     )(a, b)
 
 
@@ -283,7 +292,7 @@ def schur_update_blocks(c: jax.Array, a: jax.Array, b: jax.Array, *,
 
         spec = P(data_axis, model_axis, None, None)
         return compat.shard_map(local, mesh=mesh, in_specs=(spec,) * 3,
-                                out_specs=spec)(c, a, b)
+                                out_specs=spec, check_vma=False)(c, a, b)
     prod = multiply_blocks(a, b, engine)
     return prod - c if negate_c else c - prod
 

@@ -36,7 +36,7 @@ import dataclasses
 import warnings
 
 __all__ = ["PrecisionPolicy", "PRECISION_PRESETS", "resolve_precision",
-           "DEFAULT_PRECISION_ENV"]
+           "DEFAULT_PRECISION_ENV", "dot_precision"]
 
 # The one env knob selecting the default policy (preset name or descriptor).
 DEFAULT_PRECISION_ENV = "SPIN_PRECISION"
@@ -52,6 +52,23 @@ _FIELD_ENV = {
 
 _STORE_DTYPES = ("bfloat16", "float16", "float32", "float64",
                  "float8_e4m3fn")
+
+
+def dot_precision(*dtypes):
+    """The `precision=` for a GEMM over operands of `dtypes`.
+
+    f32 operands get `Precision.HIGHEST`: a TPU runs an f32 dot at the
+    default precision as one bf16 pass, which would give every f32 result
+    bf16 rounding. Any other dtype keeps the default, so bf16 math is
+    reached only by storing or computing in bf16 — i.e. through a
+    `PrecisionPolicy`, never by accident. CPU f32 dots are exact either way.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    if all(jnp.dtype(d) == jnp.float32 for d in dtypes):
+        return jax.lax.Precision.HIGHEST
+    return None
 
 
 def _valid_dtype(name: str) -> bool:

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from .blockmatrix import BlockMatrix, _bump
 from .multiply import current_engine, multiply_engine, validate_engine
+from .precision import dot_precision
 from .spin import LEAF_SOLVERS, spin_inverse_dense
 
 __all__ = ["spin_solve", "spin_solve_dense", "spin_solve_sharded",
@@ -85,7 +86,8 @@ def _apply_blocks(a: BlockMatrix, x: jax.Array) -> jax.Array:
     xb = x.reshape(b, bs, x.shape[-1])
     acc = _accum_dtype(a.blocks.dtype)
     out = jnp.einsum("ijab,jbk->iak", a.blocks, xb,
-                     preferred_element_type=acc)
+                     preferred_element_type=acc,
+                     precision=dot_precision(a.blocks.dtype, xb.dtype))
     return out.reshape(b * bs, x.shape[-1]).astype(x.dtype)
 
 
@@ -113,7 +115,8 @@ def _leaf_solve(block: jax.Array, rhs: jax.Array, solver: str) -> jax.Array:
         x = tri_ops.triangular_solve(lu, y, lower=False)
         return x.astype(rhs.dtype)
     inv = LEAF_SOLVERS[solver](block)
-    return (inv.astype(jnp.float32) @ r32).astype(rhs.dtype)
+    return jnp.matmul(inv.astype(jnp.float32), r32,
+                      precision=dot_precision(jnp.float32)).astype(rhs.dtype)
 
 
 def _solve(a: BlockMatrix, b: jax.Array, leaf_solver: str) -> jax.Array:
@@ -140,8 +143,9 @@ def _solve(a: BlockMatrix, b: jax.Array, leaf_solver: str) -> jax.Array:
 
     acc = _accum_dtype(iii.dtype)
     _bump("solve_applies")                                # III·X2 panel GEMM
-    x1 = y1 - jnp.matmul(iii, x2,
-                         preferred_element_type=acc).astype(y1.dtype)
+    x1 = y1 - jnp.matmul(iii, x2, preferred_element_type=acc,
+                         precision=dot_precision(iii.dtype, x2.dtype)
+                         ).astype(y1.dtype)
     _bump("subtracts")
     return jnp.concatenate([x1, x2], axis=0)
 
@@ -339,10 +343,16 @@ def sketched_approx_inverse(a: jax.Array, key: jax.Array, *,
     # under mild power-iteration underestimation).
     key, sub = jax.random.split(key)
     v = jax.random.normal(sub, (n,), dtype=jnp.float32)
+    hi = dot_precision(jnp.float32)
+
+    def gram(v):
+        return jnp.matmul(f32.T, jnp.matmul(f32, v, precision=hi),
+                          precision=hi)
+
     for _ in range(8):
-        v = f32.T @ (f32 @ v)
+        v = gram(v)
         v = v / jnp.linalg.norm(v)
-    sigma2 = float(jnp.linalg.norm(f32.T @ (f32 @ v)))
+    sigma2 = float(jnp.linalg.norm(gram(v)))
     x0 = f32.T / (1.1 * sigma2)
 
     bs = block_size or n // solve_grid_for(n)
@@ -351,7 +361,7 @@ def sketched_approx_inverse(a: jax.Array, key: jax.Array, *,
 
     def probe_residual(x_bm: BlockMatrix, k: jax.Array) -> float:
         return float(estimate_inverse_residual(
-            lambda p: f32 @ p, x_bm.to_dense(), k, n,
+            lambda p: jnp.matmul(f32, p, precision=hi), x_bm.to_dense(), k, n,
             probes=max(1, probes)))
 
     key, sub = jax.random.split(key)

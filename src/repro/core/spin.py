@@ -40,6 +40,7 @@ from repro.obs.trace import TRACER as _TRACER
 from .blockmatrix import BlockMatrix, _bump
 from .multiply import (multiply, multiply_engine, multiply_subtract,
                        subtract_multiply, validate_engine)
+from .precision import dot_precision
 
 __all__ = ["spin_inverse", "spin_inverse_dense", "spin_inverse_sharded",
            "leaf_inverse", "LEAF_SOLVERS"]
@@ -77,7 +78,8 @@ def _leaf_qr(block: jax.Array) -> jax.Array:
     q, r = jnp.linalg.qr(f32)
     n = block.shape[-1]
     rinv = jax.scipy.linalg.solve_triangular(r, jnp.eye(n, dtype=jnp.float32))
-    return (rinv @ q.T).astype(block.dtype)
+    return jnp.matmul(rinv, q.T, precision=dot_precision(jnp.float32)
+                      ).astype(block.dtype)
 
 
 LEAF_SOLVERS: dict[str, Callable[[jax.Array], jax.Array]] = {
@@ -218,6 +220,22 @@ def _spin_inverse_dense(dense: jax.Array, block_size: int,
         return spin_inverse(a, leaf_solver=leaf_solver).to_dense()
 
 
+@functools.partial(jax.jit, static_argnames=("block_size", "engine",
+                                             "sweeps"))
+def _polish_dense(dense: jax.Array, approx: jax.Array, block_size: int,
+                  engine: str | None, sweeps: int) -> jax.Array:
+    # One program, not op-by-op: eager sweeps over an n=16384 pair hold
+    # several transient f32 copies of the matrix at once (a TPU's HBM
+    # peaks near full); jitted, XLA schedules and reuses the buffers.
+    from .newton_schulz import newton_schulz_polish
+
+    a32 = BlockMatrix.from_dense(dense.astype(jnp.float32), block_size)
+    x32 = BlockMatrix.from_dense(approx.astype(jnp.float32), block_size)
+    ctx = multiply_engine(engine) if engine else contextlib.nullcontext()
+    with ctx:
+        return newton_schulz_polish(a32, x32, sweeps=sweeps).to_dense()
+
+
 def _lowp_inverse_dense(dense: jax.Array, block_size: int, leaf_solver: str,
                         engine: str | None, policy) -> jax.Array:
     """Dense low-precision inversion: recursion at the policy's compute
@@ -226,14 +244,8 @@ def _lowp_inverse_dense(dense: jax.Array, block_size: int, leaf_solver: str,
     approx = _spin_inverse_dense(dense.astype(cd), block_size, leaf_solver,
                                  engine)
     if policy.polish_sweeps:
-        from .newton_schulz import newton_schulz_polish
-
-        a32 = BlockMatrix.from_dense(dense.astype(jnp.float32), block_size)
-        x32 = BlockMatrix.from_dense(approx.astype(jnp.float32), block_size)
-        ctx = multiply_engine(engine) if engine else contextlib.nullcontext()
-        with ctx:
-            approx = newton_schulz_polish(
-                a32, x32, sweeps=policy.polish_sweeps).to_dense()
+        approx = _polish_dense(dense, approx, block_size, engine,
+                               policy.polish_sweeps)
     return approx.astype(policy.resolve_store(dense.dtype))
 
 

@@ -13,12 +13,17 @@ import jax.numpy as jnp
 __all__ = ["make_spd", "make_diag_dominant", "make_ill_conditioned_spd",
            "make_block_banded_spd", "make_spd_batch", "MATRIX_FAMILIES"]
 
+# Generators build in f32 at full precision, so a seed gives the same matrix
+# on every backend (a TPU's default f32 dot is one bf16 pass).
+_HI = jax.lax.Precision.HIGHEST
+
 
 def make_spd(n: int, key: jax.Array, dtype=jnp.float32,
              cond_boost: float = 1.0) -> jax.Array:
     """Well-conditioned SPD: B Bᵀ/n + boost·I (condition ~ O(10)/boost)."""
     b = jax.random.normal(key, (n, n), dtype=jnp.float32)
-    a = b @ b.T / n + cond_boost * jnp.eye(n, dtype=jnp.float32)
+    a = (jnp.matmul(b, b.T, precision=_HI) / n
+         + cond_boost * jnp.eye(n, dtype=jnp.float32))
     return a.astype(dtype)
 
 
@@ -39,7 +44,7 @@ def make_ill_conditioned_spd(n: int, key: jax.Array, dtype=jnp.float32,
     """
     q, _ = jnp.linalg.qr(jax.random.normal(key, (n, n), dtype=jnp.float32))
     lam = jnp.logspace(-jnp.log10(cond), 0.0, n, dtype=jnp.float32)
-    return ((q * lam[None, :]) @ q.T).astype(dtype)
+    return jnp.matmul(q * lam[None, :], q.T, precision=_HI).astype(dtype)
 
 
 def make_block_banded_spd(n: int, key: jax.Array, dtype=jnp.float32,
@@ -59,7 +64,8 @@ def make_block_banded_spd(n: int, key: jax.Array, dtype=jnp.float32,
     mask = (jnp.abs(i[:, None] - i[None, :]) <= bandwidth).astype(jnp.float32)
     mask = jnp.kron(mask, jnp.ones((band, band), jnp.float32))
     f = f * mask
-    return (f @ f.T + jnp.eye(n, dtype=jnp.float32)).astype(dtype)
+    return (jnp.matmul(f, f.T, precision=_HI)
+            + jnp.eye(n, dtype=jnp.float32)).astype(dtype)
 
 
 def make_spd_batch(batch: int, n: int, key: jax.Array,

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from .blockmatrix import BlockMatrix, _bump
+from .precision import dot_precision
 from .verify import residual_tolerance
 
 __all__ = [
@@ -62,6 +63,11 @@ def _as_panel(x: jax.Array) -> tuple[jax.Array, bool]:
     return (x[:, None], True) if x.ndim == 1 else (x, False)
 
 
+def _mm(a: jax.Array, b: jax.Array) -> jax.Array:
+    """a @ b at the operands' own precision (f32 stays f32 on the MXU)."""
+    return jnp.matmul(a, b, precision=dot_precision(a.dtype, b.dtype))
+
+
 # ---------------------------------------------------------------------------
 # Dense path
 # ---------------------------------------------------------------------------
@@ -72,10 +78,10 @@ def _smw_inverse_dense(inv: jax.Array, u: jax.Array, v: jax.Array
                        ) -> jax.Array:
     f32 = inv.astype(jnp.float32)
     u32, v32 = u.astype(jnp.float32), v.astype(jnp.float32)
-    p = f32 @ u32                                   # A⁻¹ U          (n, k)
-    q = (f32.T @ v32).T                             # Vᵀ A⁻¹         (k, n)
-    cap = jnp.eye(u.shape[1], dtype=jnp.float32) + v32.T @ p
-    return (f32 - p @ jnp.linalg.solve(cap, q)).astype(inv.dtype)
+    p = _mm(f32, u32)                               # A⁻¹ U          (n, k)
+    q = _mm(f32.T, v32).T                           # Vᵀ A⁻¹         (k, n)
+    cap = jnp.eye(u.shape[1], dtype=jnp.float32) + _mm(v32.T, p)
+    return (f32 - _mm(p, jnp.linalg.solve(cap, q))).astype(inv.dtype)
 
 
 @jax.jit
@@ -84,10 +90,11 @@ def _smw_solve_dense(inv: jax.Array, u: jax.Array, v: jax.Array,
     f32 = inv.astype(jnp.float32)
     u32, v32 = u.astype(jnp.float32), v.astype(jnp.float32)
     r32 = rhs.astype(jnp.float32)
-    x0 = f32 @ r32                                  # A⁻¹ b
-    p = f32 @ u32                                   # A⁻¹ U
-    cap = jnp.eye(u.shape[1], dtype=jnp.float32) + v32.T @ p
-    return (x0 - p @ jnp.linalg.solve(cap, v32.T @ x0)).astype(rhs.dtype)
+    x0 = _mm(f32, r32)                              # A⁻¹ b
+    p = _mm(f32, u32)                               # A⁻¹ U
+    cap = jnp.eye(u.shape[1], dtype=jnp.float32) + _mm(v32.T, p)
+    return (x0 - _mm(p, jnp.linalg.solve(cap, _mm(v32.T, x0)))
+            ).astype(rhs.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -100,7 +107,8 @@ def _blocks_apply(blocks: jax.Array, x: jax.Array) -> jax.Array:
     b, _, bs, _ = blocks.shape
     out = jnp.einsum("ijab,jbk->iak", blocks.astype(jnp.float32),
                      x.astype(jnp.float32).reshape(b, bs, x.shape[-1]),
-                     preferred_element_type=jnp.float32)
+                     preferred_element_type=jnp.float32,
+                     precision=dot_precision(jnp.float32))
     return out.reshape(b * bs, x.shape[-1])
 
 
@@ -109,7 +117,8 @@ def _blocks_apply_t(blocks: jax.Array, x: jax.Array) -> jax.Array:
     b, _, bs, _ = blocks.shape
     out = jnp.einsum("ijab,iak->jbk", blocks.astype(jnp.float32),
                      x.astype(jnp.float32).reshape(b, bs, x.shape[-1]),
-                     preferred_element_type=jnp.float32)
+                     preferred_element_type=jnp.float32,
+                     precision=dot_precision(jnp.float32))
     return out.reshape(b * bs, x.shape[-1])
 
 
@@ -119,7 +128,8 @@ def _smw_correction_blocks(blocks: jax.Array, p: jax.Array, m: jax.Array
     b, _, bs, _ = blocks.shape
     corr = jnp.einsum("iak,kjb->ijab", p.reshape(b, bs, p.shape[-1]),
                       m.reshape(m.shape[0], b, bs),
-                      preferred_element_type=jnp.float32)
+                      preferred_element_type=jnp.float32,
+                      precision=dot_precision(p.dtype, m.dtype))
     return (blocks.astype(jnp.float32) - corr).astype(blocks.dtype)
 
 
@@ -129,7 +139,7 @@ def _smw_inverse_blocks(blocks: jax.Array, u: jax.Array, v: jax.Array,
     p = anchor(_blocks_apply(blocks, u), "smw_panel")         # A⁻¹ U
     qt = anchor(_blocks_apply_t(blocks, v), "smw_panel")      # (Vᵀ A⁻¹)ᵀ
     cap = (jnp.eye(u.shape[1], dtype=jnp.float32)
-           + v.astype(jnp.float32).T @ p)
+           + _mm(v.astype(jnp.float32).T, p))
     m = jnp.linalg.solve(cap, qt.T)                           # (k, n)
     return _smw_correction_blocks(blocks, p, m)
 
@@ -176,7 +186,7 @@ def smw_update_inverse(inv, u: jax.Array, v: jax.Array):
     if isinstance(inv, sbm.ShardedBlockMatrix):
         _bump("smw_updates")
         blocks = _smw_inverse_sharded_program(
-            inv.blocks, u, v, inv.axes, sbm.mesh_fingerprint(devices=True))
+            inv.blocks, u, v, inv.axes, sbm.mesh_fingerprint())
         return sbm.ShardedBlockMatrix(blocks, inv.axes)
     if isinstance(inv, BlockMatrix):
         _bump("smw_updates")
@@ -203,11 +213,12 @@ def smw_update_solve(inv, u: jax.Array, v: jax.Array, rhs: jax.Array
         x0 = apply_inverse(inv, rhs2)
         p = apply_inverse(inv, u)
         cap = (jnp.eye(u.shape[1], dtype=jnp.float32)
-               + v.astype(jnp.float32).T @ p.astype(jnp.float32))
+               + _mm(v.astype(jnp.float32).T, p.astype(jnp.float32)))
         x = (x0.astype(jnp.float32)
-             - p.astype(jnp.float32)
-             @ jnp.linalg.solve(cap, v.astype(jnp.float32).T
-                                @ x0.astype(jnp.float32))).astype(rhs.dtype)
+             - _mm(p.astype(jnp.float32),
+                   jnp.linalg.solve(cap, _mm(v.astype(jnp.float32).T,
+                                             x0.astype(jnp.float32))))
+             ).astype(rhs.dtype)
     else:
         x = _smw_solve_dense(inv, u, v, rhs2)
     return x[:, 0] if vector else x
@@ -217,7 +228,8 @@ def smw_update_solve(inv, u: jax.Array, v: jax.Array, rhs: jax.Array
 def _apply_inverse_dense(inv: jax.Array, rhs: jax.Array) -> jax.Array:
     acc = _accum(inv.dtype)
     return jnp.matmul(inv.astype(acc), rhs.astype(acc),
-                      preferred_element_type=acc).astype(rhs.dtype)
+                      preferred_element_type=acc,
+                      precision=dot_precision(acc)).astype(rhs.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("compute", "accum"))
@@ -229,7 +241,8 @@ def _apply_inverse_dense_lowp(inv: jax.Array, rhs: jax.Array,
     # f32-accumulator contract the Pallas kernels keep in VMEM).
     c, a = jnp.dtype(compute), jnp.dtype(accum)
     return jnp.matmul(inv.astype(c), rhs.astype(c),
-                      preferred_element_type=a).astype(rhs.dtype)
+                      preferred_element_type=a,
+                      precision=dot_precision(c)).astype(rhs.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("axes", "mesh_fp"))
@@ -257,7 +270,7 @@ def apply_inverse(inv, rhs: jax.Array, *, precision=None) -> jax.Array:
     if isinstance(inv, sbm.ShardedBlockMatrix):
         _bump("solve_applies")
         x = _apply_sharded_program(inv.blocks, rhs2, inv.axes,
-                                   sbm.mesh_fingerprint(devices=True))
+                                   sbm.mesh_fingerprint())
     elif isinstance(inv, BlockMatrix):
         _bump("solve_applies")
         x = _jit_blocks_apply(inv.blocks, rhs2).astype(rhs.dtype)
@@ -283,7 +296,8 @@ _jit_blocks_apply = jax.jit(_blocks_apply)
 def _add_low_rank_dense(a: jax.Array, u: jax.Array, v: jax.Array
                         ) -> jax.Array:
     return (a.astype(jnp.float32)
-            + u.astype(jnp.float32) @ v.astype(jnp.float32).T).astype(a.dtype)
+            + _mm(u.astype(jnp.float32), v.astype(jnp.float32).T)
+            ).astype(a.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("axes", "mesh_fp"))
@@ -306,7 +320,7 @@ def add_low_rank(a, u: jax.Array, v: jax.Array):
     sbm = _sharded_helpers()
     if isinstance(a, sbm.ShardedBlockMatrix):
         blocks = _add_low_rank_sharded_program(
-            a.blocks, u, v, a.axes, sbm.mesh_fingerprint(devices=True))
+            a.blocks, u, v, a.axes, sbm.mesh_fingerprint())
         return sbm.ShardedBlockMatrix(blocks, a.axes)
     if isinstance(a, BlockMatrix):
         return BlockMatrix(_jit_add_low_rank_blocks(a.blocks, u, v))
@@ -347,7 +361,7 @@ def block_update_factors(delta_row: jax.Array, index: int, n: int
         e, jnp.eye(bs, dtype=delta_row.dtype), (index * bs, 0))
     d = jax.lax.dynamic_slice(delta_row, (0, index * bs), (bs, bs))
     wt = delta_row.T
-    u = jnp.concatenate([e, wt - e @ d], axis=1)
+    u = jnp.concatenate([e, wt - _mm(e, d)], axis=1)
     v = jnp.concatenate([wt, e], axis=1)
     return u, v
 

@@ -58,16 +58,23 @@ def _inf_norm(x: jax.Array) -> jax.Array:
     return jnp.max(jnp.abs(x.astype(jnp.float32)))
 
 
+def _product(a: jax.Array, x: jax.Array) -> jax.Array:
+    # f32 at full precision: a residual taken with a TPU's default
+    # one-pass bf16 dot would hide the error it exists to measure.
+    return jnp.matmul(a.astype(jnp.float32), x.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
 def inverse_residual(a: jax.Array, x: jax.Array) -> float:
     """‖AX − I‖∞ / ‖I‖∞ (= ‖AX − I‖∞) for a claimed inverse X."""
     n = a.shape[-1]
-    prod = a.astype(jnp.float32) @ x.astype(jnp.float32)
+    prod = _product(a, x)
     return float(_inf_norm(prod - jnp.eye(n, dtype=jnp.float32)))
 
 
 def solve_residual(a: jax.Array, x: jax.Array, b: jax.Array) -> float:
     """‖AX − B‖∞ / ‖B‖∞ for a claimed solution X of AX = B."""
-    prod = a.astype(jnp.float32) @ x.astype(jnp.float32)
+    prod = _product(a, x)
     return float(_inf_norm(prod - b.astype(jnp.float32))
                  / (_inf_norm(b) + 1e-30))
 
@@ -235,7 +242,7 @@ def run_conformance(grids: Sequence[int] = (2, 4, 8), block_size: int = 32,
     """
     if sharded:
         from repro.parallel.sharded_blockmatrix import (
-            ShardedBlockMatrix, sharded_spin_inverse, sharded_spin_solve)
+            ShardedBlockMatrix, sharded_spin_inverse, solve_program)
 
     reports = []
     key = jax.random.PRNGKey(seed)
@@ -258,7 +265,11 @@ def run_conformance(grids: Sequence[int] = (2, 4, 8), block_size: int = 32,
                 sbm = ShardedBlockMatrix.from_blockmatrix(bm)
                 with count_ops() as counts:
                     inv = sharded_spin_inverse(sbm)
-                x = sharded_spin_solve(sbm, rhs)
+                # The solve runs as the one jitted program users call:
+                # op-by-op, each eager op's output sharding is converted
+                # back to a mesh spec, which JAX cannot do for the
+                # row-sharded reshapes on a (4, 2) mesh (KeyError).
+                x = solve_program(sbm, rhs)
                 inv_dense = inv.to_dense()
                 ref = spin_inverse(bm).to_dense()
                 parity = float(_inf_norm(inv_dense - ref)

@@ -57,16 +57,14 @@ SPIN_ENV_VARS: tuple[EnvVar, ...] = (
     EnvVar("SPIN_PLAN_CACHE", "path", "~/.cache/repro_spin/plans.json",
            "Plan-cache JSON path (plans + fitted calibration constants).",
            "repro.planner.cache"),
-    EnvVar("SPIN_COMPILE_CACHE", "path", None,
-           "Persistent XLA compilation-cache directory for warm restarts.",
-           "repro.compat"),
     EnvVar("SPIN_FAULT_PLAN", "json", None,
            "Serialized FaultPlan (scripted stragglers/failures) picked up "
            "by coded execution and subprocess mesh harnesses.",
            "repro.parallel.straggler"),
     EnvVar("SPIN_PALLAS_INTERPRET", "bool", None,
-           "Force every Pallas kernel through interpret mode (CPU CI). "
-           "Unset auto-detects: interpret everywhere but real TPU.",
+           "Off-TPU only: also route the optional Pallas paths (Strassen "
+           "base case) through the interpreted kernels (CPU CI). Kernels "
+           "run compiled on TPU, where setting it is an error.",
            "repro.kernels"),
     EnvVar("SPIN_STRASSEN_CUTOFF", "int", "512",
            "Operand size at/below which Strassen goes classical. Read at "

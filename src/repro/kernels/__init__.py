@@ -1,49 +1,71 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
 """Pallas kernel packages + the shared interpret-mode policy.
 
 Every kernel wrapper in this package resolves its `interpret=` argument
-through `pallas_interpret_default()` so one environment flag governs the
-whole kernel layer:
+through `pallas_interpret_default()`, which follows the backend alone:
 
-  * ``SPIN_PALLAS_INTERPRET=1`` forces interpret mode everywhere — the CI
-    `pallas-interpret` job sets it so fused-kernel correctness is exercised
-    on CPU runners on every push, and it is the escape hatch for debugging
-    on TPU.
-  * unset (the default): compiled on TPU, interpret elsewhere, so the same
-    call sites run in tests (CPU) and production (TPU).
+  * on a TPU backend the kernels always run compiled (Mosaic). Interpret
+    mode there would hide the device behind the Python interpreter, so a
+    set ``SPIN_PALLAS_INTERPRET`` on a TPU backend is an error;
+  * everywhere else (CPU tests, CI) they run in interpret mode.
 
-The flag is a PROCESS-START switch for the jitted entry points: it is read
-at trace time, and `interpret` is a static argument only of the inner
-kernel calls — the outer `spin_inverse_dense`-style executables bake it in
-without it being part of their jit key. Set it before the first call into
-a jitted entry point (as the CI job does via the job environment);
-flipping it mid-process only affects direct kernel-wrapper calls and entry
-points that have not been traced yet.
+``SPIN_PALLAS_INTERPRET=1`` keeps one meaning, off-TPU only: the optional
+Pallas routes — the Strassen engine's base case (`kernels/strassen`) —
+are taken through the interpreted kernels too, so CI exercises that
+composition on CPU runners (`pallas_routes_forced`).
+
+The policy is read at trace time: already-compiled outer jit executables
+keep the value they were traced with.
 """
 
 
 import jax
 
-__all__ = ["pallas_interpret_default", "PALLAS_INTERPRET_ENV"]
+__all__ = ["pallas_interpret_default", "pallas_routes_forced",
+           "PALLAS_INTERPRET_ENV", "mesh_safe"]
 
 PALLAS_INTERPRET_ENV = "SPIN_PALLAS_INTERPRET"
 
 
 def pallas_interpret_default() -> bool:
-    """True when Pallas kernels should run in interpret mode.
+    """True when Pallas kernels must run in interpret mode (off-TPU).
 
-    Read at call time (not import time) so tests and the CI interpret job
-    can flip the environment without re-importing the kernel packages —
-    subject to the trace-time caveat in the module docstring: already-
-    compiled outer jit executables keep the value they were traced with.
+    Raises on a TPU backend when ``SPIN_PALLAS_INTERPRET`` is set at all:
+    no environment flag may send a kernel through the interpreter there.
     """
     from repro import envconfig
 
-    # A truthy flag forces interpret mode; unset (or explicit false) falls
-    # back to the backend check — same either way, so "0" keeps meaning
-    # "decide from the backend", as it always has.
-    if envconfig.env_bool(PALLAS_INTERPRET_ENV):
-        return True
-    return jax.default_backend() != "tpu"
+    if jax.default_backend() == "tpu":
+        if envconfig.env_raw(PALLAS_INTERPRET_ENV) is not None:
+            raise RuntimeError(
+                f"{PALLAS_INTERPRET_ENV} is set on a TPU backend; Pallas "
+                "kernels run compiled on TPU — unset it")
+        return False
+    return True
+
+
+def pallas_routes_forced() -> bool:
+    """Off-TPU, ``SPIN_PALLAS_INTERPRET=1`` also routes the optional Pallas
+    paths through the interpreted kernels (always False on TPU)."""
+    from repro import envconfig
+
+    return (pallas_interpret_default()
+            and envconfig.env_bool(PALLAS_INTERPRET_ENV))
+
+
+def mesh_safe(call):
+    """`call` (a Pallas kernel invocation), runnable under an ambient mesh.
+
+    The TPU lowering refuses a Mosaic kernel in the automatically
+    partitioned part of a mesh program ("Mosaic kernels cannot be
+    automatically partitioned"). Outside any mesh, or inside a shard_map
+    (every axis manual), the call runs as it is. Otherwise it runs
+    replicated under a shard_map: each device computes the whole call on
+    gathered operands — what the partitioner does for an op it cannot
+    split, e.g. the one leaf block of the sharded recursion.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.shape or mesh.are_all_axes_manual:
+        return call
+    return jax.shard_map(call, in_specs=P(), out_specs=P(), check_vma=False)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
 
 __all__ = ["flash_attention_pallas"]
 
@@ -104,7 +103,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
+from repro.core.precision import dot_precision
 
 __all__ = ["matmul_pallas", "schur_update_pallas", "auto_tiles",
            "DEFAULT_TILES"]
@@ -66,7 +66,8 @@ def _matmul_kernel(a_ref, b_ref, out_ref, acc_ref, *, k_steps: int) -> None:
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
+        a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
+        precision=dot_precision(a_ref.dtype, b_ref.dtype))
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -105,7 +106,7 @@ def matmul_pallas(a: jax.Array, b: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype or a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)
@@ -118,7 +119,8 @@ def _schur_update_kernel(c_ref, a_ref, b_ref, out_ref, acc_ref, *,
         acc_ref[...] = beta * c_ref[...].astype(jnp.float32)
 
     acc_ref[...] += alpha * jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
+        a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
+        precision=dot_precision(a_ref.dtype, b_ref.dtype))
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _flush():
@@ -167,7 +169,7 @@ def schur_update_pallas(c: jax.Array, a: jax.Array, b: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype or c.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(c, a, b)

@@ -19,7 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .. import pallas_interpret_default
+from .. import mesh_safe, pallas_interpret_default
 from .kernel import auto_tiles, matmul_pallas, schur_update_pallas
 
 __all__ = ["matmul", "schur_update", "block_gemm", "grid_matmul",
@@ -37,9 +37,9 @@ def matmul(a: jax.Array, b: jax.Array,
     m, k = a.shape
     n = b.shape[-1]
     tiles = tiles or auto_tiles(m, n, k)
-    return matmul_pallas(a, b, tiles=tiles,
-                         interpret=pallas_interpret_default(),
-                         out_dtype=out_dtype)
+    return mesh_safe(functools.partial(
+        matmul_pallas, tiles=tiles, interpret=pallas_interpret_default(),
+        out_dtype=out_dtype))(a, b)
 
 
 def schur_update(c: jax.Array, a: jax.Array, b: jax.Array, *,
@@ -51,9 +51,9 @@ def schur_update(c: jax.Array, a: jax.Array, b: jax.Array, *,
     out_dtype=float32 keeps the f32 accumulator un-rounded on the flush
     even for low-precision operands, matching `matmul`.
     """
-    return schur_update_pallas(c, a, b, alpha=alpha, beta=beta, tiles=tiles,
-                               interpret=pallas_interpret_default(),
-                               out_dtype=out_dtype)
+    return mesh_safe(functools.partial(
+        schur_update_pallas, alpha=alpha, beta=beta, tiles=tiles,
+        interpret=pallas_interpret_default(), out_dtype=out_dtype))(c, a, b)
 
 
 def blocks_to_dense(blocks: jax.Array) -> jax.Array:

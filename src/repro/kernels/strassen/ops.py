@@ -23,13 +23,12 @@ decision.
 
 from __future__ import annotations
 
-import os
-
 import jax
 
 from repro import compat
+from repro.core.precision import dot_precision
 
-from .. import PALLAS_INTERPRET_ENV
+from .. import pallas_interpret_default, pallas_routes_forced
 
 __all__ = ["pallas_base_default", "mosaic_legal", "base_matmul",
            "base_matmul_blocks", "base_schur_update"]
@@ -38,16 +37,14 @@ __all__ = ["pallas_base_default", "mosaic_legal", "base_matmul",
 def pallas_base_default() -> bool:
     """Should Strassen leaves compose with the Pallas kernels?
 
-    True where the kernels run compiled (TPU) and where interpret mode is
-    explicitly forced (``SPIN_PALLAS_INTERPRET=1`` — CI exercises the
-    composed base case on CPU runners). Plain off-TPU runs use XLA: an
-    implicitly interpreted kernel would be orders of magnitude slower than
-    the einsum it replaces, inverting the crossover the engine exists for.
+    True where the kernels run compiled (TPU) and, off-TPU, where the
+    interpreted route is explicitly forced (``SPIN_PALLAS_INTERPRET=1`` —
+    CI exercises the composed base case on CPU runners). Plain off-TPU
+    runs use XLA: an implicitly interpreted kernel would be orders of
+    magnitude slower than the einsum it replaces, inverting the crossover
+    the engine exists for.
     """
-    flag = os.environ.get(PALLAS_INTERPRET_ENV, "").strip().lower()
-    if flag in ("1", "true", "yes", "on"):
-        return True
-    return jax.default_backend() == "tpu"
+    return not pallas_interpret_default() or pallas_routes_forced()
 
 
 def mosaic_legal(n: int, full_tile_max: int = 512) -> bool:
@@ -97,7 +94,9 @@ def base_matmul_blocks(a: jax.Array, b: jax.Array) -> jax.Array:
         acc = (jnp.float32
                if a.dtype in (jnp.bfloat16, jnp.float16, jnp.float32)
                else a.dtype)
-        cd = jnp.matmul(ad, bd, preferred_element_type=acc).astype(a.dtype)
+        cd = jnp.matmul(ad, bd, preferred_element_type=acc,
+                        precision=dot_precision(a.dtype, b.dtype)
+                        ).astype(a.dtype)
         return cd.reshape(g, bs, g, bs).transpose(0, 2, 1, 3)
     # Late import: core.multiply dispatches into us. Import from the
     # submodule directly — `repro.core.multiply` the *attribute* is the
@@ -136,4 +135,6 @@ def base_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
         return mm_ops.matmul(a, b)
     acc = (jnp.float32 if a.dtype in (jnp.bfloat16, jnp.float16, jnp.float32)
            else a.dtype)
-    return jnp.matmul(a, b, preferred_element_type=acc).astype(a.dtype)
+    return jnp.matmul(a, b, preferred_element_type=acc,
+                      precision=dot_precision(a.dtype, b.dtype)
+                      ).astype(a.dtype)

@@ -21,7 +21,7 @@ import traceback
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import jit_shardings, set_mesh
+from repro.compat import set_mesh
 from repro.configs import SHAPES, cell_status, get_arch, list_archs
 from repro.configs.registry import ArchConfig
 from repro.configs.shapes import ShapeConfig
@@ -234,8 +234,8 @@ def _compile_cell(cfg: ArchConfig, shape: ShapeConfig, mesh,
         built = build_decode(cfg, shape, mesh, rules)
     fn, args, in_sh, out_sh, donate = built
     t0 = time.time()
-    jitted = jax.jit(fn, in_shardings=jit_shardings(in_sh, mesh),
-                     out_shardings=jit_shardings(out_sh, mesh),
+    jitted = jax.jit(fn, in_shardings=in_sh,
+                     out_shardings=out_sh,
                      donate_argnums=donate)
     lowered = jitted.lower(*args)
     t1 = time.time()
@@ -367,8 +367,8 @@ def run_solver_cell(n: int, block_size: int, *, multi_pod: bool,
             spec = P("data", "model", None, None)
             lowered = jax.jit(
                 invert,
-                in_shardings=jit_shardings(spec, mesh),
-                out_shardings=jit_shardings(spec, mesh),
+                in_shardings=spec,
+                out_shardings=spec,
             ).lower(abs_blocks)
             t1 = time.time()
             compiled = lowered.compile()

@@ -48,6 +48,7 @@ from repro import compat
 from repro.core.blockmatrix import BlockMatrix, _bump
 from repro.core.multiply import (current_engine, multiply_blocks,
                                  multiply_engine)
+from repro.core.precision import dot_precision
 
 __all__ = [
     "ShardedBlockMatrix", "SpecRecord", "record_specs",
@@ -178,26 +179,21 @@ def panel_spec(rows: int, mesh, axes: tuple[str, str] = ("data", "model")
     return P(row, None)
 
 
-def mesh_fingerprint(mesh=None, *, devices: bool = False) -> str:
+def mesh_fingerprint(mesh=None) -> str:
     """Canonical string for the ambient mesh, e.g. "data2:model2" ("" = none).
 
-    Used (a) with devices=True as the static jit-cache key component of the
-    sharded programs — device identity is included because on 0.4.x the
-    constraints bind the CONCRETE mesh at trace time, so two same-topology
-    meshes over different devices must not share an executable — and
-    (b) topology-only (devices=False) by the planner's ProblemSignature as
-    its mesh dimension, where plans legitimately transfer across device
-    identity.
+    Used (a) as the static jit-cache key component of the sharded programs
+    and (b) by the planner's ProblemSignature as its mesh dimension. It is
+    topology only: the constraints name mesh AXES, and jit places each
+    compiled program on the devices of the concrete mesh that `set_mesh`
+    installed, so two same-topology meshes over different devices share a
+    trace but never a device assignment (DESIGN.md §6).
     """
     if mesh is None:
         mesh = compat.get_abstract_mesh()
     if mesh is None or not mesh.shape:
         return ""
-    fp = ":".join(f"{k}{v}" for k, v in mesh.shape.items())
-    devs = getattr(mesh, "devices", None) if devices else None
-    if devs is not None:
-        fp += "@" + ",".join(str(d.id) for d in devs.flat)
-    return fp
+    return ":".join(f"{k}{v}" for k, v in mesh.shape.items())
 
 
 def _constrain(blocks: jax.Array, op: str,
@@ -481,8 +477,9 @@ def _sharded_solve(a: ShardedBlockMatrix, b: jax.Array,
 
     acc = _accum_dtype(iii.dtype)
     _bump("solve_applies")                                # III·X2 panel GEMM
-    x1 = y1 - jnp.matmul(iii, x2,
-                         preferred_element_type=acc).astype(y1.dtype)
+    x1 = y1 - jnp.matmul(iii, x2, preferred_element_type=acc,
+                         precision=dot_precision(iii.dtype, x2.dtype)
+                         ).astype(y1.dtype)
     _bump("subtracts")
     return _stack_panel_rows(x1, x2, "solve_panel", a.axes)
 
@@ -540,7 +537,7 @@ def inverse_program(a: ShardedBlockMatrix, *, leaf_solver: str = "linalg",
     executable.
     """
     out = _inverse_program(a.blocks, leaf_solver, engine or current_engine(),
-                           a.axes, mesh_fingerprint(devices=True))
+                           a.axes, mesh_fingerprint())
     return ShardedBlockMatrix(out, a.axes)
 
 
@@ -551,5 +548,5 @@ def solve_program(a: ShardedBlockMatrix, b: jax.Array, *,
     vector = b.ndim == 1
     rhs = b[:, None] if vector else b
     x = _solve_program(a.blocks, rhs, leaf_solver, engine or current_engine(),
-                       a.axes, mesh_fingerprint(devices=True))
+                       a.axes, mesh_fingerprint())
     return x[:, 0] if vector else x

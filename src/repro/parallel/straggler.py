@@ -35,6 +35,7 @@ chaos tests deterministic rather than live flakes.
 
 from __future__ import annotations
 
+import atexit
 import dataclasses
 import json
 import threading
@@ -57,6 +58,13 @@ __all__ = [
 ]
 
 FAULT_PLAN_ENV = "SPIN_FAULT_PLAN"
+
+# Set once the interpreter starts to exit. A straggler left sleeping on a
+# daemon thread must not wake into XLA while the runtime is being torn
+# down (the process then dies with SIGSEGV/SIGABRT after its work is done),
+# so an injected delay ends early at exit and the attempt fails instead.
+_EXITING = threading.Event()
+atexit.register(_EXITING.set)
 
 
 def _timeline(event: str, **attrs) -> None:
@@ -143,10 +151,12 @@ class FaultPlan:
             f"injected failure: rank {rank} at step {step}")
 
     def apply(self, rank: int, step: int = 0, *,
-              sleep: Callable[[float], None] = time.sleep) -> None:
+              sleep: Callable[[float], None] | None = None) -> None:
         delay = self.delay_for(rank)
         if delay > 0:
-            sleep(delay)
+            (sleep or _EXITING.wait)(delay)
+        if _EXITING.is_set():
+            raise WorkerFailure(f"rank {rank}: process exiting")
         self.check(rank, step)
 
     # -- serialization (env var for subprocess harnesses) --------------------

@@ -30,11 +30,10 @@ import time
 
 import jax
 
-from repro.core.costmodel import (DTYPE_BYTES, STRASSEN_CUTOFF, TPU_V5E,
-                                  CostParams, apply_inverse_cost, fit_scale,
-                                  spin_cost, strassen_cost,
-                                  strassen_multiply_counts,
-                                  tpu_roofline_cost)
+from repro.core.costmodel import (DTYPE_BYTES, STRASSEN_CUTOFF, CostParams,
+                                  apply_inverse_cost, fit_scale, spin_cost,
+                                  strassen_cost, strassen_multiply_counts,
+                                  tpu_peaks, tpu_roofline_cost)
 from repro.obs.trace import TRACER as _TRACER
 
 from .plan import Plan, ProblemSignature
@@ -107,8 +106,9 @@ def predict_cost(sig: ProblemSignature, plan: Plan,
 
     if sig.backend == "tpu":
         chips = max(sig.device_count, 1)
-        peak = 197e12
-        r = tpu_roofline_cost(sig.n, b, chips, dtype_bytes=bytes_)
+        hw = tpu_peaks(sig.device_kind)
+        peak = hw["peak_flops"]
+        r = tpu_roofline_cost(sig.n, b, chips, dtype_bytes=bytes_, hw=hw)
         if plan.multiply_engine == "ring":       # overlapped collective
             total = max(r["t_compute"], r["t_memory"], r["t_collective"])
         else:
@@ -123,7 +123,7 @@ def predict_cost(sig: ProblemSignature, plan: Plan,
             sub_bytes = sum(
                 2**i * 2 * 3 * (sig.n / 2**(i + 1))**2 * bytes_
                 for i in range(max(b.bit_length() - 1, 0)))
-            total += sub_bytes / (chips * TPU_V5E["hbm_bw"])
+            total += sub_bytes / (chips * hw["hbm_bw"])
         # Leaf re-pricing: the roofline books leaf flops inside t_compute at
         # full chips-parallel rate, but the recursion SERIALIZES leaves (the
         # paper's Eq. 2 — A11 before V) and each runs on one chip. Without
@@ -146,7 +146,7 @@ def predict_cost(sig: ProblemSignature, plan: Plan,
                                                       STRASSEN_CUTOFF)
                 total += nodes * 6 * (
                     2 * (macs - half_n**3) / (chips * peak)
-                    + 3 * adds * bytes_ / (chips * TPU_V5E["hbm_bw"]))
+                    + 3 * adds * bytes_ / (chips * hw["hbm_bw"]))
         sweep = 2 * 2 * sig.n**3 / (chips * peak)
     else:
         p = _cost_params(sig, b, calibration)
@@ -177,7 +177,8 @@ def predict_cost(sig: ProblemSignature, plan: Plan,
         if sig.backend == "tpu":
             chips = max(sig.device_count, 1)
             t_serve = apply_inverse_cost(
-                sig.n, 1, chips, dtype_bytes=DTYPE_BYTES.get(store, 4))
+                sig.n, 1, chips, dtype_bytes=DTYPE_BYTES.get(store, 4),
+                hw=tpu_peaks(sig.device_kind))
         else:
             p_srv = _cost_params(sig, b, calibration)
             t_serve = (2 * sig.n**2 * p_srv.t_flop

@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["Plan", "ProblemSignature", "signature_for", "enumerate_plans",
-           "candidate_grids", "mesh_descriptor", "STRASSEN_MIN_N"]
+           "candidate_grids", "mesh_descriptor", "compiles_on_tpu",
+           "STRASSEN_MIN_N"]
 
 # Smallest problem dimension at which the Strassen engine enters the
 # default candidate space. Below this every sub-multiply of the SPIN
@@ -60,6 +61,7 @@ class ProblemSignature:
     update_rank: int = 0  # accumulated SMW churn the plan is priced under
     precision: str = ""  # PrecisionPolicy.descriptor() ("" = exact default)
     constraint: str = ""  # e.g. "bs64" when the block grid is pre-fixed
+    device_kind: str = ""  # jax device_kind, e.g. "TPU v5 lite" (TPU peaks)
 
     def key(self) -> str:
         base = (f"{self.kind}/n{self.n}/{self.dtype}/{self.backend}"
@@ -76,6 +78,10 @@ class ProblemSignature:
         # schema bumped to v3 — v2 entries carry signature dicts without it.
         if self.precision:
             base += f"/p{self.precision}"
+        # TPU plans are priced against one chip kind's peaks; appended only
+        # for TPU signatures so every CPU/GPU key is unchanged.
+        if self.backend == "tpu":
+            base += f"/k{self.device_kind}"
         return f"{base}/{self.constraint}" if self.constraint else base
 
     def as_dict(self) -> dict:
@@ -90,7 +96,8 @@ def signature_for(kind: str, n: int, dtype=jnp.float32, *,
                   placement: str = "dense",
                   update_rank: int = 0,
                   precision: str = "",
-                  constraint: str = "") -> ProblemSignature:
+                  constraint: str = "",
+                  device_kind: str | None = None) -> ProblemSignature:
     """Build the signature for the *current* runtime.
 
     `cores` feeds the cost model's parallelization-factor terms: on CPU the
@@ -99,8 +106,13 @@ def signature_for(kind: str, n: int, dtype=jnp.float32, *,
     device count (the paper's `cores` = Spark executors). `mesh` defaults to
     the ambient mesh topology and `placement` to the dense executors; both
     are part of the cache key, so plans never cross mesh contexts.
+    `device_kind` defaults to the live device's kind when `backend` is the
+    live backend; a hypothetical TPU signature must name its chip kind.
     """
-    backend = backend or jax.default_backend()
+    live = jax.default_backend()
+    backend = backend or live
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind if backend == live else ""
     device_count = device_count or jax.device_count()
     if cores is None:
         cores = (max(os.cpu_count() or 1, device_count)
@@ -116,7 +128,8 @@ def signature_for(kind: str, n: int, dtype=jnp.float32, *,
                             cores=int(cores), mesh=mesh, placement=placement,
                             update_rank=int(update_rank),
                             precision=precision,
-                            constraint=constraint)
+                            constraint=constraint,
+                            device_kind=device_kind)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,6 +182,31 @@ def candidate_grids(n: int, *, min_block: int = 8, max_grid: int = 64
         grids.append(b)
         b *= 2
     return grids or [1]
+
+
+# The Pallas kernel each kernel-backed leaf solver runs, per problem kind
+# (kernels/leaf_inverse: the solve path's `pallas` leaf substitutes with
+# the triangular-solve kernel instead of inverting).
+_LEAF_KERNELS = {
+    "inverse": {"pallas": "pallas", "gauss_jordan": "gauss_jordan"},
+    "solve": {"pallas": "triangular_solve", "gauss_jordan": "gauss_jordan"},
+}
+
+
+def compiles_on_tpu(kind: str, block_size: int, leaf_solver: str,
+                    engine: str) -> bool:
+    """Whether every Pallas kernel the plan runs compiles for a TPU at
+    `block_size`: a whole-block leaf kernel must fit its VMEM reckoning
+    (`kernels.leaf_inverse.kernel.max_block_size`, the sizes
+    tests/test_tpu_compile.py compiles), and the fused GEMM engine needs a
+    Mosaic-legal leaf tiling (`kernels.strassen.ops.mosaic_legal`)."""
+    from repro.kernels.leaf_inverse.kernel import max_block_size
+    from repro.kernels.strassen.ops import mosaic_legal
+
+    kernel = _LEAF_KERNELS.get(kind, {}).get(leaf_solver)
+    if kernel is not None and block_size > max_block_size(kernel):
+        return False
+    return engine != "pallas" or mosaic_legal(block_size)
 
 
 def enumerate_plans(sig: ProblemSignature, *,
@@ -225,6 +263,9 @@ def enumerate_plans(sig: ProblemSignature, *,
         # b == 1 has no distributed multiplies — engine is irrelevant.
         for engine in (engines if b > 1 else engines[:1]):
             for leaf in leaf_solvers:
+                if sig.backend == "tpu" and not compiles_on_tpu(
+                        sig.kind, bs, leaf, engine):
+                    continue
                 plans.append(Plan(block_size=bs, leaf_solver=leaf,
                                   multiply_engine=engine,
                                   compute_dtype=sig.dtype))

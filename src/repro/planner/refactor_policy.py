@@ -37,7 +37,7 @@ import dataclasses
 
 import jax.numpy as jnp
 
-from repro.core.costmodel import DTYPE_BYTES, TPU_V5E, CostParams
+from repro.core.costmodel import DTYPE_BYTES, CostParams, tpu_peaks
 
 from .cache import PlanCache, default_cache
 from .plan import Plan, ProblemSignature, signature_for
@@ -78,8 +78,9 @@ def smw_update_cost(sig: ProblemSignature, k: int,
         chips = max(sig.device_count, 1)
         bytes_ = DTYPE_BYTES.get(_store_dtype(sig), 4)
         flops = (4 * n * n * k + k ** 3) * 2
-        t_compute = flops / (chips * TPU_V5E["peak_flops"])
-        t_memory = 2 * n * n * bytes_ / (chips * TPU_V5E["hbm_bw"])
+        hw = tpu_peaks(sig.device_kind)
+        t_compute = flops / (chips * hw["peak_flops"])
+        t_memory = 2 * n * n * bytes_ / (chips * hw["hbm_bw"])
         return float(max(t_compute, t_memory))
     t_flop = (calibration or {}).get("t_flop") or CostParams(
         n=n, b=1, cores=sig.cores).t_flop

@@ -96,10 +96,10 @@ the maintained inverse a request-serving object:
     knobs on the way back up). `snapshot_async()` captures a quiesced
     copy (JAX arrays are immutable, so the references ARE the copy) and
     runs the device→host transfer + file I/O on a background thread — the
-    tick loop never stalls on a snapshot. Pair with the persistent XLA
-    compilation cache (`compat.enable_compilation_cache`, env
-    ``SPIN_COMPILE_CACHE``) and a restarted process pays ~zero retrace
-    before its first answer.
+    tick loop never stalls on a snapshot. The service turns on the
+    persistent XLA compilation cache (`compat.enable_compilation_cache`:
+    ``$JAX_COMPILATION_CACHE_DIR`` or `<checkout>/.jax_cache`), so a
+    restarted process loads its programs instead of recompiling them.
 
 Consistency model: per-matrix FIFO. An update acts as a barrier — solves
 submitted before it complete against the pre-update matrix, solves after
@@ -123,7 +123,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.blockmatrix import BlockMatrix
-from repro.core.precision import PrecisionPolicy, resolve_precision
+from repro.core.precision import (PrecisionPolicy, dot_precision,
+                                  resolve_precision)
 from repro.core.solver_ckpt import validate_snapshot_key as \
     _validate_snapshot_key
 from repro.core.solve import (sketched_approx_inverse, spin_solve_dense,
@@ -161,8 +162,10 @@ def _ns_polish_dense(a: jax.Array, x: jax.Array, sweeps: int) -> jax.Array:
     a32 = a.astype(jnp.float32)
     x32 = x.astype(jnp.float32)
     eye2 = 2.0 * jnp.eye(a.shape[0], dtype=jnp.float32)
+    hi = dot_precision(jnp.float32)
     for _ in range(sweeps):
-        x32 = x32 @ (eye2 - a32 @ x32)
+        x32 = jnp.matmul(x32, eye2 - jnp.matmul(a32, x32, precision=hi),
+                         precision=hi)
     return x32
 
 
@@ -270,7 +273,7 @@ class SpinService:
                  spill_dir: str | None = None,
                  metrics_window: int = 4096,
                  clock=time.monotonic,
-                 compile_cache: str | bool | None = None,
+                 compile_cache: bool = True,
                  precision=None):
         from repro.compat import enable_compilation_cache
         from repro.planner import RefactorPolicy  # late: planner is optional
@@ -302,11 +305,12 @@ class SpinService:
         self._clock = clock
         self._metrics = ServiceMetrics(window=metrics_window, clock=clock)
         self._snapshot_task = None               # in-flight async snapshot
-        # Warm restarts: point XLA's persistent compilation cache at a dir
-        # (explicit str, or $SPIN_COMPILE_CACHE; False disables even that).
-        self.compile_cache_dir = (
-            None if compile_cache is False else enable_compilation_cache(
-                compile_cache if isinstance(compile_cache, str) else None))
+        # Warm restarts: XLA's persistent compilation cache, in
+        # $JAX_COMPILATION_CACHE_DIR or the fixed in-checkout default
+        # (compat.enable_compilation_cache); compile_cache=False leaves
+        # the process's cache configuration alone.
+        self.compile_cache_dir = (enable_compilation_cache()
+                                  if compile_cache else None)
         self._free: deque[int] = deque(range(slots))
         self._live: dict[int, SolveRequest] = {}
         self._queue: deque = deque()

@@ -212,7 +212,12 @@ def test_sharded_spin_parity_and_mesh_residency(devices, mesh_shape):
             out["ledger_records"] = len(recs)
             out["jaxpr_constraints"] = count_sharding_constraints(
                 jax.make_jaxpr(fn)(Ab).jaxpr)
-            out["lowering_sharded_ops"] = lowered.as_text().count("devices=")
+            # Shardy prints each constraint as
+            # `sdy.sharding_constraint %x <@mesh, [{"data"}, {"model"}, ...]>`
+            out["lowering_sharded_ops"] = sum(
+                1 for line in lowered.as_text().splitlines()
+                if "sdy.sharding_constraint" in line
+                and ('{"data"}' in line or '{"model"}' in line))
 
             # (b) dtype-aware parity with the dense path, per engine
             for eng in ("einsum", "allgather", "ring"):

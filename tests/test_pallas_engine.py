@@ -4,8 +4,6 @@ sharded), plus the planner integration — enumeration gating, cost-model
 pricing, and engine="pallas" plans round-tripping the schema-v2 cache with
 the mesh/placement key respected."""
 
-import os
-
 import jax
 import jax.numpy as jnp
 import pytest
@@ -147,27 +145,40 @@ def test_sharded_entry_points_accept_pallas_off_mesh():
 
 
 def test_interpret_env_flag_forces_interpret(monkeypatch):
-    monkeypatch.setenv(PALLAS_INTERPRET_ENV, "1")
-    assert pallas_interpret_default() is True
-    monkeypatch.setenv(PALLAS_INTERPRET_ENV, "0")
-    # flag off -> backend decides (CPU test runners are off-TPU: interpret)
-    expected = jax.default_backend() != "tpu"
-    assert pallas_interpret_default() is expected
+    """The backend alone decides: interpret off-TPU whatever the flag says,
+    compiled on TPU — where a set flag is refused, never obeyed."""
+    for value in ("1", "0", None):
+        if value is None:
+            monkeypatch.delenv(PALLAS_INTERPRET_ENV, raising=False)
+        else:
+            monkeypatch.setenv(PALLAS_INTERPRET_ENV, value)
+        assert pallas_interpret_default() is True     # CPU test runner
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for value in ("1", "true", "0"):
+        monkeypatch.setenv(PALLAS_INTERPRET_ENV, value)
+        with pytest.raises(RuntimeError, match=PALLAS_INTERPRET_ENV):
+            pallas_interpret_default()
     monkeypatch.delenv(PALLAS_INTERPRET_ENV)
-    assert pallas_interpret_default() is expected
-    # and the kernels still produce correct results under the forced flag
-    monkeypatch.setenv(PALLAS_INTERPRET_ENV, "true")
+    assert pallas_interpret_default() is False
+    monkeypatch.undo()
+    # and the kernels still produce correct results in interpret mode
     from repro.kernels.matmul import ops as mm_ops
 
     a = jax.random.normal(jax.random.PRNGKey(8), (32, 32))
-    assert jnp.allclose(mm_ops.matmul(a, a), a @ a, atol=1e-4)
+    want = jnp.matmul(a, a, precision=jax.lax.Precision.HIGHEST)
+    assert jnp.allclose(mm_ops.matmul(a, a), want, atol=1e-4)
 
 
-def test_ci_interpret_job_env_is_inherited():
-    """When the pallas-interpret CI job exports the flag, this suite runs
-    fully interpreted — assert the policy sees it (no-op locally)."""
-    if os.environ.get(PALLAS_INTERPRET_ENV, "").lower() in ("1", "true"):
-        assert pallas_interpret_default() is True
+def test_ci_interpret_job_env_is_inherited(monkeypatch):
+    """The CI job's exported flag forces the optional Pallas routes
+    off-TPU; on a TPU backend the same environment is an error."""
+    from repro.kernels import pallas_routes_forced
+
+    monkeypatch.setenv(PALLAS_INTERPRET_ENV, "1")
+    assert pallas_routes_forced() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="compiled on TPU"):
+        pallas_routes_forced()
 
 
 # ------------------------------------------------------------ planner wiring
@@ -176,7 +187,7 @@ def test_ci_interpret_job_env_is_inherited():
 def test_pallas_enumeration_gated_by_backend():
     """pallas is enumerated by default on TPU signatures, opt-in elsewhere
     (interpret mode must never be auto-measured on CPU sweeps)."""
-    tpu = signature_for("inverse", 256, jnp.float32, backend="tpu",
+    tpu = signature_for("inverse", 256, jnp.float32, backend="tpu", device_kind="TPU v5 lite",
                         device_count=1, cores=1)
     assert "pallas" in {p.multiply_engine for p in enumerate_plans(tpu)}
     cpu = signature_for("inverse", 256, jnp.float32, backend="cpu",
@@ -198,7 +209,7 @@ def test_predict_cost_credits_fused_update_on_tpu():
     """The roofline charges XLA engines the Schur-update subtract traffic;
     the fused kernel is exempt, so pallas must model strictly cheaper for
     b > 1 and identical at b = 1 (no multiplies to fuse)."""
-    sig = signature_for("inverse", 1 << 14, jnp.float32, backend="tpu",
+    sig = signature_for("inverse", 1 << 14, jnp.float32, backend="tpu", device_kind="TPU v5 lite",
                         device_count=16, cores=16)
     n = sig.n
     pal = predict_cost(sig, Plan(block_size=n // 8, multiply_engine="pallas"))

@@ -32,7 +32,7 @@ def test_enumerate_plans_single_device_has_no_summa_engines():
 
 
 def test_enumerate_plans_multi_device_and_refinement():
-    sig = signature_for("inverse", 256, jnp.float32, backend="tpu",
+    sig = signature_for("inverse", 256, jnp.float32, backend="tpu", device_kind="TPU v5 lite",
                         device_count=4, cores=4)
     plans = enumerate_plans(sig)
     assert {p.multiply_engine for p in plans} == {"einsum", "allgather",
@@ -49,6 +49,43 @@ def test_enumerate_plans_fixed_block_size():
     sig = signature_for("inverse", 256, jnp.float32)
     plans = enumerate_plans(sig, block_sizes=(64,))
     assert plans and all(p.block_size == 64 for p in plans)
+
+
+def test_tpu_plans_offer_no_leaf_kernel_that_cannot_compile():
+    """On a TPU signature a kernel-backed leaf solver is offered only up to
+    the block size its kernel compiles at (tests/test_tpu_compile.py);
+    the XLA leaves are offered at every size, and off-TPU (interpret mode,
+    no VMEM) nothing is gated."""
+    from repro.kernels.leaf_inverse.kernel import max_block_size
+
+    n = 1 << 14
+    kernels = {"inverse": {"pallas": "pallas", "gauss_jordan": "gauss_jordan"},
+               "solve": {"pallas": "triangular_solve",
+                         "gauss_jordan": "gauss_jordan"}}
+    for kind, leaf_kernel in kernels.items():
+        sig = signature_for(kind, n, jnp.float32, backend="tpu",
+                            device_kind="TPU v5 lite", device_count=1,
+                            cores=1)
+        plans = enumerate_plans(sig)
+        for p in plans:
+            if p.leaf_solver in leaf_kernel:
+                assert p.block_size <= max_block_size(
+                    leaf_kernel[p.leaf_solver]), (kind, p)
+        offered = {(p.block_size, p.leaf_solver) for p in plans}
+        assert (4096, "linalg") in offered
+        assert (4096, "gauss_jordan") not in offered
+        assert (1024, "gauss_jordan") in offered
+    cpu = signature_for("inverse", n, jnp.float32, backend="cpu",
+                        device_count=1, cores=8)
+    assert (4096, "pallas") in {(p.block_size, p.leaf_solver)
+                                for p in enumerate_plans(cpu)}
+
+
+def test_tpu_pricing_needs_a_known_chip_kind():
+    sig = signature_for("inverse", 4096, jnp.float32, backend="tpu",
+                        device_kind="TPU v99", device_count=1, cores=1)
+    with pytest.raises(ValueError, match="no published peaks"):
+        predict_cost(sig, Plan(block_size=1024))
 
 
 # ----------------------------------------------------------- cost model
@@ -82,7 +119,7 @@ def test_tpu_ranking_recurses_instead_of_single_leaf():
     b=1 (one whole-matrix serial inversion) ranks first at every n and
     auto=True never recurses on TPU."""
     for n in (1 << 13, 1 << 15):
-        sig = signature_for("inverse", n, jnp.float32, backend="tpu",
+        sig = signature_for("inverse", n, jnp.float32, backend="tpu", device_kind="TPU v5 lite",
                             device_count=256, cores=256)
         best = rank_plans(sig, enumerate_plans(sig, max_grid=256))[0]
         assert best.grid(n) > 1, f"n={n} planned a single serial leaf"
@@ -92,14 +129,14 @@ def test_solve_plans_never_enumerate_refinement():
     """Newton-Schulz polishes an inverse; execute_solve has no refinement
     stage, so enumerating refined solve plans would cache plans describing
     an execution that never happens."""
-    sig = signature_for("solve", 4096, jnp.float32, backend="tpu",
+    sig = signature_for("solve", 4096, jnp.float32, backend="tpu", device_kind="TPU v5 lite",
                         device_count=256, cores=256)
     assert not any(p.refine_sweeps for p in
                    enumerate_plans(sig, include_refinement=True))
 
 
 def test_predict_cost_tpu_ring_overlap_wins_at_scale():
-    sig = signature_for("inverse", 1 << 15, jnp.float32, backend="tpu",
+    sig = signature_for("inverse", 1 << 15, jnp.float32, backend="tpu", device_kind="TPU v5 lite",
                         device_count=256, cores=256)
     ring = predict_cost(sig, Plan(block_size=(1 << 15) // 16,
                                   multiply_engine="ring"))

@@ -126,7 +126,7 @@ def test_planner_tpu_auto_prefers_bf16_store():
     """The acceptance criterion: on a TPU-backend signature (no hardware
     needed — pure cost model), auto precision makes the cheapest plan a
     bf16-stored one, because bf16 halves the memory-bound serve roofline."""
-    sig = signature_for("inverse", 4096, jnp.float32, backend="tpu",
+    sig = signature_for("inverse", 4096, jnp.float32, backend="tpu", device_kind="TPU v5 lite",
                         device_count=4, cores=4, precision="auto")
     plans = enumerate_plans(sig)
     assert {p.store_dtype for p in plans} == {"", "bfloat16"}

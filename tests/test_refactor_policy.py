@@ -41,7 +41,7 @@ def test_smw_update_cost_scales_linearly_in_rank():
     assert c1 > 0
     assert c8 == pytest.approx(8 * c1, rel=0.05)   # k³ term is negligible
     # TPU pricing exists and is roofline-positive too
-    tpu = signature_for("inverse", 512, jnp.float32, backend="tpu",
+    tpu = signature_for("inverse", 512, jnp.float32, backend="tpu", device_kind="TPU v5 lite",
                         device_count=4, cores=4)
     assert smw_update_cost(tpu, 8) > 0
 

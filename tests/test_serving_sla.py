@@ -377,31 +377,37 @@ def test_async_snapshot_requires_quiesced_service():
 
 
 def test_enable_compilation_cache_wiring(tmp_path, monkeypatch):
-    """The compat shim points XLA's persistent cache at the dir (creating
-    it), actually produces cache entries on the next compile — even when
-    enabled AFTER earlier compilations latched the cache module off — and
-    is a no-op without an explicit dir or $SPIN_COMPILE_CACHE."""
+    """The cache lands in $JAX_COMPILATION_CACHE_DIR when it is set and in
+    the fixed in-checkout default otherwise; entries are produced on the
+    next compile even when enabled AFTER earlier compilations latched the
+    cache module; the service turns it on unless told not to."""
     import os
+    import pathlib
 
-    from repro.compat import enable_compilation_cache
+    from repro import compat
 
-    monkeypatch.delenv("SPIN_COMPILE_CACHE", raising=False)
-    assert enable_compilation_cache() is None            # opt-in only
     cache_dir = str(tmp_path / "xla-cache")
+    was = jax.config.jax_compilation_cache_dir
     try:
-        assert enable_compilation_cache(cache_dir) == cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
+        assert compat.enable_compilation_cache() == cache_dir
+        assert jax.config.jax_compilation_cache_dir == cache_dir
         assert os.path.isdir(cache_dir)
         jax.jit(lambda x: x * 3.0 + 1.0)(
             jnp.ones((16, 16))).block_until_ready()
         assert len(os.listdir(cache_dir)) > 0            # entries landed
-        # env-var path: service constructor picks it up
-        monkeypatch.setenv("SPIN_COMPILE_CACHE", cache_dir)
-        svc = SpinService(slots=1)
-        assert svc.compile_cache_dir == cache_dir
+        assert SpinService(slots=1).compile_cache_dir == cache_dir
         assert SpinService(slots=1, compile_cache=False).compile_cache_dir \
             is None                                      # explicit off
-    finally:                     # don't leak cache writes into later tests
-        jax.config.update("jax_compilation_cache_dir", None)
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        default = compat.DEFAULT_COMPILATION_CACHE_DIR
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        assert pathlib.Path(default) == repo / ".jax_cache"
+        assert compat.enable_compilation_cache() == default
+        assert jax.config.jax_compilation_cache_dir == default
+    finally:                     # restore the process's cache directory
+        jax.config.update("jax_compilation_cache_dir", was)
         from jax.experimental.compilation_cache import (
             compilation_cache as cc)
 

@@ -327,6 +327,10 @@ def test_pallas_base_composition_interpret(monkeypatch):
     assert st_ops.pallas_base_default()
     assert st_ops._leaf_engine(128) == "pallas"
     assert st_ops._leaf_engine(576) == "einsum"   # not Mosaic-legal
+    with monkeypatch.context() as tpu:      # the flag is refused on a TPU
+        tpu.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match=PALLAS_INTERPRET_ENV):
+            st_ops.pallas_base_default()
     g, bs = 4, 32                                 # leaves flatten to 64
     n = g * bs
     ka, kb = jax.random.split(jax.random.PRNGKey(11))

@@ -1,0 +1,125 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e.
+
+Nothing runs: each test lowers a kernel at a real width for one chip of a
+described `v5e:2x2` topology and compiles it with the TPU compiler, which
+refuses what interpret mode accepts (unaligned slices, unlowerable
+primitives, more VMEM than the kernel may scope). Each kernel must come
+out as a Mosaic `tpu_custom_call`. The leaf kernels compile at the largest
+block size the planner may offer them on a TPU signature
+(`kernel.max_block_size`), so a plan the planner offers is a plan that
+compiles.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time, and every test worker imports
+this file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.leaf_inverse import kernel as leaf
+from repro.kernels.matmul import kernel as mm
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it out of the cache.
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compiled_text(fn, *shapes, sharding) -> str:
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
+            for s in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_schur_update_compiles_at_2048(one_chip):
+    n = 2048
+    text = _compiled_text(
+        lambda c, a, b: mm.schur_update_pallas(
+            c, a, b, tiles=mm.auto_tiles(n, n, n)),
+        (n, n), (n, n), (n, n), sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernel,fn", [
+    ("gauss_jordan", leaf.leaf_inverse_pallas),
+    ("pallas", leaf.blocked_leaf_inverse_pallas),
+])
+def test_leaf_kernel_compiles_at_planner_max(kernel, fn, one_chip):
+    bs = leaf.max_block_size(kernel)
+    assert bs >= 512
+    text = _compiled_text(fn, (1, bs, bs), sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+def test_triangular_solve_compiles(one_chip):
+    text = _compiled_text(leaf.triangular_solve_pallas, (1, 512, 512),
+                          (1, 512, 64), sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+def test_triangular_solve_compiles_at_planner_max_with_wide_rhs(one_chip):
+    # The recursion's leaf solve sees up to ~n right-hand-side columns;
+    # they are tiled, so only the T block scales the VMEM bill.
+    bs = leaf.max_block_size("triangular_solve")
+    text = _compiled_text(leaf.triangular_solve_pallas, (1, bs, bs),
+                          (1, bs, 1000), sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+def test_sharded_pallas_recursion_compiles_on_a_four_chip_mesh(
+        topo, monkeypatch):
+    """The mesh-resident recursion with the Pallas engine and leaf, for a
+    described (2, 2) v5e mesh. The TPU lowering refuses a Mosaic kernel in
+    the automatically partitioned part of a mesh program, so the leaf
+    inversions and the multiplies whose grid no longer divides the mesh
+    must run inside a shard_map (`kernels.mesh_safe`)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.compat import set_mesh
+    from repro.kernels.leaf_inverse import ops as leaf_ops
+    from repro.kernels.matmul import ops as mm_ops
+    from repro.parallel.sharded_blockmatrix import (_inverse_program,
+                                                    mesh_fingerprint)
+
+    # The wrappers ask the (CPU) backend whether to interpret: compile.
+    monkeypatch.setattr(leaf_ops, "pallas_interpret_default", lambda: False)
+    monkeypatch.setattr(mm_ops, "pallas_interpret_default", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    grid, bs = 4, 512
+    blocks = jax.ShapeDtypeStruct(
+        (grid, grid, bs, bs), jnp.float32,
+        sharding=NamedSharding(mesh, P("data", "model", None, None)))
+    with set_mesh(mesh):
+        text = _inverse_program.lower(
+            blocks, "pallas", "pallas", ("data", "model"),
+            mesh_fingerprint()).compile().as_text()
+    assert "tpu_custom_call" in text
