@@ -35,15 +35,17 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import LAYOUT
 from repro.obs.trace import TRACER as _TRACER
+from repro.obs.trace import hlo_op_scopes, host_span, level_scope, step_scope
 
 from .blockmatrix import BlockMatrix, _bump
-from .multiply import (multiply, multiply_engine, multiply_subtract,
-                       subtract_multiply, validate_engine)
+from .multiply import (current_engine, multiply, multiply_engine,
+                       multiply_subtract, subtract_multiply, validate_engine)
 from .precision import dot_precision
 
 __all__ = ["spin_inverse", "spin_inverse_dense", "spin_inverse_sharded",
-           "leaf_inverse", "LEAF_SOLVERS"]
+           "leaf_inverse", "LEAF_SOLVERS", "inverse_op_scopes"]
 
 
 # ---------------------------------------------------------------------------
@@ -148,11 +150,11 @@ def spin_inverse(a: BlockMatrix, *, leaf_solver: str = "linalg",
     f32, and returns blocks at the policy's store dtype; the default is
     bitwise-unchanged.
 
-    `_level` threads the recursion depth to the span tracer (repro.obs):
-    under $SPIN_TRACE each internal node and leaf emits a
-    kind="recursion_level" span at trace time. With tracing off the only
-    cost is one attribute check per node — nothing reaches the compiled
-    program either way.
+    `_level` is the recursion depth. Each internal node runs under the
+    named scope `spin.L<_level>` with one scope per step, and a leaf under
+    `spin.L<_level>/leaf` (repro.obs.trace; HLO metadata only, always on).
+    Under $SPIN_TRACE each node and leaf also records a
+    kind="recursion_level" point event at trace time.
     """
     if auto:
         from repro.planner import planned_leaf_solver
@@ -173,37 +175,42 @@ def spin_inverse(a: BlockMatrix, *, leaf_solver: str = "linalg",
                           grid=1, op="leaf", solver=leaf_solver,
                           block_size=a.block_size,
                           dtype=str(a.blocks.dtype))
-        return leaf_inverse(a, solver=leaf_solver)
+        with level_scope(_level), step_scope("leaf"):
+            return leaf_inverse(a, solver=leaf_solver)
 
     if _TRACER.enabled:
-        from .multiply import current_engine
-
-        span_ctx = _TRACER.span(
-            "spin.level", "recursion_level", named_scope=True,
-            level=_level, grid=b, op="inverse_node",
-            block_size=a.block_size, dtype=str(a.blocks.dtype),
-            engine=current_engine() or "einsum")
-    else:
-        span_ctx = contextlib.nullcontext()
-    with span_ctx:
-        a11, a12, a21, a22 = a.split()
+        _TRACER.event("spin.level", "recursion_level", level=_level, grid=b,
+                      op="inverse_node", block_size=a.block_size,
+                      dtype=str(a.blocks.dtype),
+                      engine=current_engine() or "einsum")
+    with level_scope(_level):
+        with step_scope("split"):
+            a11, a12, a21, a22 = a.split()
         i_ = spin_inverse(a11, leaf_solver=leaf_solver,
                           _level=_level + 1)              # I   = A11^-1
-        ii = multiply(a21, i_)                            # II  = A21 I
-        iii = multiply(i_, a12)                           # III = I A12
+        with step_scope("II"):
+            ii = multiply(a21, i_)                        # II  = A21 I
+        with step_scope("III"):
+            iii = multiply(i_, a12)                       # III = I A12
         # IV = A21·III and V = IV − A22 (= −Schur) as ONE fused Schur
         # update: bitwise-identical multiply-then-subtract on the XLA
         # engines, a single Pallas kernel under engine="pallas". Op counts
         # book 1 multiply + 1 subtract either way.
-        v = multiply_subtract(a21, iii, a22)
+        with step_scope("schur"):
+            v = multiply_subtract(a21, iii, a22)
         vi = spin_inverse(v, leaf_solver=leaf_solver,
                           _level=_level + 1)              # VI  = V^-1
-        c12 = multiply(iii, vi)
-        c21 = multiply(vi, ii)
+        with step_scope("C12"):
+            c12 = multiply(iii, vi)
+        with step_scope("C21"):
+            c21 = multiply(vi, ii)
         # VII = III·C21 and C11 = I − VII, same fused Schur-update contract.
-        c11 = subtract_multiply(i_, iii, c21)
-        c22 = vi.neg()                                    # scalarMul(VI, -1)
-        return BlockMatrix.arrange(c11, c12, c21, c22)
+        with step_scope("C11"):
+            c11 = subtract_multiply(i_, iii, c21)
+        with step_scope("neg"):
+            c22 = vi.neg()                                # scalarMul(VI, -1)
+        with step_scope("arrange"):
+            return BlockMatrix.arrange(c11, c12, c21, c22)
 
 
 @functools.partial(jax.jit,
@@ -216,8 +223,50 @@ def _spin_inverse_dense(dense: jax.Array, block_size: int,
     # executable traced under one engine would silently serve another.
     ctx = multiply_engine(engine) if engine else contextlib.nullcontext()
     with ctx:
-        a = BlockMatrix.from_dense(dense, block_size)
-        return spin_inverse(a, leaf_solver=leaf_solver).to_dense()
+        with step_scope(LAYOUT):
+            a = BlockMatrix.from_dense(dense, block_size)
+        x = spin_inverse(a, leaf_solver=leaf_solver)
+        with step_scope(LAYOUT):
+            return x.to_dense()
+
+
+def inverse_op_scopes(n: int, block_size: int, leaf_solver: str = "linalg",
+                      engine: str | None = None, *, mesh=None, sharding=None
+                      ) -> dict[str, dict[str, tuple]]:
+    """{module: {instruction: (level, step)}} of the inversion program that
+    `spin_inverse_dense` (or, given `mesh`, `spin_inverse_sharded`) runs for
+    an (n, n) float32 operand: the join from a profiler trace's op names to the
+    recursion's named scopes (`repro.obs.trace.op_scope`).
+
+    It lowers and compiles the same jitted entry from shapes, so once the
+    caller has run it the compile is a cache hit. The executable depends on
+    where the operand lives: give the `sharding` of the caller's operand
+    when it was committed to a device (`jax.device_put`, or made there),
+    None when it was not; on a mesh the blocks carry the recursion's grid
+    sharding, which is used in its place. The instruction names are those of the executable
+    the backend built, which are the names a device trace gives its ops.
+    """
+    engine = engine or current_engine()
+    if mesh is None:
+        shape = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=sharding)
+        lowered = _spin_inverse_dense.lower(shape, block_size, leaf_solver,
+                                            engine)
+    else:
+        from jax.sharding import NamedSharding
+
+        from repro.compat import set_mesh
+        from repro.parallel.sharded_blockmatrix import (_inverse_program,
+                                                        grid_spec,
+                                                        mesh_fingerprint)
+
+        grid, axes = n // block_size, ("data", "model")
+        sharding = NamedSharding(mesh, grid_spec(grid, grid, mesh, axes))
+        blocks = jax.ShapeDtypeStruct((grid, grid, block_size, block_size),
+                                      jnp.float32, sharding=sharding)
+        with set_mesh(mesh):
+            lowered = _inverse_program.lower(blocks, leaf_solver, engine,
+                                             axes, mesh_fingerprint())
+    return hlo_op_scopes(lowered.compile().as_text())
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "engine",
@@ -272,31 +321,34 @@ def spin_inverse_dense(dense: jax.Array, block_size: int | None = None,
     rides the planner signature so the plan is priced (and cached) per
     policy. `compute_dtype=` is the deprecated pre-policy spelling and
     forwards to an equivalent policy with a one-time warning.
+
+    The call runs inside the always-on host span `spin.inverse_dense`
+    (argument and precision resolution, and the jit dispatch), on the
+    profiler's clock.
     """
-    validate_engine(engine)
-    from .precision import resolve_precision
+    with host_span("spin.inverse_dense"):
+        validate_engine(engine)
+        from .precision import resolve_precision
 
-    if compute_dtype is not None:
-        from .precision import (policy_from_compute_dtype,
-                                warn_deprecated_dtype_kwarg)
+        if compute_dtype is not None:
+            from .precision import (policy_from_compute_dtype,
+                                    warn_deprecated_dtype_kwarg)
 
-        warn_deprecated_dtype_kwarg("spin_inverse_dense")
-        if precision is None:
-            precision = policy_from_compute_dtype(compute_dtype)
-    policy = resolve_precision(precision)
-    if auto or block_size is None:
-        from repro.planner import plan_inverse
+            warn_deprecated_dtype_kwarg("spin_inverse_dense")
+            if precision is None:
+                precision = policy_from_compute_dtype(compute_dtype)
+        policy = resolve_precision(precision)
+        if auto or block_size is None:
+            from repro.planner import plan_inverse
 
-        if policy.is_exact:
-            return plan_inverse(dense)
-        return plan_inverse(dense, precision=policy)
-    from .multiply import current_engine
-
-    if not policy.is_exact and _policy_active(policy, dense.dtype):
-        return _lowp_inverse_dense(dense, block_size, leaf_solver,
-                                   engine or current_engine(), policy)
-    return _spin_inverse_dense(dense, block_size, leaf_solver,
-                               engine or current_engine())
+            if policy.is_exact:
+                return plan_inverse(dense)
+            return plan_inverse(dense, precision=policy)
+        if not policy.is_exact and _policy_active(policy, dense.dtype):
+            return _lowp_inverse_dense(dense, block_size, leaf_solver,
+                                       engine or current_engine(), policy)
+        return _spin_inverse_dense(dense, block_size, leaf_solver,
+                                   engine or current_engine())
 
 
 def _resolve_sharded_config(kind: str, a, block_size: int | None,
@@ -362,51 +414,54 @@ def spin_inverse_sharded(a, block_size: int | None = None, *,
     schedule). The coded path takes a dense (n, n) or BlockMatrix operand
     and returns a dense inverse — it is a per-panel execution model, not
     the single-program mesh recursion.
+
+    The call runs inside the always-on host span `spin.inverse_sharded`.
     """
-    from repro.parallel.sharded_blockmatrix import inverse_program
+    with host_span("spin.inverse_sharded"):
+        from repro.parallel.sharded_blockmatrix import inverse_program
 
-    validate_engine(engine)
-    if precision is not None:
-        from repro.parallel.sharded_blockmatrix import ShardedBlockMatrix
+        validate_engine(engine)
+        if precision is not None:
+            from repro.parallel.sharded_blockmatrix import ShardedBlockMatrix
 
-        from .precision import resolve_precision
+            from .precision import resolve_precision
 
-        policy = resolve_precision(precision)
-        dense_in = not isinstance(a, (BlockMatrix, ShardedBlockMatrix))
-        if not policy.is_exact and _policy_active(
-                policy, a.dtype if dense_in else a.blocks.dtype):
-            if not dense_in:
+            policy = resolve_precision(precision)
+            dense_in = not isinstance(a, (BlockMatrix, ShardedBlockMatrix))
+            if not policy.is_exact and _policy_active(
+                    policy, a.dtype if dense_in else a.blocks.dtype):
+                if not dense_in:
+                    raise ValueError(
+                        "low-precision policies on the sharded path need a "
+                        "dense operand (cast-in/cast-out semantics); got "
+                        f"{type(a).__name__}")
+                # Cast-in / cast-out: the mesh recursion has no polish stage,
+                # so the sharded low-precision contract is compute-dtype only.
+                cd = policy.resolve_compute(a.dtype)
+                out = spin_inverse_sharded(a.astype(cd), block_size,
+                                           leaf_solver=leaf_solver,
+                                           engine=engine, auto=auto,
+                                           coded=coded, fault_plan=fault_plan)
+                return out.astype(policy.resolve_store(a.dtype))
+        if coded is not None:
+            from repro.parallel.sharded_blockmatrix import ShardedBlockMatrix
+            from repro.parallel.straggler import coded_inverse
+
+            if isinstance(a, ShardedBlockMatrix):
                 raise ValueError(
-                    "low-precision policies on the sharded path need a "
-                    "dense operand (cast-in/cast-out semantics); got "
-                    f"{type(a).__name__}")
-            # Cast-in / cast-out: the mesh recursion has no polish stage,
-            # so the sharded low-precision contract is compute-dtype only.
-            cd = policy.resolve_compute(a.dtype)
-            out = spin_inverse_sharded(a.astype(cd), block_size,
-                                       leaf_solver=leaf_solver,
-                                       engine=engine, auto=auto,
-                                       coded=coded, fault_plan=fault_plan)
-            return out.astype(policy.resolve_store(a.dtype))
-    if coded is not None:
-        from repro.parallel.sharded_blockmatrix import ShardedBlockMatrix
-        from repro.parallel.straggler import coded_inverse
+                    "coded execution assembles the inverse from worker panels "
+                    "and needs a dense or BlockMatrix operand, not a "
+                    "mesh-resident ShardedBlockMatrix")
+            dense = a.to_dense() if isinstance(a, BlockMatrix) else a
+            bs = block_size or (a.block_size if isinstance(a, BlockMatrix)
+                                else None)
+            inv, _ = coded_inverse(dense, coded, block_size=bs,
+                                   leaf_solver=leaf_solver or "linalg",
+                                   engine=engine, sharded=True,
+                                   fault_plan=fault_plan)
+            return inv
 
-        if isinstance(a, ShardedBlockMatrix):
-            raise ValueError(
-                "coded execution assembles the inverse from worker panels "
-                "and needs a dense or BlockMatrix operand, not a "
-                "mesh-resident ShardedBlockMatrix")
-        dense = a.to_dense() if isinstance(a, BlockMatrix) else a
-        bs = block_size or (a.block_size if isinstance(a, BlockMatrix)
-                            else None)
-        inv, _ = coded_inverse(dense, coded, block_size=bs,
-                               leaf_solver=leaf_solver or "linalg",
-                               engine=engine, sharded=True,
-                               fault_plan=fault_plan)
-        return inv
-
-    a, leaf_solver, engine, dense_in = _resolve_sharded_config(
-        "inverse", a, block_size, leaf_solver, engine, auto)
-    out = inverse_program(a, leaf_solver=leaf_solver, engine=engine)
-    return out.to_dense() if dense_in else out
+        a, leaf_solver, engine, dense_in = _resolve_sharded_config(
+            "inverse", a, block_size, leaf_solver, engine, auto)
+        out = inverse_program(a, leaf_solver=leaf_solver, engine=engine)
+        return out.to_dense() if dense_in else out

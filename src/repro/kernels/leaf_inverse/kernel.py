@@ -172,6 +172,7 @@ def leaf_inverse_pallas(blocks: jax.Array, interpret: bool = False,
         compiler_params=_compiler_params(
             leaf_inverse_vmem_bytes(bs, itemsize), ("parallel",)),
         interpret=interpret,
+        name="leaf_inverse_pallas",
     )(blocks)
 
 
@@ -297,6 +298,7 @@ def blocked_leaf_inverse_pallas(blocks: jax.Array, panel: int | None = None,
         compiler_params=_compiler_params(
             blocked_leaf_inverse_vmem_bytes(bs, itemsize, t), ("parallel",)),
         interpret=interpret,
+        name="blocked_leaf_inverse_pallas",
     )(blocks)
 
 
@@ -414,5 +416,6 @@ def triangular_solve_pallas(t: jax.Array, b: jax.Array,
             triangular_solve_vmem_bytes(bs, itemsize, kt, tp),
             ("parallel", "parallel")),
         interpret=interpret,
+        name="triangular_solve_pallas",
     )(t, bp)
     return x if kp == k else x[:, :, :k]

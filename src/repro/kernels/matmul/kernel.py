@@ -109,6 +109,7 @@ def matmul_pallas(a: jax.Array, b: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="matmul_pallas",
     )(a, b)
 
 
@@ -172,4 +173,5 @@ def schur_update_pallas(c: jax.Array, a: jax.Array, b: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="schur_update_pallas",
     )(c, a, b)

@@ -49,6 +49,7 @@ from repro.core.blockmatrix import BlockMatrix, _bump
 from repro.core.multiply import (current_engine, multiply_blocks,
                                  multiply_engine)
 from repro.core.precision import dot_precision
+from repro.obs.trace import LAYOUT, level_scope, step_scope
 
 __all__ = [
     "ShardedBlockMatrix", "SpecRecord", "record_specs",
@@ -380,33 +381,48 @@ class ShardedBlockMatrix:
 # ---------------------------------------------------------------------------
 
 
-def sharded_spin_inverse(a: ShardedBlockMatrix, leaf_solver: str = "linalg"
-                         ) -> ShardedBlockMatrix:
+def sharded_spin_inverse(a: ShardedBlockMatrix, leaf_solver: str = "linalg",
+                         _level: int = 0) -> ShardedBlockMatrix:
     """Algorithm-2 recursion with every intermediate pinned to the mesh.
 
     Identical op sequence to `core.spin.spin_inverse` (the op-count oracle
-    holds level for level); the only difference is the sharding constraint
-    each op re-asserts, so quadrants stay device-resident between levels.
+    holds level for level) under the same named scopes (`spin.L<_level>`
+    and its steps, repro.obs.trace); the only difference is the sharding
+    constraint each op re-asserts, so quadrants stay device-resident
+    between levels.
     """
     b = a.grid
     if b & (b - 1):
         raise ValueError(f"grid must be a power of two, got {b}")
     if b == 1:
-        return a.leaf_inverse(leaf_solver)
+        with level_scope(_level), step_scope("leaf"):
+            return a.leaf_inverse(leaf_solver)
 
-    a11, a12, a21, a22 = a.split()
-    i_ = sharded_spin_inverse(a11, leaf_solver)           # I   = A11^-1
-    ii = a21.multiply(i_)                                 # II  = A21 I
-    iii = i_.multiply(a12)                                # III = I A12
-    iv = a21.multiply(iii)                                # IV  = A21 III
-    v = iv.subtract(a22)                                  # V   = IV - A22
-    vi = sharded_spin_inverse(v, leaf_solver)             # VI  = V^-1
-    c12 = iii.multiply(vi)
-    c21 = vi.multiply(ii)
-    vii = iii.multiply(c21)
-    c11 = i_.subtract(vii)
-    c22 = vi.neg()                                        # scalarMul(VI, -1)
-    return ShardedBlockMatrix.arrange(c11, c12, c21, c22)
+    with level_scope(_level):
+        with step_scope("split"):
+            a11, a12, a21, a22 = a.split()
+        i_ = sharded_spin_inverse(a11, leaf_solver,
+                                  _level + 1)             # I   = A11^-1
+        with step_scope("II"):
+            ii = a21.multiply(i_)                         # II  = A21 I
+        with step_scope("III"):
+            iii = i_.multiply(a12)                        # III = I A12
+        with step_scope("schur"):
+            iv = a21.multiply(iii)                        # IV  = A21 III
+            v = iv.subtract(a22)                          # V   = IV - A22
+        vi = sharded_spin_inverse(v, leaf_solver,
+                                  _level + 1)             # VI  = V^-1
+        with step_scope("C12"):
+            c12 = iii.multiply(vi)
+        with step_scope("C21"):
+            c21 = vi.multiply(ii)
+        with step_scope("C11"):
+            vii = iii.multiply(c21)
+            c11 = i_.subtract(vii)
+        with step_scope("neg"):
+            c22 = vi.neg()                                # scalarMul(VI, -1)
+        with step_scope("arrange"):
+            return ShardedBlockMatrix.arrange(c11, c12, c21, c22)
 
 
 def _apply_blocks_sharded(a: ShardedBlockMatrix, x: jax.Array) -> jax.Array:
@@ -513,7 +529,8 @@ def _inverse_program(blocks: jax.Array, leaf_solver: str,
                      mesh_fp: str) -> jax.Array:
     ctx = multiply_engine(engine) if engine else contextlib.nullcontext()
     with ctx:
-        a = ShardedBlockMatrix(blocks, axes).constrain("input")
+        with step_scope(LAYOUT):
+            a = ShardedBlockMatrix(blocks, axes).constrain("input")
         return sharded_spin_inverse(a, leaf_solver).blocks
 
 

@@ -2,9 +2,9 @@
 
 The hard requirement on `repro.obs.trace` (DESIGN.md §13): with
 `SPIN_TRACE` off, instrumentation must not change the compiled program —
-no extra equations, no callbacks, no host syncs. With it on, the bridging
-is metadata-only (`jax.named_scope`), so the program STILL must not gain
-equations; only host-side span records appear.
+no extra equations, no callbacks, no host syncs. With it on, only
+host-side span records appear (the recursion's named scopes are always
+on and metadata-only), so the program STILL must not gain equations.
 """
 
 import jax

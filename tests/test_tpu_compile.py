@@ -14,6 +14,8 @@ process may load the TPU library at a time, and every test worker imports
 this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -123,3 +125,68 @@ def test_sharded_pallas_recursion_compiles_on_a_four_chip_mesh(
             blocks, "pallas", "pallas", ("data", "model"),
             mesh_fingerprint()).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def _kernel_names(text: str) -> set[str]:
+    """The instruction names of the Mosaic kernels, less their `.N`."""
+    return {re.match(r"\s*(?:ROOT )?%([\w-]+?)(?:\.\d+)? = ", line).group(1)
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
+
+
+@pytest.mark.parametrize("fn,shapes,name", [
+    (lambda a, b: mm.matmul_pallas(a, b), [(1024, 1024)] * 2,
+     "matmul_pallas"),
+    (lambda c, a, b: mm.schur_update_pallas(c, a, b), [(1024, 1024)] * 3,
+     "schur_update_pallas"),
+    (lambda x: leaf.leaf_inverse_pallas(x), [(1, 256, 256)],
+     "leaf_inverse_pallas"),
+    (lambda x: leaf.blocked_leaf_inverse_pallas(x), [(1, 1024, 1024)],
+     "blocked_leaf_inverse_pallas"),
+    (lambda t, b: leaf.triangular_solve_pallas(t, b),
+     [(1, 512, 512), (1, 512, 64)], "triangular_solve_pallas"),
+])
+def test_pallas_kernels_keep_their_pinned_names(fn, shapes, name, one_chip):
+    """Each `pallas_call` passes `name=`: a trace names the kernel after it,
+    whatever function wraps it."""
+    assert _kernel_names(_compiled_text(fn, *shapes,
+                                        sharding=one_chip)) == {name}
+
+
+def test_pallas_recursion_names_its_kernels_by_step(one_chip, monkeypatch):
+    """In the recursion compiled for one chip, the kernels carry the pinned
+    names that `bench/opclasses.json` matches, and the step scopes they
+    were called in."""
+    import json
+    import pathlib
+
+    from repro.core.spin import _spin_inverse_dense
+    from repro.kernels.leaf_inverse import ops as leaf_ops
+    from repro.kernels.matmul import ops as mm_ops
+    from repro.obs.trace import hlo_op_scopes
+
+    monkeypatch.setattr(leaf_ops, "pallas_interpret_default", lambda: False)
+    monkeypatch.setattr(mm_ops, "pallas_interpret_default", lambda: False)
+    x = jax.ShapeDtypeStruct((1024, 1024), jnp.float32, sharding=one_chip)
+    text = _spin_inverse_dense.lower(x, 256, "pallas", "pallas").compile(
+        ).as_text()
+    (ops,) = hlo_op_scopes(text).values()
+    steps: dict[str, set] = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*(?:ROOT )?%(\S+) = ", line).group(1)
+            steps.setdefault(name.split(".")[0], set()).add(ops[name])
+    assert steps == {
+        "matmul_pallas": {(lv, st) for lv in (0, 1)
+                          for st in ("II", "III", "C12", "C21")},
+        "schur_update_pallas": {(lv, st) for lv in (0, 1)
+                                for st in ("schur", "C11")},
+        "blocked_leaf_inverse_pallas": {(2, "leaf")},
+    }
+    classes = json.loads((pathlib.Path(__file__).resolve().parents[1]
+                          / "bench" / "opclasses.json").read_text())
+    gemm = re.compile(classes["gemm"][1]["pattern"])
+    leaf_marker = re.compile(classes["leaf_marker"][1]["pattern"])
+    kernels = [line.strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert all(gemm.search(k) or leaf_marker.search(k) for k in kernels)
