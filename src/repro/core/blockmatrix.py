@@ -54,6 +54,9 @@ class OpCounts:
     # counters above still book each Strassen product as ONE multiply):
     strassen_base_multiplies: int = 0   # classical leaves of the recursion
     strassen_adds: int = 0              # quadrant add/sub passes (18/level)
+    # Pallas GEMM grid steps, (m/bm)·(n/bn)·(k/bk) summed over the
+    # kernels.matmul calls (engine="pallas" and its solve panels only):
+    pallas_grid_steps: int = 0
 
     def as_dict(self) -> dict[str, int]:
         return dataclasses.asdict(self)

@@ -118,7 +118,8 @@ def assert_paper_op_counts(grid: int, counts: OpCounts) -> None:
 
     Engine-blind: the Strassen-internal counters are excluded here (a
     Strassen product is still ONE Algorithm-2 multiply) and checked by
-    their own oracle, `assert_strassen_op_counts`.
+    their own oracle, `assert_strassen_op_counts`; so is the Pallas
+    kernels' grid-step count, which follows their tiles.
     """
     want = expected_spin_counts(grid)
     got = counts.as_dict()
@@ -126,7 +127,8 @@ def assert_paper_op_counts(grid: int, counts: OpCounts) -> None:
         k: (got[k], v) for k, v in want.as_dict().items()
         if k in got and got[k] != v
         and k not in ("leaf_lu", "leaf_solves", "solve_applies",
-                      "strassen_base_multiplies", "strassen_adds")
+                      "strassen_base_multiplies", "strassen_adds",
+                      "pallas_grid_steps")
     }
     if mismatches:
         raise AssertionError(

@@ -15,15 +15,37 @@ composition on CPU runners (`pallas_routes_forced`).
 
 The policy is read at trace time: already-compiled outer jit executables
 keep the value they were traced with.
+
+Every kernel that reckons its VMEM asks Mosaic for it through
+`vmem_compiler_params`, under the one `VMEM_CAP_BYTES` cap.
 """
 
 
 import jax
 
 __all__ = ["pallas_interpret_default", "pallas_routes_forced",
-           "PALLAS_INTERPRET_ENV", "mesh_safe"]
+           "PALLAS_INTERPRET_ENV", "mesh_safe", "VMEM_CAP_BYTES",
+           "vmem_compiler_params"]
 
 PALLAS_INTERPRET_ENV = "SPIN_PALLAS_INTERPRET"
+
+# Physical VMEM is 128 MiB on v5e; leave room for Mosaic's own scratch.
+VMEM_CAP_BYTES = 96 * 2**20
+
+
+def vmem_compiler_params(vmem_bytes: int, semantics: tuple[str, ...]):
+    """Mosaic compiler params asking for a kernel's reckoned VMEM plus a
+    quarter (Mosaic scopes a kernel to 16 MiB unless it asks); refuses a
+    reckoning over `VMEM_CAP_BYTES`."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if vmem_bytes > VMEM_CAP_BYTES:
+        raise ValueError(
+            f"kernel needs ~{vmem_bytes / 2**20:.0f} MiB of VMEM, over the "
+            f"{VMEM_CAP_BYTES / 2**20:.0f} MiB cap: use smaller blocks or "
+            "tiles")
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=int(vmem_bytes * 1.25))
 
 
 def pallas_interpret_default() -> bool:

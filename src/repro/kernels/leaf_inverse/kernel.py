@@ -51,6 +51,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import VMEM_CAP_BYTES, vmem_compiler_params
+
 __all__ = ["leaf_inverse_pallas", "blocked_leaf_inverse_pallas",
            "triangular_solve_pallas", "default_panel", "VMEM_CAP_BYTES",
            "leaf_inverse_vmem_bytes", "blocked_leaf_inverse_vmem_bytes",
@@ -59,18 +61,7 @@ __all__ = ["leaf_inverse_pallas", "blocked_leaf_inverse_pallas",
 _HI = jax.lax.Precision.HIGHEST     # every dot here is f32 (one-hot gathers
                                     # must not round their operand to bf16)
 
-# Physical VMEM is 128 MiB on v5e; leave room for Mosaic's own scratch.
-VMEM_CAP_BYTES = 96 * 2**20
 _ROW_CHUNK = 256                    # rows per rank-t update step
-
-
-def _compiler_params(vmem_bytes: int, semantics: tuple[str, ...]):
-    if vmem_bytes > VMEM_CAP_BYTES:
-        raise ValueError(
-            f"kernel needs ~{vmem_bytes / 2**20:.0f} MiB of VMEM, over the "
-            f"{VMEM_CAP_BYTES / 2**20:.0f} MiB cap: use a smaller block size")
-    return pltpu.CompilerParams(dimension_semantics=semantics,
-                                vmem_limit_bytes=int(vmem_bytes * 1.25))
 
 
 def leaf_inverse_vmem_bytes(bs: int, itemsize: int = 4) -> int:
@@ -169,7 +160,7 @@ def leaf_inverse_pallas(blocks: jax.Array, interpret: bool = False,
         out_specs=pl.BlockSpec((1, bs, bs), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(blocks.shape, out_dtype),
         scratch_shapes=[pltpu.VMEM((bs, 2 * bs), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=vmem_compiler_params(
             leaf_inverse_vmem_bytes(bs, itemsize), ("parallel",)),
         interpret=interpret,
         name="leaf_inverse_pallas",
@@ -295,7 +286,7 @@ def blocked_leaf_inverse_pallas(blocks: jax.Array, panel: int | None = None,
         out_specs=pl.BlockSpec((1, bs, bs), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(blocks.shape, out_dtype),
         scratch_shapes=[pltpu.VMEM((bs, 2 * bs), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=vmem_compiler_params(
             blocked_leaf_inverse_vmem_bytes(bs, itemsize, t), ("parallel",)),
         interpret=interpret,
         name="blocked_leaf_inverse_pallas",
@@ -412,7 +403,7 @@ def triangular_solve_pallas(t: jax.Array, b: jax.Array,
         out_specs=pl.BlockSpec((1, bs, kt), lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct(bp.shape, b.dtype),
         scratch_shapes=[pltpu.VMEM((bs, kt), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=vmem_compiler_params(
             triangular_solve_vmem_bytes(bs, itemsize, kt, tp),
             ("parallel", "parallel")),
         interpret=interpret,

@@ -10,6 +10,10 @@ sites run on CPU (tests, CI) and TPU (production).
 whole (bi, bk, bs, bs) block grid into its dense equivalent and contract it
 with ONE Pallas kernel (k-accumulation in f32 VMEM scratch), instead of one
 kernel per output block.
+
+`matmul` and `schur_update` resolve each call's tiles (`auto_tiles` from
+the shape and itemsizes, unless given) and book the call's grid steps as
+`pallas_grid_steps` in an active `count_ops` context (trace time only).
 """
 
 from __future__ import annotations
@@ -19,11 +23,22 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.blockmatrix import _bump
+
 from .. import mesh_safe, pallas_interpret_default
-from .kernel import auto_tiles, matmul_pallas, schur_update_pallas
+from .kernel import call_tiles, matmul_pallas, schur_update_pallas
 
 __all__ = ["matmul", "schur_update", "block_gemm", "grid_matmul",
            "grid_schur_update", "blocks_to_dense", "dense_to_blocks"]
+
+
+def _booked(tiles, a, b, out_dtype, c=None) -> tuple[int, int, int]:
+    """`kernel.call_tiles`, its grid steps booked as `pallas_grid_steps`."""
+    bm, bn, bk = call_tiles(a, b, out_dtype or (a if c is None else c).dtype,
+                            c, tiles)
+    m, k = a.shape
+    _bump("pallas_grid_steps", (m // bm) * (b.shape[-1] // bn) * (k // bk))
+    return bm, bn, bk
 
 
 def matmul(a: jax.Array, b: jax.Array,
@@ -34,12 +49,9 @@ def matmul(a: jax.Array, b: jax.Array,
     out_dtype=float32 keeps the f32 accumulator un-rounded on the flush
     even for low-precision operands (see matmul_pallas).
     """
-    m, k = a.shape
-    n = b.shape[-1]
-    tiles = tiles or auto_tiles(m, n, k)
     return mesh_safe(functools.partial(
-        matmul_pallas, tiles=tiles, interpret=pallas_interpret_default(),
-        out_dtype=out_dtype))(a, b)
+        matmul_pallas, tiles=_booked(tiles, a, b, out_dtype),
+        interpret=pallas_interpret_default(), out_dtype=out_dtype))(a, b)
 
 
 def schur_update(c: jax.Array, a: jax.Array, b: jax.Array, *,
@@ -52,7 +64,8 @@ def schur_update(c: jax.Array, a: jax.Array, b: jax.Array, *,
     even for low-precision operands, matching `matmul`.
     """
     return mesh_safe(functools.partial(
-        schur_update_pallas, alpha=alpha, beta=beta, tiles=tiles,
+        schur_update_pallas, alpha=alpha, beta=beta,
+        tiles=_booked(tiles, a, b, out_dtype, c),
         interpret=pallas_interpret_default(), out_dtype=out_dtype))(c, a, b)
 
 
@@ -104,7 +117,7 @@ def block_gemm(a_blocks: jax.Array, b_blocks: jax.Array,
     """
     bi, bk, bs, _ = a_blocks.shape
     _, bj, _, _ = b_blocks.shape
-    mm = functools.partial(matmul_pallas, tiles=tiles or auto_tiles(bs, bs, bs),
+    mm = functools.partial(matmul_pallas, tiles=tiles,
                            interpret=pallas_interpret_default())
 
     # vmap over (i, j); lax.map over k to bound trace size, accumulate f32.

@@ -50,11 +50,12 @@ def pallas_base_default() -> bool:
 def mosaic_legal(n: int, full_tile_max: int = 512) -> bool:
     """Whether an (n, n) flattened-leaf GEMM gets a Mosaic-legal tiling.
 
-    `kernels.matmul.auto_tiles` emits 128-multiple tiles when they divide
-    the dimension and falls back to one full-dim tile otherwise; a full-dim
-    tile is only safe while three n×n f32 tiles fit VMEM comfortably
-    (n ≤ 512 ⇒ ≤ 3 MB of 16 MB). Outside both regimes the leaf stays on
-    XLA rather than risk a Mosaic layout failure.
+    `kernels.matmul.auto_tiles` picks, per dim, a multiple of 128 that
+    divides it (sized by its VMEM reckoning) and falls back to one full-dim
+    tile otherwise; a full-dim tile is only safe while the n×n f32 tiles
+    and the dot's temporaries fit VMEM comfortably (n ≤ 512 ⇒ at most 14 MiB).
+    Outside both regimes the leaf stays on XLA rather than risk a Mosaic
+    layout failure.
     """
     return n % 128 == 0 or n <= full_tile_max
 

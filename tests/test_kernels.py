@@ -8,7 +8,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.testing import make_spd
 from repro.kernels.leaf_inverse import ops as gj_ops, ref as gj_ref
-from repro.kernels.matmul import ops as mm_ops, ref as mm_ref
+from repro.kernels.matmul import kernel as mm_kernel, ops as mm_ops, ref as mm_ref
 
 
 @pytest.mark.parametrize("m,k,n", [
@@ -133,6 +133,93 @@ def test_grid_matmul_matches_einsum():
     got = mm_ops.grid_matmul(a, b)
     want = jnp.einsum("ikab,kjbc->ijac", a, b)
     assert jnp.allclose(got, want, atol=1e-3)
+
+
+# ------------------------------------------------- tile rule
+
+
+_RULE_DIMS = (64, 192, 1024, 4096, 8192, 16384, 32768)
+
+
+def _rule_tiles(m, n, k, dtype, kernel, out_itemsize):
+    size = jnp.dtype(dtype).itemsize
+    return mm_kernel.auto_tiles(
+        m, n, k, a_itemsize=size, b_itemsize=size, out_itemsize=out_itemsize,
+        c_itemsize=out_itemsize if kernel == "schur_update" else 0)
+
+
+@pytest.mark.parametrize("m", _RULE_DIMS)
+@pytest.mark.parametrize("kernel", ["matmul", "schur_update"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_auto_tiles_are_mosaic_legal_and_fit_vmem(m, kernel, dtype):
+    """Every tile is a multiple of 128 dividing its dim, or the full dim,
+    and the tiling's VMEM reckoning stays under the kernels' cap."""
+    from repro.kernels import VMEM_CAP_BYTES
+
+    size = jnp.dtype(dtype).itemsize
+    for n in _RULE_DIMS:
+        for k in _RULE_DIMS:
+            for out in {size, 4}:
+                tiles = _rule_tiles(m, n, k, dtype, kernel, out)
+                for t, dim in zip(tiles, (m, n, k)):
+                    assert dim % t == 0, (m, n, k, tiles)
+                    assert t == dim or t % 128 == 0, (m, n, k, tiles)
+                c = out if kernel == "schur_update" else 0
+                assert mm_kernel.gemm_vmem_bytes(
+                    *tiles, size, size, out, c) <= VMEM_CAP_BYTES
+
+
+@pytest.mark.parametrize("kernel", ["matmul", "schur_update"])
+def test_auto_tiles_take_an_8192_product_in_few_grid_steps(kernel):
+    n = 8192
+    bm, bn, bk = _rule_tiles(n, n, n, jnp.float32, kernel, 4)
+    assert (n // bm) * (n // bn) * (n // bk) <= 4096
+
+
+@pytest.mark.parametrize("kernel", ["matmul", "schur_update"])
+def test_rule_tiles_above_128_accumulate_over_k_steps(kernel):
+    """At a shape where the rule picks tiles above 128 and several k steps,
+    the default-tiled kernels match the oracle."""
+    m = n = 256
+    k = 2 * mm_kernel.TILE_MAX[2]
+    bm, bn, bk = _rule_tiles(m, n, k, jnp.float32, kernel, 4)
+    assert min(bm, bn, bk) > 128 and k // bk > 1
+    key = jax.random.PRNGKey(6)
+    a = jax.random.normal(key, (m, k))
+    b = jax.random.normal(jax.random.fold_in(key, 1), (k, n))
+    if kernel == "matmul":
+        got, want = mm_ops.matmul(a, b), mm_ref.matmul_ref(a, b)
+    else:
+        c = jax.random.normal(jax.random.fold_in(key, 2), (m, n))
+        got = mm_ops.schur_update(c, a, b)
+        want = mm_ref.schur_update_ref(c, a, b)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-3
+
+
+def test_pallas_grid_steps_count_the_rule_over_a_traced_inverse():
+    """`pallas_grid_steps` books (m/bm)·(n/bn)·(k/bk) for every product of
+    the recursion: per node at half-dim d, four products and two Schur
+    updates of d³."""
+    from repro.core import BlockMatrix, count_ops, spin_inverse
+    from repro.core.multiply import multiply_engine
+
+    n, grid = 2048, 16
+    bs = n // grid
+    with count_ops() as counts, multiply_engine("pallas"):
+        jax.eval_shape(
+            lambda x: spin_inverse(BlockMatrix.from_dense(x, bs),
+                                   leaf_solver="pallas").blocks,
+            jax.ShapeDtypeStruct((n, n), jnp.float32))
+
+    def steps(d, kernel):
+        bm, bn, bk = _rule_tiles(d, d, d, jnp.float32, kernel, 4)
+        return (d // bm) * (d // bn) * (d // bk)
+
+    want, nodes, d = 0, 1, n // 2
+    while d >= bs:
+        want += nodes * (4 * steps(d, "matmul") + 2 * steps(d, "schur_update"))
+        nodes, d = nodes * 2, d // 2
+    assert counts.pallas_grid_steps == want
 
 
 # ------------------------------------------------- blocked Gauss-Jordan

@@ -55,8 +55,8 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _compiled_text(fn, *shapes, sharding) -> str:
-    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
+def _compiled_text(fn, *shapes, sharding, dtype=jnp.float32) -> str:
+    args = [jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
             for s in shapes]
     return jax.jit(fn).lower(*args).compile().as_text()
 
@@ -68,6 +68,30 @@ def test_schur_update_compiles_at_2048(one_chip):
             c, a, b, tiles=mm.auto_tiles(n, n, n)),
         (n, n), (n, n), (n, n), sharding=one_chip)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("fn,n,dtype,name", [
+    (lambda a, b: mm.matmul_pallas(a, b), 8192, jnp.float32,
+     "matmul_pallas"),
+    (lambda c, a, b: mm.schur_update_pallas(c, a, b), 8192, jnp.float32,
+     "schur_update_pallas"),
+    (lambda c, a, b: mm.schur_update_pallas(c, a, b, out_dtype=jnp.float32),
+     4096, jnp.bfloat16, "schur_update_pallas"),
+])
+def test_gemm_kernels_compile_with_their_default_tiles(fn, n, dtype, name,
+                                                       one_chip, monkeypatch):
+    """The tile rule's choice at the recursion's largest product compiles
+    with no more VMEM than its reckoning: the limit here is the reckoning
+    alone, without the margin the kernels ask for, since a kernel that XLA
+    fuses into a consumer gets only the budget."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(mm, "vmem_compiler_params", lambda v, sem: (
+        pltpu.CompilerParams(dimension_semantics=sem, vmem_limit_bytes=v)))
+    jax.clear_caches()      # no kernel traced under the usual limit
+    shapes = [(n, n)] * (fn.__code__.co_argcount)
+    text = _compiled_text(fn, *shapes, sharding=one_chip, dtype=dtype)
+    assert _kernel_names(text) == {name}
 
 
 @pytest.mark.parametrize("kernel,fn", [
@@ -125,6 +149,25 @@ def test_sharded_pallas_recursion_compiles_on_a_four_chip_mesh(
             blocks, "pallas", "pallas", ("data", "model"),
             mesh_fingerprint()).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_pallas_recursion_compiles_with_its_rule_tiles(one_chip,
+                                                      monkeypatch):
+    """The one-chip recursion at products of 2048 and 1024, where the tile
+    rule reaches its largest tiles. XLA fuses a kernel's output into the
+    in-place arrange that consumes it, and a fused kernel is held to the
+    default scoped VMEM whatever it asks: the rule's budget must hold."""
+    from repro.core.spin import _spin_inverse_dense
+    from repro.kernels.leaf_inverse import ops as leaf_ops
+    from repro.kernels.matmul import ops as mm_ops
+
+    monkeypatch.setattr(leaf_ops, "pallas_interpret_default", lambda: False)
+    monkeypatch.setattr(mm_ops, "pallas_interpret_default", lambda: False)
+    x = jax.ShapeDtypeStruct((4096, 4096), jnp.float32, sharding=one_chip)
+    text = _spin_inverse_dense.lower(x, 1024, "pallas", "pallas").compile(
+        ).as_text()
+    assert _kernel_names(text) == {"matmul_pallas", "schur_update_pallas",
+                                   "blocked_leaf_inverse_pallas"}
 
 
 def _kernel_names(text: str) -> set[str]:
