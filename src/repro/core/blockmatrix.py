@@ -57,6 +57,13 @@ class OpCounts:
     # Pallas GEMM grid steps, (m/bm)·(n/bn)·(k/bk) summed over the
     # kernels.matmul calls (engine="pallas" and its solve panels only):
     pallas_grid_steps: int = 0
+    # On a mesh (zero off one): bytes each device receives from the SUMMA
+    # gathers, bs×bs GEMMs of products every device repeats (the grid no
+    # longer divides the mesh), and leaf inversions (every device repeats
+    # each one).
+    gather_bytes: int = 0
+    replicated_block_gemms: int = 0
+    replicated_leaves: int = 0
 
     def as_dict(self) -> dict[str, int]:
         return dataclasses.asdict(self)

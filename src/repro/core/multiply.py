@@ -42,6 +42,11 @@ Grid-to-mesh contract for the shard_map engines:
     A grid (i, k): i over 'data', k over 'model'
     B grid (k, j): k over 'data', j over 'model'
     C grid (i, j): i over 'data', j over 'model'
+
+Every SUMMA all-gather (and the ring's ppermute) runs under the `gather`
+step scope (repro.obs.trace) and books the bytes each device receives as
+`OpCounts.gather_bytes`; a product whose grid does not divide the mesh runs
+on every device and books its bs×bs GEMMs as `replicated_block_gemms`.
 """
 
 from __future__ import annotations
@@ -56,6 +61,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
+from repro.obs.trace import step_scope
 
 from .blockmatrix import BlockMatrix, _bump
 from .precision import dot_precision
@@ -133,20 +139,34 @@ def matmul_blocks_pallas(a: jax.Array, b: jax.Array) -> jax.Array:
     return mm_ops.grid_matmul(a, b)
 
 
+def _gather(panel: jax.Array, axis_name: str, axis: int) -> jax.Array:
+    """One SUMMA panel all-gather (tiled), under the `gather` step scope;
+    books the bytes this device receives as `gather_bytes` (trace time)."""
+    with step_scope("gather"):
+        full = jax.lax.all_gather(panel, axis_name, axis=axis, tiled=True)
+    _bump("gather_bytes", (full.size - panel.size) * panel.dtype.itemsize)
+    return full
+
+
+def _gather_panels(a_loc: jax.Array, b_loc: jax.Array, *, model_axis: str,
+                   data_axis: str) -> tuple[jax.Array, jax.Array]:
+    """SUMMA's row and column broadcast: A's k-panels along `model`, B's
+    along `data`."""
+    return (_gather(a_loc, model_axis, 1), _gather(b_loc, data_axis, 0))
+
+
 def allgather_matmul_panels(a_loc: jax.Array, b_loc: jax.Array, *,
                             model_axis: str, data_axis: str) -> jax.Array:
     """SUMMA row/column broadcast as two tiled all-gathers + one local GEMM."""
-    a_full = jax.lax.all_gather(a_loc, model_axis, axis=1, tiled=True)
-    b_full = jax.lax.all_gather(b_loc, data_axis, axis=0, tiled=True)
-    return matmul_blocks_einsum(a_full, b_full)
+    return matmul_blocks_einsum(*_gather_panels(
+        a_loc, b_loc, model_axis=model_axis, data_axis=data_axis))
 
 
 def pallas_matmul_panels(a_loc: jax.Array, b_loc: jax.Array, *,
                          model_axis: str, data_axis: str) -> jax.Array:
     """SUMMA gathers with the local grid GEMM swapped for the Pallas kernel."""
-    a_full = jax.lax.all_gather(a_loc, model_axis, axis=1, tiled=True)
-    b_full = jax.lax.all_gather(b_loc, data_axis, axis=0, tiled=True)
-    return matmul_blocks_pallas(a_full, b_full)
+    return matmul_blocks_pallas(*_gather_panels(
+        a_loc, b_loc, model_axis=model_axis, data_axis=data_axis))
 
 
 def ring_matmul_panels(a_loc: jax.Array, b_loc: jax.Array, *, model_axis: str,
@@ -157,12 +177,14 @@ def ring_matmul_panels(a_loc: jax.Array, b_loc: jax.Array, *, model_axis: str,
     B's k-panels circulate around the `data` ring: at step t each rank holds
     the panel that started at rank (d_idx − t), multiplies it against the
     matching k-columns of A, and forwards it. The forward ppermute is issued
-    BEFORE the GEMM so XLA overlaps transfer with compute.
+    BEFORE the GEMM so XLA overlaps transfer with compute. Every step's
+    forward counts in `gather_bytes`, the last one's too.
     """
-    a_full = jax.lax.all_gather(a_loc, model_axis, axis=1, tiled=True)
+    a_full = _gather(a_loc, model_axis, 1)
     n_data = compat.axis_size(data_axis)
     if n_data == 1:
         return matmul_blocks_einsum(a_full, b_loc)
+    _bump("gather_bytes", n_data * b_loc.size * b_loc.dtype.itemsize)
     d_idx = jax.lax.axis_index(data_axis)
     bk_panel = b_loc.shape[0]                  # B's local k extent
     perm = [(i, (i + 1) % n_data) for i in range(n_data)]
@@ -175,7 +197,8 @@ def ring_matmul_panels(a_loc: jax.Array, b_loc: jax.Array, *, model_axis: str,
 
     def step(t, carry):
         acc, panel = carry
-        next_panel = jax.lax.ppermute(panel, data_axis, perm)  # in flight…
+        with step_scope("gather"):                             # in flight…
+            next_panel = jax.lax.ppermute(panel, data_axis, perm)
         src = (d_idx - t) % n_data                 # whose slab is this?
         a_cols = jax.lax.dynamic_slice_in_dim(
             a_full, src * bk_panel, bk_panel, axis=1)
@@ -190,8 +213,10 @@ def _mesh_axes_for(mesh, *grids) -> tuple[str, str] | None:
     """(data_axis, model_axis) when every (rows, cols) grid divides the mesh.
 
     Deep recursion levels shrink the grid below the mesh; shard_map needs
-    even divisibility, so those (comm-light) levels fall back to the SPMD
-    partitioner. Explicit SUMMA only pays off when the grid covers the mesh.
+    even divisibility, so those levels fall back to the SPMD partitioner,
+    and with their grid undivided there every device computes the whole
+    product: they run replicated on every chip (`_book_replicated`).
+    Explicit SUMMA only pays off when the grid covers the mesh.
     """
     if mesh is None or not mesh.shape:
         return None
@@ -202,6 +227,13 @@ def _mesh_axes_for(mesh, *grids) -> tuple[str, str] | None:
         if rows % mesh.shape[data_axis] or cols % mesh.shape[model_axis]:
             return None
     return data_axis, model_axis
+
+
+def _book_replicated(mesh, a: jax.Array, b: jax.Array) -> None:
+    """Book, as `replicated_block_gemms`, the bs×bs GEMMs of a product that
+    runs outside SUMMA on a mesh, so that every device repeats it."""
+    if mesh is not None and mesh.shape:
+        _bump("replicated_block_gemms", a.shape[0] * a.shape[1] * b.shape[1])
 
 
 def _local_matmul(engine: str):
@@ -217,6 +249,7 @@ def _shard_map_multiply(a: jax.Array, b: jax.Array, engine: str) -> jax.Array:
     axes = _mesh_axes_for(mesh, (a.shape[0], a.shape[1]),
                           (b.shape[0], b.shape[1]))
     if axes is None:
+        _book_replicated(mesh, a, b)
         return _local_matmul(engine)(a, b)
     data_axis, model_axis = axes
     fn = {"ring": ring_matmul_panels,
@@ -281,12 +314,14 @@ def schur_update_blocks(c: jax.Array, a: jax.Array, b: jax.Array, *,
                               (b.shape[0], b.shape[1]),
                               (c.shape[0], c.shape[1]))
         if axes is None:
+            _book_replicated(mesh, a, b)
             return mm_ops.grid_schur_update(c, a, b, alpha=alpha, beta=beta)
         data_axis, model_axis = axes
 
         def local(c_loc, a_loc, b_loc):
-            a_full = jax.lax.all_gather(a_loc, model_axis, axis=1, tiled=True)
-            b_full = jax.lax.all_gather(b_loc, data_axis, axis=0, tiled=True)
+            a_full, b_full = _gather_panels(a_loc, b_loc,
+                                            model_axis=model_axis,
+                                            data_axis=data_axis)
             return mm_ops.grid_schur_update(c_loc, a_full, b_full,
                                             alpha=alpha, beta=beta)
 

@@ -119,7 +119,9 @@ def assert_paper_op_counts(grid: int, counts: OpCounts) -> None:
     Engine-blind: the Strassen-internal counters are excluded here (a
     Strassen product is still ONE Algorithm-2 multiply) and checked by
     their own oracle, `assert_strassen_op_counts`; so is the Pallas
-    kernels' grid-step count, which follows their tiles.
+    kernels' grid-step count, which follows their tiles, and so are the
+    mesh counters (`gather_bytes`, `replicated_*`), which follow the
+    placement.
     """
     want = expected_spin_counts(grid)
     got = counts.as_dict()
@@ -128,7 +130,8 @@ def assert_paper_op_counts(grid: int, counts: OpCounts) -> None:
         if k in got and got[k] != v
         and k not in ("leaf_lu", "leaf_solves", "solve_applies",
                       "strassen_base_multiplies", "strassen_adds",
-                      "pallas_grid_steps")
+                      "pallas_grid_steps", "gather_bytes",
+                      "replicated_block_gemms", "replicated_leaves")
     }
     if mismatches:
         raise AssertionError(

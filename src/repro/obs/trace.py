@@ -7,11 +7,13 @@ Two kinds of instrumentation live here.
     `jax.named_scope` per internal node, `spin.L<k>` at depth k, and inside
     it one per step (`STEPS`: `split`, `II`, `III`, `schur`, `C12`, `C21`,
     `C11`, `neg`, `arrange`); a leaf opens `spin.L<k>/leaf`, and the dense
-    entry's block layout opens `spin.layout` outside any level. A named
-    scope is HLO metadata only (`op_name`), so the names cost nothing at
-    run time and change no instruction; `op_scope` reads the level and the
-    step back from an `op_name`, `hlo_op_scopes` from a compiled module's
-    text. The entry points open one `jax.profiler.TraceAnnotation` each
+    entry's block layout opens `spin.layout` outside any level. On a mesh
+    each SUMMA all-gather opens `gather` inside its product step, so it
+    reads as `spin.L<k>/<step>/gather` and `op_scope` gives it the step
+    `gather`. A named scope is HLO metadata only (`op_name`), so the names
+    cost nothing at run time and change no instruction; `op_scope` reads
+    the level and the step back from an `op_name`, `hlo_op_scopes` from a
+    compiled module's text. The entry points open one `jax.profiler.TraceAnnotation` each
     (`host_span`), which costs about a microsecond with no profiler
     session. None of this is gated by `SPIN_TRACE`.
   * **Span records, gated by `SPIN_TRACE`.** The recursion, the planner,
@@ -62,9 +64,10 @@ TRACE_DIR_ENV = "SPIN_TRACE_DIR"
 LEVEL_PREFIX = "spin.L"
 LAYOUT = "spin.layout"
 # Steps of one internal node in Algorithm-2 order (`schur` is IV and V
-# fused, `C11` is VII and the subtract fused), the leaf, and the layout.
+# fused, `C11` is VII and the subtract fused), the leaf, the SUMMA gathers
+# inside a product step (mesh only), and the layout.
 STEPS = ("split", "II", "III", "schur", "C12", "C21", "C11", "neg",
-         "arrange", "leaf", LAYOUT)
+         "arrange", "leaf", "gather", LAYOUT)
 _LEVEL = re.compile(re.escape(LEVEL_PREFIX) + r"(\d+)")
 
 

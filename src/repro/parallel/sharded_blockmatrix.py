@@ -21,9 +21,13 @@ level:
 
 i.e. a level stays fully grid-sharded as long as its (halved) grid still
 covers the mesh axis; when the grid outgrows divisibility the undivisible
-axis degrades to replicated-along-that-axis (a single bs×bs leaf block is
-the only fully replicated object, and it is one block, never the matrix).
-Dense solve panels shard their row axis over `data` under the same rule.
+axis degrades to replicated-along-that-axis. Once a node's quadrants no
+longer divide the mesh, its products run replicated: every device computes
+them whole (at grid 32 on a (2, 2) mesh, the 16 nodes of depth 4 with 96
+bs×bs GEMMs), and so does every leaf inversion (all 32 there). What is
+replicated is small, never the matrix, but it is repeated work, booked as
+`OpCounts.replicated_block_gemms` and `replicated_leaves`. Dense solve
+panels shard their row axis over `data` under the same rule.
 
 Every constraint is also recorded in a trace-time *spec ledger*
 (`record_specs`), which is how tests assert the no-replication property
@@ -372,6 +376,9 @@ class ShardedBlockMatrix:
         if self.grid != 1:
             raise ValueError(f"leaf_inverse expects grid==1, got {self.grid}")
         _bump("leaf_inversions")
+        mesh = compat.get_abstract_mesh()
+        if mesh is not None and mesh.shape:
+            _bump("replicated_leaves")        # every device inverts it
         inv = LEAF_SOLVERS[solver](self.blocks[0, 0])
         return self._wrap(inv[None, None], "leaf_inverse")
 

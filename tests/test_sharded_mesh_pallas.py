@@ -1,0 +1,163 @@
+"""The mesh-resident inverse with the Pallas engine and leaf on a (2, 2)
+mesh, and what the mesh adds to the op counts (`OpCounts.gather_bytes`,
+`replicated_block_gemms`, `replicated_leaves`) and to the named scopes
+(the `gather` step).
+
+n=512 at block 64 is a grid of 8: the nodes at depths 0 and 1 have
+quadrant grids of 4 and 2, which divide the mesh, and multiply by SUMMA;
+the four nodes at depth 2 have one-block quadrants and multiply
+replicated, on every device, as do the eight leaves at depth 3. Four
+devices need a subprocess (`tests/mesh_harness.py`); the Pallas kernels
+run interpreted there.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import AbstractMesh, AxisType
+
+from repro.core import BlockMatrix, count_ops, spin_inverse
+from repro.core.multiply import multiply_engine
+from repro.core.spin import inverse_op_scopes
+from repro.core.testing import make_spd
+from repro.obs.trace import STEPS
+from repro.parallel import ShardedBlockMatrix, sharded_spin_inverse
+from tests.mesh_harness import run_mesh
+
+N, BS, MESH = 512, 64, (2, 2)
+COUNTERS = ("gather_bytes", "replicated_block_gemms", "replicated_leaves")
+
+
+def closed_forms(n: int, bs: int, mesh: tuple[int, int]) -> dict:
+    """The three mesh counters of one f32 inversion. A node at depth k has
+    2**k peers and six products of quadrants h = grid/2**(k+1) blocks on a
+    side. Where h divides both mesh axes a product is SUMMA: each device
+    holds (h/d)×(h/m) blocks of each operand and receives the rest of A's
+    row panel along `model` and of B's column panel along `data`. Where it
+    does not, every device computes its h³ block GEMMs. Every leaf is
+    inverted on every device."""
+    d, m = mesh
+    grid = n // bs
+    out = dict.fromkeys(COUNTERS, 0)
+    out["replicated_leaves"] = grid
+    k = 0
+    while grid >> k > 1:
+        h, products = grid >> (k + 1), 6 * 2 ** k
+        if h % d == 0 and h % m == 0:
+            blocks_in = (h // d) * (h - h // m) + (h - h // d) * (h // m)
+            out["gather_bytes"] += products * blocks_in * bs * bs * 4
+        else:
+            out["replicated_block_gemms"] += products * h ** 3
+        k += 1
+    return out
+
+
+@pytest.fixture(scope="module")
+def on_the_mesh():
+    """One sharded Pallas inversion on four devices: its residual, its
+    distance from NumPy's inverse, its counters and its gather scopes."""
+    (got,) = run_mesh(f"""
+        import numpy as np
+        import jax, jax.numpy as jnp
+        from jax.sharding import AxisType, Mesh, NamedSharding
+        from jax.sharding import PartitionSpec as P
+        from repro.compat import set_mesh
+        from repro.core import count_ops, spin_inverse_sharded
+        from repro.core.spin import inverse_op_scopes
+        from repro.core.testing import make_spd
+
+        mesh = Mesh(np.asarray(jax.devices()).reshape(2, 2),
+                    ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+        a = jax.device_put(make_spd({N}, jax.random.PRNGKey(7)),
+                           NamedSharding(mesh, P("data", "model")))
+        with set_mesh(mesh), count_ops() as counts:
+            x = spin_inverse_sharded(a, {BS}, leaf_solver="pallas",
+                                     engine="pallas")
+        # The formula of bench/reference.inverse_residual, at HIGHEST.
+        r = jnp.matmul(a, x, precision=jax.lax.Precision.HIGHEST)
+        residual = jnp.linalg.norm(r - jnp.eye({N})) / np.sqrt({N})
+        a64, x64 = np.asarray(a, np.float64), np.asarray(x, np.float64)
+        scopes = inverse_op_scopes({N}, {BS}, "pallas", "pallas", mesh=mesh)
+        emit_result({{
+            "residual": float(residual),
+            "vs_numpy": float(np.abs(x64 - np.linalg.inv(a64)).max()),
+            "counts": counts.as_dict(),
+            "gather_levels": sorted({{lv for ops in scopes.values()
+                                     for lv, st in ops.values()
+                                     if st == "gather"}})}})
+    """, devices=4)
+    return got
+
+
+# A = B Bᵀ/n + I has its spectrum in about [1, 5], so ‖A⁻¹‖₂ ≤ 1 and the
+# condition number is about 5. The CPU reads a residual of about 3e-7 and
+# a largest entry error of about 6e-7 here.
+@pytest.mark.parametrize("reading,limit", [
+    # ‖A X − I‖_F/√n: f32 rounding (6e-8 a step) grows over the four
+    # depths and the 64-wide leaf eliminations; 2e-6 leaves 6× room above
+    # the reading and is 500× below one bf16 pass (the benchmark's control
+    # on a CPU reads ~1e-3).
+    ("residual", 2e-6),
+    # max |X − A⁻¹| against NumPy in float64: condition × the residual, with
+    # the same room.
+    ("vs_numpy", 5e-6),
+])
+def test_sharded_pallas_inverse_matches_the_reference(on_the_mesh, reading,
+                                                      limit):
+    assert 0 < on_the_mesh[reading] < limit, on_the_mesh
+
+
+@pytest.mark.parametrize("counter", COUNTERS)
+def test_mesh_counters_equal_their_closed_forms(on_the_mesh, counter):
+    assert on_the_mesh["counts"][counter] == closed_forms(N, BS,
+                                                          MESH)[counter]
+
+
+def test_gathers_are_named_at_the_summa_levels_only(on_the_mesh):
+    assert "gather" in STEPS
+    assert on_the_mesh["gather_levels"] == [0, 1]
+
+
+@pytest.mark.parametrize("n,bs", [(4096, 512), (32768, 1024)])
+def test_counters_at_size_under_an_abstract_mesh(n, bs):
+    """Counted at trace time, so tracing under a (2, 2) mesh with no device
+    behind it is enough: the benchmark's recorded size and the mesh cell's
+    configuration (6.04e9 gathered bytes per device, 96 replicated GEMMs,
+    32 replicated leaves)."""
+    mesh = AbstractMesh(MESH, ("data", "model"),
+                        axis_types=(AxisType.Auto,) * 2)
+    grid = n // bs
+
+    def inverse(blocks):
+        with multiply_engine("pallas"):
+            return sharded_spin_inverse(
+                ShardedBlockMatrix(blocks).constrain(), "pallas").blocks
+
+    with jax.sharding.use_abstract_mesh(mesh), count_ops() as counts:
+        jax.eval_shape(inverse, jax.ShapeDtypeStruct((grid, grid, bs, bs),
+                                                     jnp.float32))
+    assert {c: getattr(counts, c) for c in COUNTERS} == closed_forms(
+        n, bs, MESH)
+
+
+@pytest.mark.parametrize("entry", ["dense", "sharded"])
+def test_counters_are_zero_off_the_mesh(entry):
+    """The recursions run op by op, so the counts never come from a jit
+    cache that an earlier test filled."""
+    a = make_spd(128, jax.random.PRNGKey(3))
+    with count_ops() as counts, multiply_engine("pallas"):
+        if entry == "dense":
+            spin_inverse(BlockMatrix.from_dense(a, 32), leaf_solver="pallas")
+        else:
+            sharded_spin_inverse(ShardedBlockMatrix.from_dense(a, 32),
+                                 "pallas")
+    assert counts.block_gemms > 0 and counts.leaf_inversions == 4
+    assert {c: getattr(counts, c) for c in COUNTERS} == dict.fromkeys(
+        COUNTERS, 0)
+
+
+def test_one_device_compile_has_no_gather_scope():
+    (ops,) = inverse_op_scopes(256, 64, "pallas", "pallas").values()
+    steps = {st for _, st in ops.values()}
+    assert "gather" not in steps
+    assert {"II", "schur", "leaf"} <= steps
