@@ -64,6 +64,11 @@ class OpCounts:
     gather_bytes: int = 0
     replicated_block_gemms: int = 0
     replicated_leaves: int = 0
+    # Nodes of the mesh recursion split and arranged in interleaved
+    # quadrants on the device that holds them (also booked as splits and
+    # arranges; parallel/sharded_blockmatrix.py):
+    local_splits: int = 0
+    local_arranges: int = 0
 
     def as_dict(self) -> dict[str, int]:
         return dataclasses.asdict(self)

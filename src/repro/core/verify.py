@@ -120,8 +120,8 @@ def assert_paper_op_counts(grid: int, counts: OpCounts) -> None:
     Strassen product is still ONE Algorithm-2 multiply) and checked by
     their own oracle, `assert_strassen_op_counts`; so is the Pallas
     kernels' grid-step count, which follows their tiles, and so are the
-    mesh counters (`gather_bytes`, `replicated_*`), which follow the
-    placement.
+    mesh counters (`gather_bytes`, `replicated_*`, `local_*`), which
+    follow the placement.
     """
     want = expected_spin_counts(grid)
     got = counts.as_dict()
@@ -131,7 +131,8 @@ def assert_paper_op_counts(grid: int, counts: OpCounts) -> None:
         and k not in ("leaf_lu", "leaf_solves", "solve_applies",
                       "strassen_base_multiplies", "strassen_adds",
                       "pallas_grid_steps", "gather_bytes",
-                      "replicated_block_gemms", "replicated_leaves")
+                      "replicated_block_gemms", "replicated_leaves",
+                      "local_splits", "local_arranges")
     }
     if mismatches:
         raise AssertionError(

@@ -29,6 +29,26 @@ replicated is small, never the matrix, but it is repeated work, booked as
 `OpCounts.replicated_block_gemms` and `replicated_leaves`. Dense solve
 panels shard their row axis over `data` under the same rule.
 
+On a square mesh (|data| == |model|) the recursion splits a node into
+*interleaved* quadrants wherever each device holds an even number of its
+block rows: along both grid axes, the leading half is the first half of
+every device's local rows, the trailing half the rest. Stored in that
+order, each quadrant is again `P(data, model)` in contiguous chunks, so
+the same rule holds one level down, and split and arrange are local
+slices and a local concatenate inside a `shard_map` — no bytes cross
+chips (`OpCounts.local_splits`, `local_arranges`). Contiguous halves
+would each lie on one device, and re-anchoring them to the mesh reshards
+¾ of every quadrant each way. The interleaved halves are a symmetric
+block permutation of the node, which Algorithm 2 admits wherever the
+leading half and its Schur complement are invertible: SPD and
+diagonally dominant matrices (the zoo's families) stay so under it; a
+general matrix needs its interleaved, not its contiguous, leading blocks
+nonsingular. The result is inv(A) in A's own layout: arrange undoes the
+permutation split made. Elsewhere — off a mesh, a non-square mesh, or a
+node whose quadrants no longer divide it — the contiguous `split` and
+`arrange` run. The public `split()` and `arrange()` keep their natural
+quadrant meaning (the solve uses them).
+
 Every constraint is also recorded in a trace-time *spec ledger*
 (`record_specs`), which is how tests assert the no-replication property
 from the jaxpr rather than trusting this docstring: each
@@ -384,6 +404,65 @@ class ShardedBlockMatrix:
 
 
 # ---------------------------------------------------------------------------
+# Interleaved quadrants: split and arrange on the device that holds them
+# ---------------------------------------------------------------------------
+
+
+def _on_device_spec(a: ShardedBlockMatrix) -> P | None:
+    """The node's grid spec when its interleaved split stays on device.
+
+    That needs both grid axes sharded over equal mesh axes (rows and
+    columns then partition alike, a symmetric permutation) and an even
+    local extent; None sends the node down the contiguous `split`.
+    """
+    mesh = compat.get_abstract_mesh()
+    if mesh is None or not mesh.shape:
+        return None
+    spec = grid_spec(a.grid, a.grid, mesh, a.axes)
+    if spec[0] is None or spec[1] is None:
+        return None
+    local = a.grid // mesh.shape[spec[0]]
+    if mesh.shape[spec[0]] != mesh.shape[spec[1]] or local % 2:
+        return None
+    return spec
+
+
+def _split_on_device(a: ShardedBlockMatrix, spec: P
+                     ) -> tuple[ShardedBlockMatrix, ...]:
+    """Interleaved quadrants (module docstring): each device cuts its
+    own (l, l) blocks into four (l/2, l/2) pieces."""
+    _bump("splits")
+    _bump("local_splits")
+
+    def local(blk):
+        h = blk.shape[0] // 2
+        return blk[:h, :h], blk[:h, h:], blk[h:, :h], blk[h:, h:]
+
+    quads = compat.shard_map(local, mesh=compat.get_abstract_mesh(),
+                             in_specs=spec, out_specs=(spec,) * 4)(a.blocks)
+    return tuple(a._wrap(q, "split") for q in quads)
+
+
+def _arrange_on_device(c11: ShardedBlockMatrix, c12: ShardedBlockMatrix,
+                       c21: ShardedBlockMatrix, c22: ShardedBlockMatrix,
+                       spec: P) -> ShardedBlockMatrix:
+    """Inverse of `_split_on_device`: each device concatenates its four
+    pieces in place (inside the shard_map no partitioner sees the
+    concatenate, so `assemble_quadrants`' hazard cannot arise)."""
+    _bump("arranges")
+    _bump("local_arranges")
+
+    def local(q11, q12, q21, q22):
+        return jnp.concatenate([jnp.concatenate([q11, q12], axis=1),
+                                jnp.concatenate([q21, q22], axis=1)], axis=0)
+
+    out = compat.shard_map(local, mesh=compat.get_abstract_mesh(),
+                           in_specs=(spec,) * 4, out_specs=spec)(
+        c11.blocks, c12.blocks, c21.blocks, c22.blocks)
+    return c11._wrap(out, "arrange")
+
+
+# ---------------------------------------------------------------------------
 # The mesh-resident recursion (paper Algorithm 2)
 # ---------------------------------------------------------------------------
 
@@ -394,9 +473,10 @@ def sharded_spin_inverse(a: ShardedBlockMatrix, leaf_solver: str = "linalg",
 
     Identical op sequence to `core.spin.spin_inverse` (the op-count oracle
     holds level for level) under the same named scopes (`spin.L<_level>`
-    and its steps, repro.obs.trace); the only difference is the sharding
+    and its steps, repro.obs.trace); the differences are the sharding
     constraint each op re-asserts, so quadrants stay device-resident
-    between levels.
+    between levels, and, where `_on_device_spec` allows, interleaved
+    quadrants split and arranged without leaving their device.
     """
     b = a.grid
     if b & (b - 1):
@@ -405,9 +485,11 @@ def sharded_spin_inverse(a: ShardedBlockMatrix, leaf_solver: str = "linalg",
         with level_scope(_level), step_scope("leaf"):
             return a.leaf_inverse(leaf_solver)
 
+    spec = _on_device_spec(a)
     with level_scope(_level):
         with step_scope("split"):
-            a11, a12, a21, a22 = a.split()
+            a11, a12, a21, a22 = (a.split() if spec is None
+                                  else _split_on_device(a, spec))
         i_ = sharded_spin_inverse(a11, leaf_solver,
                                   _level + 1)             # I   = A11^-1
         with step_scope("II"):
@@ -429,7 +511,9 @@ def sharded_spin_inverse(a: ShardedBlockMatrix, leaf_solver: str = "linalg",
         with step_scope("neg"):
             c22 = vi.neg()                                # scalarMul(VI, -1)
         with step_scope("arrange"):
-            return ShardedBlockMatrix.arrange(c11, c12, c21, c22)
+            if spec is None:
+                return ShardedBlockMatrix.arrange(c11, c12, c21, c22)
+            return _arrange_on_device(c11, c12, c21, c22, spec)
 
 
 def _apply_blocks_sharded(a: ShardedBlockMatrix, x: jax.Array) -> jax.Array:

@@ -1,11 +1,12 @@
 """The mesh-resident inverse with the Pallas engine and leaf on a (2, 2)
 mesh, and what the mesh adds to the op counts (`OpCounts.gather_bytes`,
-`replicated_block_gemms`, `replicated_leaves`) and to the named scopes
-(the `gather` step).
+`replicated_block_gemms`, `replicated_leaves`, `local_splits`,
+`local_arranges`) and to the named scopes (the `gather` step).
 
 n=512 at block 64 is a grid of 8: the nodes at depths 0 and 1 have
-quadrant grids of 4 and 2, which divide the mesh, and multiply by SUMMA;
-the four nodes at depth 2 have one-block quadrants and multiply
+quadrant grids of 4 and 2, which divide the mesh, split and arrange
+interleaved quadrants on the device and multiply by SUMMA; the four nodes
+at depth 2 have one-block quadrants, split contiguously and multiply
 replicated, on every device, as do the eight leaves at depth 3. Four
 devices need a subprocess (`tests/mesh_harness.py`); the Pallas kernels
 run interpreted there.
@@ -25,15 +26,18 @@ from repro.parallel import ShardedBlockMatrix, sharded_spin_inverse
 from tests.mesh_harness import run_mesh
 
 N, BS, MESH = 512, 64, (2, 2)
-COUNTERS = ("gather_bytes", "replicated_block_gemms", "replicated_leaves")
+COUNTERS = ("gather_bytes", "replicated_block_gemms", "replicated_leaves",
+            "local_splits", "local_arranges")
+COLLECTIVE = r"all-gather|all-to-all|collective-permute"
 
 
 def closed_forms(n: int, bs: int, mesh: tuple[int, int]) -> dict:
-    """The three mesh counters of one f32 inversion. A node at depth k has
+    """The five mesh counters of one f32 inversion. A node at depth k has
     2**k peers and six products of quadrants h = grid/2**(k+1) blocks on a
     side. Where h divides both mesh axes a product is SUMMA: each device
     holds (h/d)×(h/m) blocks of each operand and receives the rest of A's
-    row panel along `model` and of B's column panel along `data`. Where it
+    row panel along `model` and of B's column panel along `data`; on a
+    square mesh the node also splits and arranges on the device. Where it
     does not, every device computes its h³ block GEMMs. Every leaf is
     inverted on every device."""
     d, m = mesh
@@ -46,6 +50,9 @@ def closed_forms(n: int, bs: int, mesh: tuple[int, int]) -> dict:
         if h % d == 0 and h % m == 0:
             blocks_in = (h // d) * (h - h // m) + (h - h // d) * (h // m)
             out["gather_bytes"] += products * blocks_in * bs * bs * 4
+            if d == m:
+                out["local_splits"] += 2 ** k
+                out["local_arranges"] += 2 ** k
         else:
             out["replicated_block_gemms"] += products * h ** 3
         k += 1
@@ -55,8 +62,10 @@ def closed_forms(n: int, bs: int, mesh: tuple[int, int]) -> dict:
 @pytest.fixture(scope="module")
 def on_the_mesh():
     """One sharded Pallas inversion on four devices: its residual, its
-    distance from NumPy's inverse, its counters and its gather scopes."""
+    distance from NumPy's inverse, its counters, its gather scopes and
+    the levels of its collectives under a `split` or `arrange` step."""
     (got,) = run_mesh(f"""
+        import re
         import numpy as np
         import jax, jax.numpy as jnp
         from jax.sharding import AxisType, Mesh, NamedSharding
@@ -84,7 +93,12 @@ def on_the_mesh():
             "counts": counts.as_dict(),
             "gather_levels": sorted({{lv for ops in scopes.values()
                                      for lv, st in ops.values()
-                                     if st == "gather"}})}})
+                                     if st == "gather"}}),
+            "layout_collective_levels": sorted({{
+                lv for ops in scopes.values()
+                for name, (lv, st) in ops.items()
+                if st in ("split", "arrange")
+                and re.search({COLLECTIVE!r}, name)}})}})
     """, devices=4)
     return got
 
@@ -118,12 +132,81 @@ def test_gathers_are_named_at_the_summa_levels_only(on_the_mesh):
     assert on_the_mesh["gather_levels"] == [0, 1]
 
 
+def test_split_and_arrange_move_no_bytes_at_the_summa_levels(on_the_mesh):
+    """Interleaved quadrants stay on their device at depths 0 and 1; the
+    one-block quadrants of depth 2 keep the contiguous split, whose
+    reshards show that the search finds collectives."""
+    assert on_the_mesh["layout_collective_levels"] == [2]
+
+
+@pytest.fixture(scope="module")
+def split_on_device():
+    """`_split_on_device` of the n=512 grid on four devices, beside the
+    interleaved index sets taken from the dense matrix."""
+    (got,) = run_mesh(f"""
+        import numpy as np
+        import jax
+        from jax.sharding import AxisType, Mesh, NamedSharding
+        from jax.sharding import PartitionSpec as P
+        from repro.compat import set_mesh
+        from repro.core import BlockMatrix
+        from repro.core.testing import make_spd
+        from repro.parallel.sharded_blockmatrix import (
+            ShardedBlockMatrix, _arrange_on_device, _on_device_spec,
+            _split_on_device)
+
+        mesh = Mesh(np.asarray(jax.devices()).reshape(2, 2),
+                    ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+        dense = make_spd({N}, jax.random.PRNGKey(5))
+        spec = P("data", "model", None, None)
+        blocks = jax.device_put(BlockMatrix.from_dense(dense, {BS}).blocks,
+                                NamedSharding(mesh, spec))
+
+        def split_arrange(x):
+            a = ShardedBlockMatrix(x)
+            quads = _split_on_device(a, _on_device_spec(a))
+            back = _arrange_on_device(*quads, _on_device_spec(a))
+            return tuple(q.blocks for q in quads), back.blocks
+
+        with set_mesh(mesh):
+            quads, back = jax.jit(split_arrange)(blocks)
+        # Each device holds l = 4 block rows; the leading half is the
+        # first l/2 of every device's rows.
+        grid, d = {N} // {BS}, 2
+        local = grid // d
+        lead = [i * local + j for i in range(d) for j in range(local // 2)]
+        trail = [i for i in range(grid) if i not in lead]
+        ref = np.asarray(blocks)
+        want = [ref[np.ix_(r, c)] for r in (lead, trail)
+                for c in (lead, trail)]
+        emit_result({{
+            "quadrants_are_the_interleaved_sets": all(
+                np.array_equal(np.asarray(q), w) for q, w in zip(quads, want)),
+            "arrange_after_split_is_the_identity": bool(
+                np.array_equal(np.asarray(back), ref)),
+            "every_piece_stays_grid_sharded": all(
+                x.sharding.spec[:2] == ("data", "model")
+                for x in (*quads, back)),
+        }})
+    """, devices=4)
+    return got
+
+
+@pytest.mark.parametrize("check", [
+    "quadrants_are_the_interleaved_sets",
+    "arrange_after_split_is_the_identity",
+    "every_piece_stays_grid_sharded",
+])
+def test_split_on_device(split_on_device, check):
+    assert split_on_device[check] is True, split_on_device
+
+
 @pytest.mark.parametrize("n,bs", [(4096, 512), (32768, 1024)])
 def test_counters_at_size_under_an_abstract_mesh(n, bs):
     """Counted at trace time, so tracing under a (2, 2) mesh with no device
     behind it is enough: the benchmark's recorded size and the mesh cell's
     configuration (6.04e9 gathered bytes per device, 96 replicated GEMMs,
-    32 replicated leaves)."""
+    32 replicated leaves, 15 nodes split and arranged on the device)."""
     mesh = AbstractMesh(MESH, ("data", "model"),
                         axis_types=(AxisType.Auto,) * 2)
     grid = n // bs
