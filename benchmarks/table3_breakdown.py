@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.core import BlockMatrix, leaf_inverse, multiply, testing
+from repro.core import BlockMatrix, multiply, testing
 from repro.core.costmodel import spin_schedule
 
 from .common import (bench_arg_parser, csv_row, emit_header, time_fn,
@@ -39,7 +39,7 @@ def run(emit, *, n=N, bs=BS, json_path: str | None = None) -> dict:
         if grid == 1:
             blk = testing.make_spd(bs, key)
             bm = BlockMatrix.from_dense(blk, bs)
-            t = time_fn(lambda x: leaf_inverse(x).blocks, bm)
+            t = time_fn(lambda x: x.leaf_inverse().blocks, bm)
             totals["leafNode"] += lvl["nodes"] * t
             continue
         half = grid // 2

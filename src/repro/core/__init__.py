@@ -16,8 +16,7 @@ from .precision import (PrecisionPolicy, PRECISION_PRESETS,
                         resolve_precision)
 from .strassen import (strassen_cutoff, strassen_matmul,
                        strassen_matmul_blocks)
-from .spin import (spin_inverse, spin_inverse_dense, spin_inverse_sharded,
-                   leaf_inverse)
+from .spin import spin_inverse, spin_inverse_dense, spin_inverse_sharded
 from .solve import (spin_solve, spin_solve_dense, spin_solve_sharded,
                     spin_inverse_batched, solve_grid_for,
                     SketchedInverse, sketched_approx_inverse)
@@ -36,7 +35,6 @@ __all__ = [
     "PrecisionPolicy", "PRECISION_PRESETS", "resolve_precision",
     "strassen_cutoff", "strassen_matmul", "strassen_matmul_blocks",
     "spin_inverse", "spin_inverse_dense", "spin_inverse_sharded",
-    "leaf_inverse",
     "spin_solve", "spin_solve_dense", "spin_solve_sharded",
     "spin_inverse_batched", "solve_grid_for",
     "SketchedInverse", "sketched_approx_inverse",

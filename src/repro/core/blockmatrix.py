@@ -15,11 +15,13 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
-from typing import Iterator
+from typing import Callable, Iterator
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+from .leaf import LEAF_SOLVERS
 
 __all__ = [
     "BlockMatrix",
@@ -111,8 +113,7 @@ def block_sharding(mesh, grid_axes=("data", "model")) -> NamedSharding:
 
 
 def assemble_quadrants(c11: jax.Array, c12: jax.Array, c21: jax.Array,
-                       c22: jax.Array, into: jax.Array | None = None
-                       ) -> jax.Array:
+                       c22: jax.Array, into: jax.Array) -> jax.Array:
     """Four (h, h, bs, bs) quadrant grids -> one (2h, 2h, bs, bs) grid.
 
     Deliberately zeros + dynamic_update_slice, NOT jnp.concatenate: the XLA
@@ -122,17 +123,15 @@ def assemble_quadrants(c11: jax.Array, c12: jax.Array, c21: jax.Array,
     lowers correctly for every operand sharding the recursion produces, and
     is bitwise-identical pure data movement wherever concatenate was right.
 
-    `into` lets a sharding-aware caller supply a pre-anchored (e.g.
-    with_sharding_constraint'ed) zero buffer so the updates inherit the
-    intended output sharding; default is a fresh unconstrained buffer.
+    `into` is the (2h, 2h, bs, bs) zero buffer written into; a
+    sharding-aware caller anchors it first (e.g. with_sharding_constraint)
+    so the updates inherit the intended output sharding.
     """
     h = c11.shape[0]
-    out = (jnp.zeros((2 * h, 2 * h) + c11.shape[2:], c11.dtype)
-           if into is None else into)
     for (i, j), quad in zip(((0, 0), (0, 1), (1, 0), (1, 1)),
                             (c11, c12, c21, c22)):
-        out = jax.lax.dynamic_update_slice(out, quad, (i * h, j * h, 0, 0))
-    return out
+        into = jax.lax.dynamic_update_slice(into, quad, (i * h, j * h, 0, 0))
+    return into
 
 
 # ---------------------------------------------------------------------------
@@ -143,9 +142,22 @@ def assemble_quadrants(c11: jax.Array, c12: jax.Array, c21: jax.Array,
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class BlockMatrix:
-    """A b×b grid of bs×bs blocks, stored as one (b, b, bs, bs) array."""
+    """A b×b grid of bs×bs blocks, stored as one (b, b, bs, bs) array.
+
+    The methods below are the node operations both SPIN recursions
+    (`core.recursion`) walk. A subclass changes where the blocks live by
+    overriding the placement hooks — `placed`, `node_split`,
+    `place_panel`, `stack_rows`, `leaf_inverse` and `fused_schur` — which
+    on a BlockMatrix leave every block where XLA puts it
+    (`repro.parallel.ShardedBlockMatrix` pins them to a mesh).
+    """
 
     blocks: jax.Array
+
+    # The recursion's two Schur steps run as one fused update
+    # (`multiply_subtract`, `subtract_multiply`), not a multiply then a
+    # subtract.
+    fused_schur = True
 
     # -- pytree protocol ----------------------------------------------------
     def tree_flatten(self):
@@ -173,6 +185,39 @@ class BlockMatrix:
     @property
     def dtype(self):
         return self.blocks.dtype
+
+    # -- placement hooks ------------------------------------------------------
+    def placed(self, blocks: jax.Array, op: str) -> "BlockMatrix":
+        """`blocks`, produced by step `op`, in this matrix's placement."""
+        return BlockMatrix(blocks)
+
+    def node_split(self) -> tuple[tuple["BlockMatrix", ...], Callable]:
+        """The four quadrants of a recursion node, and the arrange that
+        puts four results back in their places."""
+        return self.split(), type(self).arrange
+
+    def place_panel(self, x: jax.Array, op: str) -> jax.Array:
+        """A dense (rows, k) solve panel, produced by step `op`, in this
+        matrix's placement."""
+        return x
+
+    def stack_rows(self, x1: jax.Array, x2: jax.Array) -> jax.Array:
+        """[X1; X2]: the solve's two row panels as one."""
+        return jnp.concatenate([x1, x2], axis=0)
+
+    def leaf_inverse(self, solver: str = "linalg") -> "BlockMatrix":
+        """Paper Algorithm 2 `if` branch: grid==1, invert the block in place.
+
+        The paper deliberately does NOT collect the block to the driver
+        ("we do a map which takes the only block of the RDD") — likewise we
+        invert in situ on whichever device holds the block; no reshard is
+        issued.
+        """
+        if self.grid != 1:
+            raise ValueError(f"leaf_inverse expects grid==1, got {self.grid}")
+        _bump("leaf_inversions")
+        inv = LEAF_SOLVERS[solver](self.blocks[0, 0])
+        return self.placed(inv[None, None], "leaf_inverse")
 
     # -- conversions ----------------------------------------------------------
     @classmethod
@@ -204,33 +249,41 @@ class BlockMatrix:
         _bump("splits")
         blk = self.blocks
         return (
-            BlockMatrix(blk[:h, :h]),
-            BlockMatrix(blk[:h, h:]),
-            BlockMatrix(blk[h:, :h]),
-            BlockMatrix(blk[h:, h:]),
+            self.placed(blk[:h, :h], "split"),
+            self.placed(blk[:h, h:], "split"),
+            self.placed(blk[h:, :h], "split"),
+            self.placed(blk[h:, h:], "split"),
         )
 
     @staticmethod
     def arrange(
         c11: "BlockMatrix", c12: "BlockMatrix", c21: "BlockMatrix", c22: "BlockMatrix"
     ) -> "BlockMatrix":
-        """The paper's arrange: four quadrants -> one matrix (Algorithm 6)."""
+        """The paper's arrange: four quadrants -> one matrix (Algorithm 6).
+
+        The quadrants are written into a zero grid placed FIRST (see
+        `assemble_quadrants` on why not concatenate); the writes inherit
+        its placement, so the result needs no second one.
+        """
         _bump("arranges")
-        return BlockMatrix(assemble_quadrants(
-            c11.blocks, c12.blocks, c21.blocks, c22.blocks))
+        h = c11.grid
+        into = c11.placed(jnp.zeros((2 * h, 2 * h) + c11.blocks.shape[2:],
+                                    c11.dtype), "arrange")
+        return dataclasses.replace(c11, blocks=assemble_quadrants(
+            c11.blocks, c12.blocks, c21.blocks, c22.blocks, into=into.blocks))
 
     # -- arithmetic ----------------------------------------------------------
     def subtract(self, other: "BlockMatrix") -> "BlockMatrix":
         _bump("subtracts")
-        return BlockMatrix(self.blocks - other.blocks)
+        return self.placed(self.blocks - other.blocks, "subtract")
 
     def add(self, other: "BlockMatrix") -> "BlockMatrix":
         _bump("subtracts")  # same cost class as subtract in the paper's model
-        return BlockMatrix(self.blocks + other.blocks)
+        return self.placed(self.blocks + other.blocks, "add")
 
     def scalar_mul(self, scalar) -> "BlockMatrix":
         _bump("scalar_muls")
-        return BlockMatrix(self.blocks * scalar)
+        return self.placed(self.blocks * scalar, "scalar_mul")
 
     def neg(self) -> "BlockMatrix":
         return self.scalar_mul(-1.0)

@@ -64,6 +64,7 @@ from repro import compat
 from repro.obs.trace import step_scope
 
 from .blockmatrix import BlockMatrix, _bump
+from .placement import active_mesh, grid_spec, mesh_axes
 from .precision import dot_precision
 
 __all__ = ["multiply", "multiply_engine", "current_engine", "validate_engine",
@@ -218,21 +219,20 @@ def _mesh_axes_for(mesh, *grids) -> tuple[str, str] | None:
     product: they run replicated on every chip (`_book_replicated`).
     Explicit SUMMA only pays off when the grid covers the mesh.
     """
-    if mesh is None or not mesh.shape:
+    if mesh is None:
         return None
-    axis_names = list(mesh.shape.keys())
-    data_axis = "data" if "data" in axis_names else axis_names[0]
-    model_axis = "model" if "model" in axis_names else axis_names[-1]
+    axes = mesh_axes(mesh)
     for rows, cols in grids:
-        if rows % mesh.shape[data_axis] or cols % mesh.shape[model_axis]:
+        spec = grid_spec(rows, cols, mesh, axes)
+        if spec[0] is None or spec[1] is None:
             return None
-    return data_axis, model_axis
+    return axes
 
 
 def _book_replicated(mesh, a: jax.Array, b: jax.Array) -> None:
     """Book, as `replicated_block_gemms`, the bs×bs GEMMs of a product that
     runs outside SUMMA on a mesh, so that every device repeats it."""
-    if mesh is not None and mesh.shape:
+    if mesh is not None:
         _bump("replicated_block_gemms", a.shape[0] * a.shape[1] * b.shape[1])
 
 
@@ -245,7 +245,7 @@ def _local_matmul(engine: str):
 # `scratch += loaded_operand` fails the check though every value is
 # per-shard by construction (each shard's kernel reads only its own panels).
 def _shard_map_multiply(a: jax.Array, b: jax.Array, engine: str) -> jax.Array:
-    mesh = compat.get_abstract_mesh()
+    mesh = active_mesh()
     axes = _mesh_axes_for(mesh, (a.shape[0], a.shape[1]),
                           (b.shape[0], b.shape[1]))
     if axes is None:
@@ -269,9 +269,9 @@ def multiply_blocks(a: jax.Array, b: jax.Array,
                     engine: str | None = None) -> jax.Array:
     """Engine dispatch on raw (bi,bk,bs,bs)×(bk,bj,bs,bs) block grids.
 
-    The shared mechanism under both `multiply` (BlockMatrix) and the
-    mesh-resident `ShardedBlockMatrix.multiply`; engine=None reads the
-    ambient `multiply_engine` context.
+    The mechanism under `multiply` for every block container, on one
+    device or on a mesh; engine=None reads the ambient `multiply_engine`
+    context.
     """
     engine = validate_engine(engine) or _ENGINE.get()
     if engine == "einsum":
@@ -309,7 +309,7 @@ def schur_update_blocks(c: jax.Array, a: jax.Array, b: jax.Array, *,
         from repro.kernels.matmul import ops as mm_ops  # late: optional layer
 
         alpha, beta = (1.0, -1.0) if negate_c else (-1.0, 1.0)
-        mesh = compat.get_abstract_mesh()
+        mesh = active_mesh()
         axes = _mesh_axes_for(mesh, (a.shape[0], a.shape[1]),
                               (b.shape[0], b.shape[1]),
                               (c.shape[0], c.shape[1]))
@@ -339,7 +339,7 @@ def multiply(a: BlockMatrix, b: BlockMatrix) -> BlockMatrix:
             f"grid mismatch: {a.blocks.shape} vs {b.blocks.shape}")
     _bump("multiplies")
     _bump("block_gemms", a.grid ** 3)
-    return BlockMatrix(multiply_blocks(a.blocks, b.blocks))
+    return a.placed(multiply_blocks(a.blocks, b.blocks), "multiply")
 
 
 def _fused_op_counts(grid: int) -> None:
@@ -353,21 +353,27 @@ def _fused_op_counts(grid: int) -> None:
 
 def multiply_subtract(a: BlockMatrix, b: BlockMatrix,
                       c: BlockMatrix) -> BlockMatrix:
-    """A·B − C (the paper's `V = IV − A22` with IV = A21·III, fused)."""
+    """A·B − C (the paper's `V = IV − A22` with IV = A21·III), fused where
+    `a`'s container fuses Schur updates (`BlockMatrix.fused_schur`)."""
     if a.grid != b.grid or a.grid != c.grid:
         raise ValueError(f"grid mismatch: {a.blocks.shape} vs "
                          f"{b.blocks.shape} vs {c.blocks.shape}")
+    if not a.fused_schur:
+        return multiply(a, b).subtract(c)
     _fused_op_counts(a.grid)
-    return BlockMatrix(schur_update_blocks(c.blocks, a.blocks, b.blocks,
-                                           negate_c=True))
+    return a.placed(schur_update_blocks(c.blocks, a.blocks, b.blocks,
+                                        negate_c=True), "schur")
 
 
 def subtract_multiply(c: BlockMatrix, a: BlockMatrix,
                       b: BlockMatrix) -> BlockMatrix:
-    """C − A·B (the paper's `C11 = I − VII` with VII = III·C21, fused)."""
+    """C − A·B (the paper's `C11 = I − VII` with VII = III·C21), fused where
+    `c`'s container fuses Schur updates (`BlockMatrix.fused_schur`)."""
     if a.grid != b.grid or a.grid != c.grid:
         raise ValueError(f"grid mismatch: {a.blocks.shape} vs "
                          f"{b.blocks.shape} vs {c.blocks.shape}")
+    if not c.fused_schur:
+        return c.subtract(multiply(a, b))
     _fused_op_counts(a.grid)
-    return BlockMatrix(schur_update_blocks(c.blocks, a.blocks, b.blocks,
-                                           negate_c=False))
+    return c.placed(schur_update_blocks(c.blocks, a.blocks, b.blocks,
+                                        negate_c=False), "schur")

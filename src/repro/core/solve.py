@@ -5,20 +5,8 @@ regression, Shampoo preconditioning, Earth-science normal equations): given
 SPD `A` and a block of right-hand sides `B`, produce `X = A⁻¹B` WITHOUT
 materializing `A⁻¹` and multiplying. It reuses the SPIN recursion's quadrant
 products (paper Algorithm 2's I/III/V names) in their inverse-free Schur
-form:
-
-    [A11 A12] [X1]   [B1]      III = A11⁻¹ A12   (recursive solve)
-    [A21 A22] [X2] = [B2]      Y1  = A11⁻¹ B1    (same recursive call —
-                                                  the RHS blocks ride along)
-    V  = A21·III − A22         (= −Schur complement, the paper's V)
-    X2 = V⁻¹ (A21·Y1 − B2)     (recursive solve on V)
-    X1 = Y1 − III·X2
-
-Per level this is 2 recursive solves + 3 block-times-panel products — it
-drops the 3 quadrant-assembly multiplies (C12, C21, VII) and the arrange
-that full inversion pays, and the only dense objects ever formed are n×(n/2)
-panels, never A⁻¹. Leaf systems go through the same pluggable leaf solvers
-as `spin_inverse`.
+form (`core.recursion.solve`, which also serves the mesh). Leaf systems go
+through the same pluggable leaf solvers as `spin_inverse` (`core.leaf`).
 
 `spin_inverse_batched` vmaps the whole SPIN recursion over a leading batch
 axis of SPD matrices — the shape Shampoo's stacked-layer factor refresh
@@ -34,10 +22,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .blockmatrix import BlockMatrix, _bump
+from .blockmatrix import BlockMatrix
 from .multiply import current_engine, multiply_engine, validate_engine
 from .precision import dot_precision
-from .spin import LEAF_SOLVERS, spin_inverse_dense
+from .recursion import solve
+from .spin import spin_inverse_dense
 
 __all__ = ["spin_solve", "spin_solve_dense", "spin_solve_sharded",
            "spin_inverse_batched", "solve_grid_for",
@@ -56,98 +45,6 @@ def solve_grid_for(n: int, max_grid: int = 8, min_block: int = 64) -> int:
            and n // (g * 2) >= min_block):
         g *= 2
     return g
-
-
-def _accum_dtype(dtype) -> jnp.dtype:
-    return (jnp.float32 if dtype in (jnp.bfloat16, jnp.float16, jnp.float32)
-            else dtype)
-
-
-def _apply_blocks(a: BlockMatrix, x: jax.Array) -> jax.Array:
-    """Distributed A·X for a BlockMatrix A and a dense (n, k) panel X.
-
-    The panel is reshaped onto A's block rows so each (bs×bs)·(bs×k) product
-    is a local GEMM; the k-axis stays replicated (RHS panels are thin
-    relative to A). Accumulates in f32 like the multiply engines. Under the
-    ``pallas`` engine the whole panel product runs as one fused kernel with
-    the k-sum in VMEM scratch.
-    """
-    _bump("solve_applies")
-    if current_engine() == "pallas":
-        from repro.kernels.matmul import ops as mm_ops  # late: optional layer
-
-        # out_dtype keeps the kernel's f32 accumulator un-rounded on the
-        # flush: a bf16 block matrix must not squeeze an f32 RHS panel
-        # through bf16 on the way out (the einsum branch below never does).
-        out = mm_ops.matmul(mm_ops.blocks_to_dense(a.blocks), x,
-                            out_dtype=_accum_dtype(a.blocks.dtype))
-        return out.astype(x.dtype)
-    b, _, bs, _ = a.blocks.shape
-    xb = x.reshape(b, bs, x.shape[-1])
-    acc = _accum_dtype(a.blocks.dtype)
-    out = jnp.einsum("ijab,jbk->iak", a.blocks, xb,
-                     preferred_element_type=acc,
-                     precision=dot_precision(a.blocks.dtype, xb.dtype))
-    return out.reshape(b * bs, x.shape[-1]).astype(x.dtype)
-
-
-def _leaf_solve(block: jax.Array, rhs: jax.Array, solver: str) -> jax.Array:
-    """Solve the grid==1 system with the shared leaf-solver registry.
-
-    `linalg` uses the LAPACK solve directly (cheaper + better conditioned
-    than inverse-then-multiply); `pallas` factorizes with XLA's LU and runs
-    both substitution sweeps through the blocked Pallas triangular-solve
-    kernel — also inverse-free, with the O(bs²·k) substitutions on the
-    kernel path; the remaining kernel-backed solvers go through their
-    explicit inverse, which is the point of having them pluggable.
-    """
-    _bump("leaf_solves")
-    f32 = block.astype(jnp.float32)
-    r32 = rhs.astype(jnp.float32)
-    if solver == "linalg":
-        return jnp.linalg.solve(f32, r32).astype(rhs.dtype)
-    if solver == "pallas":
-        from repro.kernels.leaf_inverse import ops as tri_ops  # late import
-
-        lu, _, perm = jax.lax.linalg.lu(f32)
-        y = tri_ops.triangular_solve(lu, r32[perm], lower=True,
-                                     unit_diagonal=True)
-        x = tri_ops.triangular_solve(lu, y, lower=False)
-        return x.astype(rhs.dtype)
-    inv = LEAF_SOLVERS[solver](block)
-    return jnp.matmul(inv.astype(jnp.float32), r32,
-                      precision=dot_precision(jnp.float32)).astype(rhs.dtype)
-
-
-def _solve(a: BlockMatrix, b: jax.Array, leaf_solver: str) -> jax.Array:
-    grid = a.grid
-    if grid == 1:
-        return _leaf_solve(a.blocks[0, 0], b, leaf_solver)
-
-    bs = a.block_size
-    a11, a12, a21, a22 = a.split()
-    half = a11.n
-    b1, b2 = b[:half], b[half:]
-
-    # One recursive solve covers both III (= A11⁻¹A12) and Y1 (= A11⁻¹B1):
-    # the B1 columns ride along as extra RHS.
-    z = _solve(a11, jnp.concatenate([a12.to_dense(), b1], axis=1),
-               leaf_solver)
-    iii, y1 = z[:, :half], z[:, half:]
-
-    v = _apply_blocks(a21, iii) - a22.to_dense()          # −Schur complement
-    _bump("subtracts")
-    rhs2 = _apply_blocks(a21, y1) - b2
-    _bump("subtracts")
-    x2 = _solve(BlockMatrix.from_dense(v, bs), rhs2, leaf_solver)
-
-    acc = _accum_dtype(iii.dtype)
-    _bump("solve_applies")                                # III·X2 panel GEMM
-    x1 = y1 - jnp.matmul(iii, x2, preferred_element_type=acc,
-                         precision=dot_precision(iii.dtype, x2.dtype)
-                         ).astype(y1.dtype)
-    _bump("subtracts")
-    return jnp.concatenate([x1, x2], axis=0)
 
 
 def spin_solve(a: BlockMatrix, b: jax.Array, *,
@@ -178,15 +75,7 @@ def spin_solve(a: BlockMatrix, b: jax.Array, *,
             x = spin_solve(BlockMatrix(a.blocks.astype(cd)), b.astype(cd),
                            leaf_solver=leaf_solver)
             return x.astype(b.dtype)
-    grid = a.grid
-    if grid & (grid - 1):
-        raise ValueError(f"grid must be a power of two, got {grid}")
-    if b.shape[0] != a.n:
-        raise ValueError(f"rhs rows {b.shape[0]} != matrix dim {a.n}")
-    vector = b.ndim == 1
-    rhs = b[:, None] if vector else b
-    x = _solve(a, rhs, leaf_solver)
-    return x[:, 0] if vector else x
+    return solve(a, b, leaf_solver)
 
 
 @functools.partial(jax.jit,
@@ -266,8 +155,7 @@ def spin_solve_sharded(a, b: jax.Array, block_size: int | None = None, *,
     the sharded placement; explicit block_size / leaf_solver / engine
     arguments always override the planner's choices.
     """
-    from repro.parallel.sharded_blockmatrix import (ShardedBlockMatrix,
-                                                    solve_program)
+    from repro.parallel.sharded_blockmatrix import solve_program
 
     from .spin import _policy_active, _resolve_sharded_config
 
@@ -276,7 +164,7 @@ def spin_solve_sharded(a, b: jax.Array, block_size: int | None = None, *,
         from .precision import resolve_precision
 
         policy = resolve_precision(precision)
-        dense_in = not isinstance(a, (BlockMatrix, ShardedBlockMatrix))
+        dense_in = not isinstance(a, BlockMatrix)
         if not policy.is_exact and _policy_active(
                 policy, a.dtype if dense_in else a.blocks.dtype):
             if not dense_in:

@@ -32,7 +32,6 @@ import numpy as np
 from .blockmatrix import BlockMatrix
 from .matrix_io import load_blockmatrix, save_blockmatrix
 from .multiply import multiply
-from .spin import leaf_inverse
 
 __all__ = ["CheckpointedSpin", "save_service_snapshot",
            "load_service_snapshot", "validate_snapshot_key",
@@ -54,8 +53,8 @@ class CheckpointedSpin:
             BlockMatrix(a), BlockMatrix(b)).blocks)
         self._sub = jax.jit(lambda a, b: a - b)
         self._neg = jax.jit(lambda a: -a)
-        self._leaf = jax.jit(lambda a: leaf_inverse(
-            BlockMatrix(a), solver=leaf_solver).blocks)
+        self._leaf = jax.jit(lambda a: BlockMatrix(a).leaf_inverse(
+            leaf_solver).blocks)
 
     # -- persistence --------------------------------------------------------
     def _path(self, name: str) -> str:

@@ -1,114 +1,30 @@
-"""SPIN: Strassen's block-recursive matrix inversion (paper Algorithm 1/2).
+"""SPIN's inversion entry points: a block container, a dense matrix, a mesh.
 
-Per recursion level (paper §3.1):      leaf (grid == 1):
-    I    <- Inverse(A11)                   invert the single block locally
-    II   <- A21 . I                        (Pallas Gauss-Jordan kernel or
-    III  <- I . A12                         jnp.linalg.inv oracle)
-    IV   <- A21 . III
-    V    <- IV - A22
-    VI   <- Inverse(V)
-    C12  <- III . VI
-    C21  <- VI . II
-    VII  <- III . C21
-    C11  <- I - VII
-    C22  <- -VI
-
-Exactly 6 distributed multiplies + 2 subtracts + 1 scalarMul per level and
-ONE local O(bs^3) op per leaf — vs the LU baseline's ~9x leaf work and extra
-multiplies (see lu_inverse.py and costmodel.py). Valid for matrices whose
-leading principal blocks are invertible (SPD in particular — the class the
-paper targets).
-
-The whole recursion is structural (depth = log2(b) fixed at trace time), so
-`jax.jit(spin_inverse)` compiles the ENTIRE multi-level algorithm into one
-XLA program — no per-level Spark job scheduling. That is the single biggest
-behavioural difference vs the paper's runtime and is accounted for in
-DESIGN.md §11.
+`spin_inverse` runs the paper's Algorithm 2 (`core.recursion.invert`) on a
+block container; `spin_inverse_dense` jits it end to end for a dense
+matrix; `spin_inverse_sharded` runs the same recursion on a
+`ShardedBlockMatrix`, whose blocks stay on the mesh, as one program
+(`repro.parallel.sharded_blockmatrix`). Each entry resolves the planner's
+choices and the precision policy before the recursion is traced.
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
 
 from repro.obs.trace import LAYOUT
-from repro.obs.trace import TRACER as _TRACER
-from repro.obs.trace import hlo_op_scopes, host_span, level_scope, step_scope
+from repro.obs.trace import hlo_op_scopes, host_span, step_scope
 
-from .blockmatrix import BlockMatrix, _bump
-from .multiply import (current_engine, multiply, multiply_engine,
-                       multiply_subtract, subtract_multiply, validate_engine)
-from .precision import dot_precision
+from .blockmatrix import BlockMatrix
+from .multiply import current_engine, multiply_engine, validate_engine
+from .recursion import invert
 
 __all__ = ["spin_inverse", "spin_inverse_dense", "spin_inverse_sharded",
-           "leaf_inverse", "LEAF_SOLVERS", "inverse_op_scopes"]
-
-
-# ---------------------------------------------------------------------------
-# Leaf solvers: invert one bs×bs block on a single device.
-# ---------------------------------------------------------------------------
-
-
-def _leaf_linalg(block: jax.Array) -> jax.Array:
-    # LAPACK-style getrf/getri; the oracle everything else is tested against.
-    f32 = block.astype(jnp.float32)
-    return jnp.linalg.inv(f32).astype(block.dtype)
-
-
-def _leaf_gauss_jordan(block: jax.Array) -> jax.Array:
-    # Pallas scalar Gauss-Jordan kernel (TPU target, interpret=True on CPU).
-    from repro.kernels.leaf_inverse import ops as gj_ops
-
-    return gj_ops.leaf_inverse(block)
-
-
-def _leaf_pallas(block: jax.Array) -> jax.Array:
-    # Pallas BLOCKED Gauss-Jordan: panel elimination with rank-t MXU updates
-    # (kernels/leaf_inverse.blocked_leaf_inverse_pallas) — the leaf half of
-    # the `pallas` engine family.
-    from repro.kernels.leaf_inverse import ops as gj_ops
-
-    return gj_ops.blocked_leaf_inverse(block)
-
-
-def _leaf_qr(block: jax.Array) -> jax.Array:
-    f32 = block.astype(jnp.float32)
-    q, r = jnp.linalg.qr(f32)
-    n = block.shape[-1]
-    rinv = jax.scipy.linalg.solve_triangular(r, jnp.eye(n, dtype=jnp.float32))
-    return jnp.matmul(rinv, q.T, precision=dot_precision(jnp.float32)
-                      ).astype(block.dtype)
-
-
-LEAF_SOLVERS: dict[str, Callable[[jax.Array], jax.Array]] = {
-    "linalg": _leaf_linalg,
-    "gauss_jordan": _leaf_gauss_jordan,
-    "pallas": _leaf_pallas,
-    "qr": _leaf_qr,
-}
-
-
-def leaf_inverse(a: BlockMatrix, solver: str = "linalg") -> BlockMatrix:
-    """Paper Algorithm 2 `if` branch: grid==1, invert the block in place.
-
-    The paper deliberately does NOT collect the block to the driver ("we do a
-    map which takes the only block of the RDD") — likewise we invert in situ
-    on whichever device holds the block; no reshard is issued.
-    """
-    if a.grid != 1:
-        raise ValueError(f"leaf_inverse expects grid==1, got {a.grid}")
-    _bump("leaf_inversions")
-    inv = LEAF_SOLVERS[solver](a.blocks[0, 0])
-    return BlockMatrix(inv[None, None])
-
-
-# ---------------------------------------------------------------------------
-# The recursion (paper Algorithm 2 `else` branch)
-# ---------------------------------------------------------------------------
+           "inverse_op_scopes"]
 
 
 def _policy_active(policy, operand_dtype) -> bool:
@@ -150,11 +66,8 @@ def spin_inverse(a: BlockMatrix, *, leaf_solver: str = "linalg",
     f32, and returns blocks at the policy's store dtype; the default is
     bitwise-unchanged.
 
-    `_level` is the recursion depth. Each internal node runs under the
-    named scope `spin.L<_level>` with one scope per step, and a leaf under
-    `spin.L<_level>/leaf` (repro.obs.trace; HLO metadata only, always on).
-    Under $SPIN_TRACE each node and leaf also records a
-    kind="recursion_level" point event at trace time.
+    `_level` is the depth `a` sits at, which names its scopes
+    (`core.recursion.invert`).
     """
     if auto:
         from repro.planner import planned_leaf_solver
@@ -166,51 +79,7 @@ def spin_inverse(a: BlockMatrix, *, leaf_solver: str = "linalg",
         policy = resolve_precision(precision)
         if not policy.is_exact and _policy_active(policy, a.blocks.dtype):
             return _lowp_inverse_blocks(a, leaf_solver, policy)
-    b = a.grid
-    if b & (b - 1):
-        raise ValueError(f"grid must be a power of two, got {b}")
-    if b == 1:
-        if _TRACER.enabled:
-            _TRACER.event("spin.leaf", "recursion_level", level=_level,
-                          grid=1, op="leaf", solver=leaf_solver,
-                          block_size=a.block_size,
-                          dtype=str(a.blocks.dtype))
-        with level_scope(_level), step_scope("leaf"):
-            return leaf_inverse(a, solver=leaf_solver)
-
-    if _TRACER.enabled:
-        _TRACER.event("spin.level", "recursion_level", level=_level, grid=b,
-                      op="inverse_node", block_size=a.block_size,
-                      dtype=str(a.blocks.dtype),
-                      engine=current_engine() or "einsum")
-    with level_scope(_level):
-        with step_scope("split"):
-            a11, a12, a21, a22 = a.split()
-        i_ = spin_inverse(a11, leaf_solver=leaf_solver,
-                          _level=_level + 1)              # I   = A11^-1
-        with step_scope("II"):
-            ii = multiply(a21, i_)                        # II  = A21 I
-        with step_scope("III"):
-            iii = multiply(i_, a12)                       # III = I A12
-        # IV = A21·III and V = IV − A22 (= −Schur) as ONE fused Schur
-        # update: bitwise-identical multiply-then-subtract on the XLA
-        # engines, a single Pallas kernel under engine="pallas". Op counts
-        # book 1 multiply + 1 subtract either way.
-        with step_scope("schur"):
-            v = multiply_subtract(a21, iii, a22)
-        vi = spin_inverse(v, leaf_solver=leaf_solver,
-                          _level=_level + 1)              # VI  = V^-1
-        with step_scope("C12"):
-            c12 = multiply(iii, vi)
-        with step_scope("C21"):
-            c21 = multiply(vi, ii)
-        # VII = III·C21 and C11 = I − VII, same fused Schur-update contract.
-        with step_scope("C11"):
-            c11 = subtract_multiply(i_, iii, c21)
-        with step_scope("neg"):
-            c22 = vi.neg()                                # scalarMul(VI, -1)
-        with step_scope("arrange"):
-            return BlockMatrix.arrange(c11, c12, c21, c22)
+    return invert(a, leaf_solver, _level)
 
 
 @functools.partial(jax.jit,
@@ -252,20 +121,10 @@ def inverse_op_scopes(n: int, block_size: int, leaf_solver: str = "linalg",
         lowered = _spin_inverse_dense.lower(shape, block_size, leaf_solver,
                                             engine)
     else:
-        from jax.sharding import NamedSharding
+        from repro.parallel.sharded_blockmatrix import lower_inverse_program
 
-        from repro.compat import set_mesh
-        from repro.parallel.sharded_blockmatrix import (_inverse_program,
-                                                        grid_spec,
-                                                        mesh_fingerprint)
-
-        grid, axes = n // block_size, ("data", "model")
-        sharding = NamedSharding(mesh, grid_spec(grid, grid, mesh, axes))
-        blocks = jax.ShapeDtypeStruct((grid, grid, block_size, block_size),
-                                      jnp.float32, sharding=sharding)
-        with set_mesh(mesh):
-            lowered = _inverse_program.lower(blocks, leaf_solver, engine,
-                                             axes, mesh_fingerprint())
+        lowered = lower_inverse_program(n, block_size, leaf_solver, engine,
+                                        mesh)
     return hlo_op_scopes(lowered.compile().as_text())
 
 
@@ -365,7 +224,7 @@ def _resolve_sharded_config(kind: str, a, block_size: int | None,
     """
     from repro.parallel.sharded_blockmatrix import ShardedBlockMatrix
 
-    dense_in = not isinstance(a, (BlockMatrix, ShardedBlockMatrix))
+    dense_in = not isinstance(a, BlockMatrix)
     n = a.shape[0] if dense_in else a.n
     if auto or (dense_in and block_size is None):
         from repro.planner import get_plan
@@ -381,7 +240,7 @@ def _resolve_sharded_config(kind: str, a, block_size: int | None,
 
     if dense_in:
         a = ShardedBlockMatrix.from_dense(a, block_size)
-    elif isinstance(a, BlockMatrix):
+    elif not isinstance(a, ShardedBlockMatrix):
         a = ShardedBlockMatrix.from_blockmatrix(a)
     return a, leaf_solver or "linalg", engine, dense_in
 
@@ -422,12 +281,10 @@ def spin_inverse_sharded(a, block_size: int | None = None, *,
 
         validate_engine(engine)
         if precision is not None:
-            from repro.parallel.sharded_blockmatrix import ShardedBlockMatrix
-
             from .precision import resolve_precision
 
             policy = resolve_precision(precision)
-            dense_in = not isinstance(a, (BlockMatrix, ShardedBlockMatrix))
+            dense_in = not isinstance(a, BlockMatrix)
             if not policy.is_exact and _policy_active(
                     policy, a.dtype if dense_in else a.blocks.dtype):
                 if not dense_in:

@@ -26,9 +26,9 @@ Three variants share this one recursion:
              along sharded dimensions (see blockmatrix.assemble_quadrants).
   * mesh-resident — the same grid recursion under an active mesh: every
              intermediate (quadrant sums, the seven m_i, padding buffers,
-             the combined output) is re-anchored with a grid-over-mesh
-             sharding constraint and recorded in the spec ledger
-             (parallel.sharded_blockmatrix.record_specs), so no Strassen
+             the combined output) is re-anchored with the grid-over-mesh
+             rule and recorded in the spec ledger (core.placement:
+             `constrain_grid`, `record_specs`), so no Strassen
              level gathers to dense. Base-case multiplies dispatch through
              `multiply_blocks`, whose shard_map SUMMA path is the fallback
              wherever the (halved, possibly padded) grid no longer splits
@@ -61,11 +61,11 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.obs.trace import TRACER as _TRACER
 
 from .blockmatrix import _bump, assemble_quadrants
 from .costmodel import STRASSEN_CUTOFF
+from .placement import constrain_grid
 
 __all__ = ["STRASSEN_CUTOFF_ENV", "strassen_cutoff", "strassen_matmul",
            "strassen_matmul_blocks", "strassen_schur_update_blocks"]
@@ -87,39 +87,6 @@ def strassen_cutoff() -> int:
     return STRASSEN_CUTOFF if raw is None else max(raw, 0)
 
 
-# ---------------------------------------------------------------------------
-# Mesh anchoring: the sharded recursion's residency contract, for Strassen
-# intermediates.
-# ---------------------------------------------------------------------------
-
-
-def _anchor(blocks: jax.Array, op: str) -> jax.Array:
-    """Re-assert grid-over-mesh sharding on a Strassen intermediate.
-
-    Same contract as sharded_blockmatrix._constrain: under an active mesh
-    the (possibly halved/padded) grid is constrained onto the mesh axes
-    wherever divisibility allows, and every constraint is recorded in the
-    spec ledger so tests can prove no Strassen level replicated. Off-mesh
-    this is a recorded no-op. Axis names resolve like the SUMMA engines'
-    `_mesh_axes_for` (prefer "data"/"model", else first/last mesh axis).
-    """
-    # Late import: sharded_blockmatrix imports core.multiply, which
-    # dispatches into this module.
-    from repro.parallel.sharded_blockmatrix import _record, grid_spec
-
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or not mesh.shape:
-        _record(op, "grid", blocks.shape, None, ("data", "model"), None)
-        return blocks
-    names = list(mesh.shape.keys())
-    axes = ("data" if "data" in names else names[0],
-            "model" if "model" in names else names[-1])
-    spec = grid_spec(blocks.shape[0], blocks.shape[1], mesh, axes)
-    blocks = jax.lax.with_sharding_constraint(blocks, spec)
-    _record(op, "grid", blocks.shape, spec, axes, mesh)
-    return blocks
-
-
 def _pad_grid(x: jax.Array, op: str) -> jax.Array:
     """Zero-pad an odd (g, g, ...) grid to (g+1, g+1, ...) for an even split.
 
@@ -128,8 +95,8 @@ def _pad_grid(x: jax.Array, op: str) -> jax.Array:
     the other operand, so slicing the product back to g×g is exact.
     """
     g = x.shape[0]
-    buf = _anchor(jnp.zeros((g + 1, g + 1) + x.shape[2:], x.dtype), op)
-    return _anchor(jax.lax.dynamic_update_slice(
+    buf = constrain_grid(jnp.zeros((g + 1, g + 1) + x.shape[2:], x.dtype), op)
+    return constrain_grid(jax.lax.dynamic_update_slice(
         buf, x, (0,) * x.ndim), op)
 
 
@@ -179,16 +146,16 @@ def strassen_matmul_blocks(a: jax.Array, b: jax.Array, *,
         ap = _pad_grid(a, "strassen_pad")
         bp = _pad_grid(b, "strassen_pad")
         out = strassen_matmul_blocks(ap, bp, cutoff=cutoff, base=base)
-        return _anchor(out[:g, :g], "strassen_unpad")
+        return constrain_grid(out[:g, :g], "strassen_unpad")
 
     a11, a12, a21, a22 = _quads(a)
     b11, b12, b21, b22 = _quads(b)
 
     def add(x, y):
-        return _anchor(x + y, "strassen_add")
+        return constrain_grid(x + y, "strassen_add")
 
     def sub(x, y):
-        return _anchor(x - y, "strassen_add")
+        return constrain_grid(x - y, "strassen_add")
 
     rec = functools.partial(strassen_matmul_blocks, cutoff=cutoff, base=base)
     m1 = rec(add(a11, a22), add(b11, b22))
@@ -204,10 +171,10 @@ def strassen_matmul_blocks(a: jax.Array, b: jax.Array, *,
     c22 = add(sub(add(m1, m3), m2), m6)
     # 10 operand-side + 8 output-side elementwise passes per split level.
     _bump("strassen_adds", 18)
-    into = _anchor(jnp.zeros((g, g) + a.shape[2:], a.dtype),
-                   "strassen_combine")
+    into = constrain_grid(jnp.zeros((g, g) + a.shape[2:], a.dtype),
+                          "strassen_combine")
     out = assemble_quadrants(c11, c12, c21, c22, into=into)
-    return _anchor(out, "strassen_combine")
+    return constrain_grid(out, "strassen_combine")
 
 
 def strassen_schur_update_blocks(c: jax.Array, a: jax.Array, b: jax.Array, *,
@@ -232,7 +199,7 @@ def strassen_schur_update_blocks(c: jax.Array, a: jax.Array, b: jax.Array, *,
         return st_ops.base_schur_update(c, a, b, negate_c=negate_c)
     prod = strassen_matmul_blocks(a, b, cutoff=cutoff)
     out = prod - c if negate_c else c - prod
-    return _anchor(out, "strassen_schur")
+    return constrain_grid(out, "strassen_schur")
 
 
 # ---------------------------------------------------------------------------

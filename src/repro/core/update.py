@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from .blockmatrix import BlockMatrix, _bump
+from .placement import constrain_grid, constrain_panel, mesh_fingerprint
 from .precision import dot_precision
 from .verify import residual_tolerance
 
@@ -149,25 +150,25 @@ def _smw_inverse_blocks(blocks: jax.Array, u: jax.Array, v: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _sharded_helpers():
-    # Late import: core must not import the parallel layer at module scope.
-    from repro.parallel import sharded_blockmatrix as sbm
+def _sharded_type():
+    # Late import: the mesh container belongs to the parallel layer, which
+    # imports core.
+    from repro.parallel import ShardedBlockMatrix
 
-    return sbm
+    return ShardedBlockMatrix
 
 
 @functools.partial(jax.jit, static_argnames=("axes", "mesh_fp"))
 def _smw_inverse_sharded_program(blocks: jax.Array, u: jax.Array,
                                  v: jax.Array, axes: tuple[str, str],
                                  mesh_fp: str) -> jax.Array:
-    sbm = _sharded_helpers()
-    anchored = sbm.ShardedBlockMatrix(blocks, axes).constrain("smw_input")
+    anchored = constrain_grid(blocks, "smw_input", axes)
 
     def anchor(x, op):
-        return sbm._constrain_panel(x, op, axes)
+        return constrain_panel(x, op, axes)
 
-    out = _smw_inverse_blocks(anchored.blocks, u, v, constrain_panel=anchor)
-    return sbm._constrain(out, "smw_update", axes)
+    out = _smw_inverse_blocks(anchored, u, v, constrain_panel=anchor)
+    return constrain_grid(out, "smw_update", axes)
 
 
 def smw_update_inverse(inv, u: jax.Array, v: jax.Array):
@@ -182,12 +183,11 @@ def smw_update_inverse(inv, u: jax.Array, v: jax.Array):
     """
     u, _ = _as_panel(u)
     v, _ = _as_panel(v)
-    sbm = _sharded_helpers()
-    if isinstance(inv, sbm.ShardedBlockMatrix):
+    if isinstance(inv, _sharded_type()):
         _bump("smw_updates")
         blocks = _smw_inverse_sharded_program(
-            inv.blocks, u, v, inv.axes, sbm.mesh_fingerprint())
-        return sbm.ShardedBlockMatrix(blocks, inv.axes)
+            inv.blocks, u, v, inv.axes, mesh_fingerprint())
+        return dataclasses.replace(inv, blocks=blocks)
     if isinstance(inv, BlockMatrix):
         _bump("smw_updates")
         return BlockMatrix(_jit_smw_inverse_blocks(inv.blocks, u, v))
@@ -208,8 +208,7 @@ def smw_update_solve(inv, u: jax.Array, v: jax.Array, rhs: jax.Array
     u, _ = _as_panel(u)
     v, _ = _as_panel(v)
     rhs2, vector = _as_panel(rhs)
-    sbm = _sharded_helpers()
-    if isinstance(inv, (BlockMatrix, sbm.ShardedBlockMatrix)):
+    if isinstance(inv, BlockMatrix):
         x0 = apply_inverse(inv, rhs2)
         p = apply_inverse(inv, u)
         cap = (jnp.eye(u.shape[1], dtype=jnp.float32)
@@ -248,10 +247,9 @@ def _apply_inverse_dense_lowp(inv: jax.Array, rhs: jax.Array,
 @functools.partial(jax.jit, static_argnames=("axes", "mesh_fp"))
 def _apply_sharded_program(blocks: jax.Array, rhs: jax.Array,
                            axes: tuple[str, str], mesh_fp: str) -> jax.Array:
-    sbm = _sharded_helpers()
-    anchored = sbm.ShardedBlockMatrix(blocks, axes).constrain("apply_input")
-    out = _blocks_apply(anchored.blocks, rhs).astype(rhs.dtype)
-    return sbm._constrain_panel(out, "apply_inverse", axes)
+    anchored = constrain_grid(blocks, "apply_input", axes)
+    out = _blocks_apply(anchored, rhs).astype(rhs.dtype)
+    return constrain_panel(out, "apply_inverse", axes)
 
 
 def apply_inverse(inv, rhs: jax.Array, *, precision=None) -> jax.Array:
@@ -266,11 +264,10 @@ def apply_inverse(inv, rhs: jax.Array, *, precision=None) -> jax.Array:
     accumulate in f32 and are unaffected.
     """
     rhs2, vector = _as_panel(rhs)
-    sbm = _sharded_helpers()
-    if isinstance(inv, sbm.ShardedBlockMatrix):
+    if isinstance(inv, _sharded_type()):
         _bump("solve_applies")
         x = _apply_sharded_program(inv.blocks, rhs2, inv.axes,
-                                   sbm.mesh_fingerprint())
+                                   mesh_fingerprint())
     elif isinstance(inv, BlockMatrix):
         _bump("solve_applies")
         x = _jit_blocks_apply(inv.blocks, rhs2).astype(rhs.dtype)
@@ -304,12 +301,11 @@ def _add_low_rank_dense(a: jax.Array, u: jax.Array, v: jax.Array
 def _add_low_rank_sharded_program(blocks: jax.Array, u: jax.Array,
                                   v: jax.Array, axes: tuple[str, str],
                                   mesh_fp: str) -> jax.Array:
-    sbm = _sharded_helpers()
-    anchored = sbm.ShardedBlockMatrix(blocks, axes).constrain("add_input")
-    out = _smw_correction_blocks(anchored.blocks,
+    anchored = constrain_grid(blocks, "add_input", axes)
+    out = _smw_correction_blocks(anchored,
                                  -u.astype(jnp.float32),
                                  v.astype(jnp.float32).T)
-    return sbm._constrain(out, "add_low_rank", axes)
+    return constrain_grid(out, "add_low_rank", axes)
 
 
 def add_low_rank(a, u: jax.Array, v: jax.Array):
@@ -317,11 +313,10 @@ def add_low_rank(a, u: jax.Array, v: jax.Array):
     of `smw_update_inverse`; the service maintains both sides)."""
     u, _ = _as_panel(u)
     v, _ = _as_panel(v)
-    sbm = _sharded_helpers()
-    if isinstance(a, sbm.ShardedBlockMatrix):
+    if isinstance(a, _sharded_type()):
         blocks = _add_low_rank_sharded_program(
-            a.blocks, u, v, a.axes, sbm.mesh_fingerprint())
-        return sbm.ShardedBlockMatrix(blocks, a.axes)
+            a.blocks, u, v, a.axes, mesh_fingerprint())
+        return dataclasses.replace(a, blocks=blocks)
     if isinstance(a, BlockMatrix):
         return BlockMatrix(_jit_add_low_rank_blocks(a.blocks, u, v))
     return _add_low_rank_dense(a, u, v)

@@ -2,8 +2,8 @@
 
 Two kinds of instrumentation live here.
 
-  * **Always-on names.** Both recursions (`core.spin.spin_inverse`,
-    `parallel.sharded_blockmatrix.sharded_spin_inverse`) open a
+  * **Always-on names.** The inversion recursion (`core.recursion.invert`,
+    on one device and on a mesh alike) opens a
     `jax.named_scope` per internal node, `spin.L<k>` at depth k, and inside
     it one per step (`STEPS`: `split`, `II`, `III`, `schur`, `C12`, `C21`,
     `C11`, `neg`, `arrange`); a leaf opens `spin.L<k>/leaf`, and the dense

@@ -18,6 +18,8 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.core.leaf import LEAF_SOLVERS
+
 __all__ = ["Plan", "ProblemSignature", "signature_for", "enumerate_plans",
            "candidate_grids", "mesh_descriptor", "compiles_on_tpu",
            "STRASSEN_MIN_N"]
@@ -236,8 +238,6 @@ def enumerate_plans(sig: ProblemSignature, *,
     signatures (n ≥ STRASSEN_MIN_N) where its recursion actually splits;
     pass `engines=(..., "strassen")` to opt in below that.
     """
-    from repro.core.spin import LEAF_SOLVERS  # late: avoid import cycle
-
     if leaf_solvers is None:
         leaf_solvers = tuple(LEAF_SOLVERS)
     if engines is None:
