@@ -64,6 +64,9 @@ class OpCounts:
     # longer divides the mesh), and leaf inversions (every device repeats
     # each one).
     gather_bytes: int = 0
+    # The part of gather_bytes that arrives while a GEMM step of the same
+    # Pallas SUMMA product runs: every step's bytes but the first.
+    pipelined_gather_bytes: int = 0
     replicated_block_gemms: int = 0
     replicated_leaves: int = 0
     # Nodes of the mesh recursion split and arranged in interleaved

@@ -29,9 +29,16 @@ block's operands land on one node. On a TPU mesh we provide three engines:
                     Algorithm 2 (`V = A21·III − A22`, `C11 = I − III·C21`)
                     fuse the trailing subtract into the same kernel
                     (`schur_update_blocks`), so the intermediate product
-                    never round-trips through HBM. Under a mesh the SUMMA
-                    gathers stay; only the local GEMM swaps to the kernel.
-                    Off-TPU the kernels run in interpret mode (tests/CI).
+                    never round-trips through HBM. Under a mesh its SUMMA
+                    is a k-chunked pipeline (`pallas_matmul_panels`): the
+                    local k extent goes in s steps, the largest divisor of
+                    its block count up to 4 where both mesh axes hold two
+                    devices, and step t+1's A and B chunks are swapped
+                    with the neighbours while step t's kernels run, the
+                    k-sum in f32 throughout. At one local k block (or on
+                    another mesh) s = 1: two all-gathers and one kernel,
+                    as before. Off-TPU the kernels run in interpret mode
+                    (tests/CI).
 
 All engines accumulate in f32 (`preferred_element_type`) so bf16 inputs hit
 the MXU with f32 accumulation — the TPU analogue of JBlas dgemm — and f32
@@ -43,10 +50,12 @@ Grid-to-mesh contract for the shard_map engines:
     B grid (k, j): k over 'data', j over 'model'
     C grid (i, j): i over 'data', j over 'model'
 
-Every SUMMA all-gather (and the ring's ppermute) runs under the `gather`
-step scope (repro.obs.trace) and books the bytes each device receives as
-`OpCounts.gather_bytes`; a product whose grid does not divide the mesh runs
-on every device and books its bs×bs GEMMs as `replicated_block_gemms`.
+Every SUMMA all-gather (and the ring's and the pipeline's ppermutes) runs
+under the `gather` step scope (repro.obs.trace) and books the bytes each
+device receives as `OpCounts.gather_bytes`, the pipeline's steps after the
+first also as `pipelined_gather_bytes`; a product whose grid does not
+divide the mesh runs on every device and books its bs×bs GEMMs as
+`replicated_block_gemms`.
 """
 
 from __future__ import annotations
@@ -163,11 +172,101 @@ def allgather_matmul_panels(a_loc: jax.Array, b_loc: jax.Array, *,
         a_loc, b_loc, model_axis=model_axis, data_axis=data_axis))
 
 
-def pallas_matmul_panels(a_loc: jax.Array, b_loc: jax.Array, *,
+# The Pallas SUMMA takes its local k extent in at most this many steps:
+# enough that all but a quarter of the bytes move behind a GEMM, few enough
+# that each step's GEMMs keep the kernel's large tiles.
+_MAX_SUMMA_STEPS = 4
+_SWAP = ((0, 1), (1, 0))
+
+
+def _summa_steps(local_k: int, model_axis: str, data_axis: str) -> int:
+    """Steps of the Pallas SUMMA product over a local k extent of `local_k`
+    blocks: its largest divisor up to `_MAX_SUMMA_STEPS` where both mesh
+    axes hold two devices (a swap then moves one step's pieces), else 1."""
+    if compat.axis_size(model_axis) != 2 or compat.axis_size(data_axis) != 2:
+        return 1
+    return max(s for s in range(1, _MAX_SUMMA_STEPS + 1) if local_k % s == 0)
+
+
+def _swap(piece: jax.Array, axis_name: str) -> jax.Array:
+    """The other device's `piece` along a two-device axis: one step of a
+    SUMMA gather, under the `gather` step scope; books the bytes this
+    device receives as `gather_bytes` (trace time)."""
+    with step_scope("gather"):
+        got = jax.lax.ppermute(piece, axis_name, _SWAP)
+    _bump("gather_bytes", piece.size * piece.dtype.itemsize)
+    return got
+
+
+def pallas_matmul_panels(a_loc: jax.Array, b_loc: jax.Array,
+                         c_loc: jax.Array | None = None, *,
+                         alpha: float = 1.0, beta: float = 1.0,
                          model_axis: str, data_axis: str) -> jax.Array:
-    """SUMMA gathers with the local grid GEMM swapped for the Pallas kernel."""
-    return matmul_blocks_pallas(*_gather_panels(
-        a_loc, b_loc, model_axis=model_axis, data_axis=data_axis))
+    """SUMMA with the Pallas kernels: β·C + α·A·B on this device's shards,
+    or A·B without `c_loc`, pipelined over `_summa_steps` k-chunks.
+
+    In one step, A's k-panels are gathered along `model` and B's along
+    `data`, and one kernel takes the gathered panels. In s > 1 steps, step t
+    swaps chunk t of A's local k-columns with the `model` neighbour and
+    chunk t of B's local k-rows with the `data` neighbour (on a two-device
+    axis the swap is the all-gather, byte for byte), then runs two kernels:
+    the own A chunk with the B chunk of the same k, the received A chunk
+    with the other. The swaps of chunk t+1 are issued before step t's
+    kernels and awaited after them: an optimization barrier ties their
+    operands to chunk t's arrival and to step t−1's product, so XLA neither
+    hoists every swap to the start nor merges them, and only chunk 0's
+    transfer blocks. Which B chunk meets the own A chunk depends on the
+    device: its own on the mesh diagonal (A's local k is B's), the
+    received one off it. Chunks go dense before they are sent, so only the
+    local panels are copied into the kernels' layout. The k-sum stays in
+    the accumulator dtype (f32) across steps and is cast once at the end.
+    """
+    from repro.kernels.matmul import ops as mm_ops  # late: kernels optional
+
+    steps = _summa_steps(a_loc.shape[1], model_axis, data_axis)
+    if steps == 1:
+        a_full, b_full = _gather_panels(a_loc, b_loc, model_axis=model_axis,
+                                        data_axis=data_axis)
+        if c_loc is None:
+            return mm_ops.grid_matmul(a_full, b_full)
+        return mm_ops.grid_schur_update(c_loc, a_full, b_full, alpha=alpha,
+                                        beta=beta)
+    dense = mm_ops.blocks_to_dense
+    a_parts = [dense(x) for x in jnp.split(a_loc, steps, axis=1)]
+    b_parts = [dense(x) for x in jnp.split(b_loc, steps, axis=0)]
+    diagonal = (jax.lax.axis_index(data_axis)
+                == jax.lax.axis_index(model_axis))
+    acc_dtype = _accum_dtype(a_loc.dtype)
+    out_dtype = a_loc.dtype if c_loc is None else c_loc.dtype
+    acc = None if c_loc is None else dense(c_loc)
+
+    def send(t: int) -> tuple[jax.Array, ...]:
+        a, b = a_parts[t], b_parts[t]
+        if t:
+            _bump("pipelined_gather_bytes",
+                  (a.size + b.size) * a.dtype.itemsize)
+        return a, _swap(a, model_axis), b, _swap(b, data_axis)
+
+    def gemm(acc, a, b, first: bool) -> jax.Array:
+        if acc is None:
+            return mm_ops.matmul(a, b, out_dtype=acc_dtype)
+        return mm_ops.schur_update(acc, a, b, alpha=alpha,
+                                   beta=beta if first else 1.0,
+                                   out_dtype=acc_dtype)
+
+    held = send(0)
+    for t in range(steps):
+        if t + 1 < steps:
+            a_parts[t + 1], b_parts[t + 1], held, acc = (
+                jax.lax.optimization_barrier(
+                    (a_parts[t + 1], b_parts[t + 1], held, acc)))
+            ahead = send(t + 1)
+        a_own, a_got, b_own, b_got = held
+        acc = gemm(acc, a_own, jnp.where(diagonal, b_own, b_got), t == 0)
+        acc = gemm(acc, a_got, jnp.where(diagonal, b_got, b_own), False)
+        if t + 1 < steps:
+            held = ahead
+    return mm_ops.dense_to_blocks(acc.astype(out_dtype), a_loc.shape[2])
 
 
 def ring_matmul_panels(a_loc: jax.Array, b_loc: jax.Array, *, model_axis: str,
@@ -319,11 +418,9 @@ def schur_update_blocks(c: jax.Array, a: jax.Array, b: jax.Array, *,
         data_axis, model_axis = axes
 
         def local(c_loc, a_loc, b_loc):
-            a_full, b_full = _gather_panels(a_loc, b_loc,
-                                            model_axis=model_axis,
-                                            data_axis=data_axis)
-            return mm_ops.grid_schur_update(c_loc, a_full, b_full,
-                                            alpha=alpha, beta=beta)
+            return pallas_matmul_panels(a_loc, b_loc, c_loc, alpha=alpha,
+                                        beta=beta, model_axis=model_axis,
+                                        data_axis=data_axis)
 
         spec = P(data_axis, model_axis, None, None)
         return compat.shard_map(local, mesh=mesh, in_specs=(spec,) * 3,

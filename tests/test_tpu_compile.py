@@ -151,6 +151,47 @@ def test_sharded_pallas_recursion_compiles_on_a_four_chip_mesh(
     assert "tpu_custom_call" in text
 
 
+def test_mesh_summa_swaps_each_step_behind_the_kernels(topo, monkeypatch):
+    """An L0-shaped product of the mesh cell, a 16×16 grid of 1024 blocks
+    on the (2, 2) mesh (8 local k blocks, so 4 steps), compiled for a
+    described v5e:2x2. Each step swaps one A and one B chunk, each by its
+    own collective-permute: none merged, no all-gather left. In the
+    scheduled program every swap but the first step's is started before a
+    Pallas kernel and awaited after it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.compat import set_mesh
+    from repro.core.multiply import multiply_blocks
+    from repro.kernels.matmul import ops as mm_ops
+
+    monkeypatch.setattr(mm_ops, "pallas_interpret_default", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    grid, bs, steps = 16, 1024, 4
+    x = jax.ShapeDtypeStruct(
+        (grid, grid, bs, bs), jnp.float32,
+        sharding=NamedSharding(mesh, P("data", "model", None, None)))
+    with set_mesh(mesh):
+        text = jax.jit(lambda a, b: multiply_blocks(a, b, "pallas")).lower(
+            x, x).compile().as_text()
+    entry = text[text.index("\nENTRY"):].splitlines()
+    started, overlapped, kernels = {}, 0, 0
+    for line in entry:
+        name = re.match(r"\s*(?:ROOT )?%(\S+) = ", line)
+        if name is None:
+            continue
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kernels += 1
+        elif " collective-permute-start(" in line:
+            started[name.group(1)] = kernels
+        elif done := re.search(r" collective-permute-done\(%(\S+?)\)",
+                               line):
+            overlapped += kernels > started[done.group(1)]
+    assert "all-gather" not in "\n".join(entry)
+    assert len(started) == 2 * steps
+    assert overlapped == 2 * (steps - 1)
+
+
 def test_pallas_recursion_compiles_with_its_rule_tiles(one_chip,
                                                       monkeypatch):
     """The one-chip recursion at products of 2048 and 1024, where the tile
