@@ -74,6 +74,9 @@ class OpCounts:
     # arranges; parallel/sharded_blockmatrix.py):
     local_splits: int = 0
     local_arranges: int = 0
+    # Matrices of a stack inverted by one `spin_inverse_batched` call (its
+    # scan traces the recursion once; the counters above are one slice's).
+    batched_matrices: int = 0
 
     def as_dict(self) -> dict[str, int]:
         return dataclasses.asdict(self)

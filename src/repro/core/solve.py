@@ -8,9 +8,10 @@ products (paper Algorithm 2's I/III/V names) in their inverse-free Schur
 form (`core.recursion.solve`, which also serves the mesh). Leaf systems go
 through the same pluggable leaf solvers as `spin_inverse` (`core.leaf`).
 
-`spin_inverse_batched` vmaps the whole SPIN recursion over a leading batch
+`spin_inverse_batched` maps the whole SPIN recursion over a leading batch
 axis of SPD matrices — the shape Shampoo's stacked-layer factor refresh
-needs (L, d, d) — compiling ONE program for the batch instead of L.
+needs (L, d, d) — compiling ONE program for the batch instead of L;
+`batched_op_scopes` maps that program's instructions to their scopes.
 """
 
 from __future__ import annotations
@@ -22,14 +23,16 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .blockmatrix import BlockMatrix
+from repro.obs.trace import BATCH, hlo_op_scopes, host_span, step_scope
+
+from .blockmatrix import BlockMatrix, _bump
 from .multiply import current_engine, multiply_engine, validate_engine
 from .precision import dot_precision
 from .recursion import solve
 from .spin import spin_inverse_dense
 
 __all__ = ["spin_solve", "spin_solve_dense", "spin_solve_sharded",
-           "spin_inverse_batched", "solve_grid_for",
+           "spin_inverse_batched", "batched_op_scopes", "solve_grid_for",
            "SketchedInverse", "sketched_approx_inverse"]
 
 
@@ -287,32 +290,39 @@ def spin_inverse_batched(batch: jax.Array, block_size: int | None = None,
     swap in jax.vmap and relax the exactness test to allclose.
     One program is compiled for the whole stack either way, which is the
     batched L/R factor refresh Shampoo's stacked layers need.
+
+    The call runs inside the always-on host span `spin.inverse_batched`;
+    the scan's slicing and stacking run under the scope `spin.batch`, and
+    tracing the program books the stack's depth as
+    `OpCounts.batched_matrices`.
     """
-    if batch.ndim != 3:
-        raise ValueError(f"expected (batch, n, n), got {batch.shape}")
-    validate_engine(engine)
-    from .precision import resolve_precision
-    from .spin import _policy_active
+    with host_span("spin.inverse_batched"):
+        if batch.ndim != 3:
+            raise ValueError(f"expected (batch, n, n), got {batch.shape}")
+        validate_engine(engine)
+        from .precision import resolve_precision
+        from .spin import _policy_active
 
-    if compute_dtype is not None:
-        from .precision import (policy_from_compute_dtype,
-                                warn_deprecated_dtype_kwarg)
+        if compute_dtype is not None:
+            from .precision import (policy_from_compute_dtype,
+                                    warn_deprecated_dtype_kwarg)
 
-        warn_deprecated_dtype_kwarg("spin_inverse_batched")
-        if precision is None:
-            precision = policy_from_compute_dtype(compute_dtype)
-    policy = resolve_precision(precision)
-    if block_size is None:
-        from repro.planner import planned_block_size
+            warn_deprecated_dtype_kwarg("spin_inverse_batched")
+            if precision is None:
+                precision = policy_from_compute_dtype(compute_dtype)
+        policy = resolve_precision(precision)
+        if block_size is None:
+            from repro.planner import planned_block_size
 
-        block_size = planned_block_size(batch.shape[-1], batch.dtype)
-    if not policy.is_exact and _policy_active(policy, batch.dtype):
-        cd = policy.resolve_compute(batch.dtype)
-        out = _spin_inverse_batched(batch.astype(cd), block_size,
-                                    leaf_solver, engine or current_engine())
-        return out.astype(policy.resolve_store(batch.dtype))
-    return _spin_inverse_batched(batch, block_size, leaf_solver,
-                                 engine or current_engine())
+            block_size = planned_block_size(batch.shape[-1], batch.dtype)
+        if not policy.is_exact and _policy_active(policy, batch.dtype):
+            cd = policy.resolve_compute(batch.dtype)
+            out = _spin_inverse_batched(batch.astype(cd), block_size,
+                                        leaf_solver,
+                                        engine or current_engine())
+            return out.astype(policy.resolve_store(batch.dtype))
+        return _spin_inverse_batched(batch, block_size, leaf_solver,
+                                     engine or current_engine())
 
 
 @functools.partial(jax.jit,
@@ -320,6 +330,30 @@ def spin_inverse_batched(batch: jax.Array, block_size: int | None = None,
 def _spin_inverse_batched(batch: jax.Array, block_size: int,
                           leaf_solver: str = "linalg",
                           engine: str | None = None) -> jax.Array:
+    _bump("batched_matrices", batch.shape[0])
     fn = functools.partial(spin_inverse_dense, block_size=block_size,
                            leaf_solver=leaf_solver, engine=engine)
-    return jax.lax.map(fn, batch)
+    with step_scope(BATCH):
+        return jax.lax.map(fn, batch)
+
+
+def batched_op_scopes(batch: int, n: int, block_size: int,
+                      leaf_solver: str = "linalg",
+                      engine: str | None = None, *, sharding=None
+                      ) -> dict[str, dict[str, tuple]]:
+    """{module: {instruction: (level, step)}} of the two programs that
+    `optim.spin_shampoo.invert_spd` runs on a (batch, n, n) float32 stack:
+    its damping and `spin_inverse_batched`'s scan. Like
+    `core.spin.inverse_op_scopes`, it compiles the same jitted entries from
+    shapes (a cache hit once the caller has run them); give the `sharding`
+    of an operand committed to a device."""
+    from repro.optim.spin_shampoo import damp
+
+    shape = jax.ShapeDtypeStruct((batch, n, n), jnp.float32,
+                                 sharding=sharding)
+    texts = [damp.lower(shape, 0.0).compile().as_text(),
+             _spin_inverse_batched.lower(
+                 shape, block_size, leaf_solver,
+                 engine or current_engine()).compile().as_text()]
+    return {mod: ops for text in texts
+            for mod, ops in hlo_op_scopes(text).items()}

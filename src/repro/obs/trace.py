@@ -7,7 +7,11 @@ Two kinds of instrumentation live here.
     `jax.named_scope` per internal node, `spin.L<k>` at depth k, and inside
     it one per step (`STEPS`: `split`, `II`, `III`, `schur`, `C12`, `C21`,
     `C11`, `neg`, `arrange`); a leaf opens `spin.L<k>/leaf`, and the dense
-    entry's block layout opens `spin.layout` outside any level. On a mesh
+    entry's block layout opens `spin.layout` outside any level. What a
+    stack of matrices adds around the per-matrix recursion
+    (`optim.spin_shampoo.invert_spd`'s damping, the scan of
+    `core.solve.spin_inverse_batched` slicing and stacking) opens
+    `spin.batch`. On a mesh
     each SUMMA all-gather opens `gather` inside its product step, so it
     reads as `spin.L<k>/<step>/gather` and `op_scope` gives it the step
     `gather`. A named scope is HLO metadata only (`op_name`), so the names
@@ -50,8 +54,8 @@ from repro import envconfig
 
 __all__ = ["Span", "SpanTracer", "TRACER", "tracer", "trace_enabled",
            "tracing", "refresh", "TRACE_ENV", "TRACE_DIR_ENV", "STEPS",
-           "LAYOUT", "level_scope", "step_scope", "host_span", "op_scope",
-           "hlo_op_scopes", "profiler_clock"]
+           "LAYOUT", "BATCH", "level_scope", "step_scope",
+           "host_span", "op_scope", "hlo_op_scopes", "profiler_clock"]
 
 TRACE_ENV = "SPIN_TRACE"
 TRACE_DIR_ENV = "SPIN_TRACE_DIR"
@@ -63,11 +67,12 @@ TRACE_DIR_ENV = "SPIN_TRACE_DIR"
 
 LEVEL_PREFIX = "spin.L"
 LAYOUT = "spin.layout"
+BATCH = "spin.batch"
 # Steps of one internal node in Algorithm-2 order (`schur` is IV and V
 # fused, `C11` is VII and the subtract fused), the leaf, the SUMMA gathers
-# inside a product step (mesh only), and the layout.
+# inside a product step (mesh only), the layout, and what a stack adds.
 STEPS = ("split", "II", "III", "schur", "C12", "C21", "C11", "neg",
-         "arrange", "leaf", "gather", LAYOUT)
+         "arrange", "leaf", "gather", LAYOUT, BATCH)
 _LEVEL = re.compile(re.escape(LEVEL_PREFIX) + r"(\d+)")
 
 

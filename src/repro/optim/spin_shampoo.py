@@ -29,10 +29,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import spin_inverse_batched, spin_inverse_dense
+from repro.obs.trace import BATCH, step_scope
 from .adamw import global_norm
 
 __all__ = ["SpinShampooConfig", "spin_shampoo_init", "spin_shampoo_update",
-           "invert_spd"]
+           "damp", "invert_spd"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,30 +48,50 @@ class SpinShampooConfig:
     grafting: bool = True           # graft step norm onto Adam's (stability)
 
 
-def invert_spd(mat: jax.Array, damping: float) -> jax.Array:
-    """(mat + λ·tr/n·I)⁻¹ via distributed SPIN (leaf fallback for odd dims).
+@jax.jit
+def damp(mat: jax.Array, damping) -> jax.Array:
+    """mat + λ·tr/n·I in float32, per matrix of a stack, under the scope
+    `spin.batch`.
 
     Damping is scaled by the mean eigenvalue (trace/n) so it is invariant to
-    the gradient scale, the standard Shampoo/K-FAC choice. Stacked-layer
-    factors (L, d, d) go through `spin_inverse_batched` — one compiled SPIN
-    program for the whole stack instead of L unrolled copies.
+    the gradient scale, the standard Shampoo/K-FAC choice. One program, so
+    the damped copy is the only full-size array it writes."""
+    with step_scope(BATCH):
+        n = mat.shape[-1]
+        lam = damping * (jnp.trace(mat, axis1=-2, axis2=-1) / n + 1e-12)
+        damped = mat + lam[..., None, None] * jnp.eye(n, dtype=mat.dtype)
+        return damped.astype(jnp.float32)
 
-    The block grid comes from the planner's cost-model path (no live
-    measurement — this runs inside `jax.lax.cond` branches at trace time),
-    so each factor dimension lands at the bottom of its §4 U-curve instead
-    of a hand-picked grid.
+
+def invert_spd(mat: jax.Array, damping: float, *,
+               block_size: int | None = None,
+               leaf_solver: str | None = None,
+               engine: str | None = None) -> jax.Array:
+    """(mat + λ·tr/n·I)⁻¹ via SPIN, for one (n, n) factor or an (L, n, n)
+    stack (`damp`, then the recursion).
+
+    `block_size`, `leaf_solver` and `engine` are the plan the recursion
+    runs, as `spin_inverse_dense` takes them. Those not given come from one
+    plan of the planner's cost-model path (no live measurement — this runs
+    inside `jax.lax.cond` branches at trace time), over the given block
+    size where there is one, so each factor dimension lands at the bottom
+    of its §4 U-curve instead of a hand-picked grid. Stacked-layer factors
+    go through `spin_inverse_batched` — one compiled SPIN program for the
+    whole stack instead of L unrolled copies, each slice bitwise the
+    per-matrix inverse.
     """
-    from repro.planner import planned_block_size
+    if None in (block_size, leaf_solver, engine):
+        from repro.planner import planned_fields
 
-    n = mat.shape[-1]
-    lam = damping * (jnp.trace(mat, axis1=-2, axis2=-1) / n + 1e-12)
-    damped = mat + lam[..., None, None] * jnp.eye(n, dtype=mat.dtype)
-
-    bs = planned_block_size(n, jnp.float32)
-    damped32 = damped.astype(jnp.float32)
-    if mat.ndim == 2:
-        return spin_inverse_dense(damped32, bs).astype(mat.dtype)
-    return spin_inverse_batched(damped32, bs).astype(mat.dtype)
+        bs, leaf, eng = planned_fields(mat.shape[-1], jnp.float32,
+                                       block_size=block_size)
+        block_size = block_size or bs
+        leaf_solver = leaf_solver or leaf
+        engine = engine or eng
+    damped = damp(mat, damping)
+    invert = spin_inverse_dense if mat.ndim == 2 else spin_inverse_batched
+    return invert(damped, block_size, leaf_solver,
+                  engine=engine).astype(mat.dtype)
 
 
 class _Factor(NamedTuple):

@@ -20,7 +20,8 @@ from .cache import PLAN_CACHE_VERSION, PlanCache, default_cache, \
     default_cache_path
 from .dispatch import (MEASURE_MAX_N, execute_inverse, execute_solve,
                        get_plan, plan_inverse, plan_solve,
-                       planned_block_size, planned_leaf_solver)
+                       planned_block_size, planned_fields,
+                       planned_leaf_solver)
 from .refactor_policy import (RefactorDecision, RefactorPolicy,
                               smw_update_cost)
 
@@ -31,7 +32,7 @@ __all__ = [
     "LEAF_SOLVER_RATE", "ENGINE_RATE",
     "PlanCache", "default_cache", "default_cache_path", "PLAN_CACHE_VERSION",
     "get_plan", "plan_inverse", "plan_solve", "planned_block_size",
-    "planned_leaf_solver", "execute_inverse", "execute_solve",
+    "planned_fields", "planned_leaf_solver", "execute_inverse", "execute_solve",
     "MEASURE_MAX_N",
     "RefactorDecision", "RefactorPolicy", "smw_update_cost",
 ]

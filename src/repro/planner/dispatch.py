@@ -281,7 +281,7 @@ def plan_solve(dense: jax.Array, rhs: jax.Array, *, plan: Plan | None = None,
 @functools.lru_cache(maxsize=256)
 def _planned_fields(kind: str, n: int, dtype_name: str,
                     block_sizes: tuple[int, ...] | None,
-                    cache_path: str, mesh: str) -> tuple[int, str]:
+                    cache_path: str, mesh: str) -> tuple[int, str, str]:
     # cache_path is part of the memo key so a changed $SPIN_PLAN_CACHE (e.g.
     # a test pointing at a tmpdir) is observed instead of serving answers
     # memoized against the previous cache file. `mesh` is in the key for the
@@ -290,25 +290,29 @@ def _planned_fields(kind: str, n: int, dtype_name: str,
     # mesh (get_plan re-derives the same descriptor via signature_for).
     kw = {"block_sizes": block_sizes} if block_sizes else {}
     plan = get_plan(kind, n, jnp.dtype(dtype_name), measure=False, **kw)
-    return plan.block_size, plan.leaf_solver
+    return plan.block_size, plan.leaf_solver, plan.multiply_engine
+
+
+def planned_fields(n: int, dtype=jnp.float32, kind: str = "inverse",
+                   block_size: int | None = None) -> tuple[int, str, str]:
+    """(block_size, leaf_solver, multiply_engine) of one cost-model plan
+    for (kind, n, dtype), over the given block size only when one is
+    given — trace-time safe."""
+    from .cache import default_cache_path
+    from .plan import mesh_descriptor
+
+    return _planned_fields(kind, int(n), jnp.dtype(dtype).name,
+                           (int(block_size),) if block_size else None,
+                           default_cache_path(), mesh_descriptor())
 
 
 def planned_block_size(n: int, dtype=jnp.float32, kind: str = "inverse"
                        ) -> int:
     """Cost-model-only block size for (kind, n, dtype) — trace-time safe."""
-    from .cache import default_cache_path
-    from .plan import mesh_descriptor
-
-    return _planned_fields(kind, int(n), jnp.dtype(dtype).name, None,
-                           default_cache_path(), mesh_descriptor())[0]
+    return planned_fields(n, dtype, kind)[0]
 
 
 def planned_leaf_solver(n: int, block_size: int, dtype=jnp.float32,
                         kind: str = "inverse") -> str:
     """Leaf solver for a problem whose block grid is already fixed."""
-    from .cache import default_cache_path
-    from .plan import mesh_descriptor
-
-    return _planned_fields(kind, int(n), jnp.dtype(dtype).name,
-                           (int(block_size),), default_cache_path(),
-                           mesh_descriptor())[1]
+    return planned_fields(n, dtype, kind, block_size)[1]

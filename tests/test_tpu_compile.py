@@ -274,3 +274,26 @@ def test_pallas_recursion_names_its_kernels_by_step(one_chip, monkeypatch):
     kernels = [line.strip() for line in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     assert all(gemm.search(k) or leaf_marker.search(k) for k in kernels)
+
+
+def test_batched_pallas_refresh_compiles_and_fits_one_chip(one_chip,
+                                                           monkeypatch):
+    """The K-FAC refresh cell's program: Granite-8B-Code's (36, 4096, 4096)
+    factor stack through `spin_inverse_batched` at block 256, the Pallas
+    engine and leaf inside the scan. Its own buffers (the stack in, the
+    inverses out and the temporaries) fit a 16 GB chip."""
+    from repro.core.solve import _spin_inverse_batched
+    from repro.kernels.leaf_inverse import ops as leaf_ops
+    from repro.kernels.matmul import ops as mm_ops
+
+    monkeypatch.setattr(leaf_ops, "pallas_interpret_default", lambda: False)
+    monkeypatch.setattr(mm_ops, "pallas_interpret_default", lambda: False)
+    x = jax.ShapeDtypeStruct((36, 4096, 4096), jnp.float32, sharding=one_chip)
+    compiled = _spin_inverse_batched.lower(x, 256, "pallas",
+                                           "pallas").compile()
+    assert _kernel_names(compiled.as_text()) == {
+        "matmul_pallas", "schur_update_pallas", "blocked_leaf_inverse_pallas"}
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < 16e9, total
