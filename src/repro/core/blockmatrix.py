@@ -59,6 +59,9 @@ class OpCounts:
     # Pallas GEMM grid steps, (m/bm)·(n/bn)·(k/bk) summed over the
     # kernels.matmul calls (engine="pallas" and its solve panels only):
     pallas_grid_steps: int = 0
+    # Diagonal blocks the `pallas` leaf's blocked Gauss–Jordan kernel
+    # inverts, bs/t per block it inverts (kernels/leaf_inverse):
+    leaf_panels: int = 0
     # On a mesh (zero off one): bytes each device receives from the SUMMA
     # gathers, bs×bs GEMMs of products every device repeats (the grid no
     # longer divides the mesh), and leaf inversions (every device repeats

@@ -118,8 +118,9 @@ def assert_paper_op_counts(grid: int, counts: OpCounts) -> None:
 
     Engine-blind: the Strassen-internal counters are excluded here (a
     Strassen product is still ONE Algorithm-2 multiply) and checked by
-    their own oracle, `assert_strassen_op_counts`; so is the Pallas
-    kernels' grid-step count, which follows their tiles, and so are the
+    their own oracle, `assert_strassen_op_counts`; so are the Pallas
+    kernels' grid-step and leaf-panel counts, which follow their tiles
+    and panels, and so are the
     mesh counters (`gather_bytes`, `replicated_*`, `local_*`), which
     follow the placement.
     """
@@ -130,7 +131,7 @@ def assert_paper_op_counts(grid: int, counts: OpCounts) -> None:
         if k in got and got[k] != v
         and k not in ("leaf_lu", "leaf_solves", "solve_applies",
                       "strassen_base_multiplies", "strassen_adds",
-                      "pallas_grid_steps", "gather_bytes",
+                      "pallas_grid_steps", "leaf_panels", "gather_bytes",
                       "replicated_block_gemms", "replicated_leaves",
                       "local_splits", "local_arranges")
     }

@@ -16,13 +16,14 @@ block. SPIN's leaf has batch=1; the SPIN-Shampoo optimizer batches all layer
 factors through the same kernel.
 
 Two blocked variants ride alongside the scalar sweep (the `pallas` leaf
-solver / leaf-solve path): `blocked_leaf_inverse_pallas` runs the same GJ
-elimination panel-by-panel so all cross-panel work is rank-t MXU GEMMs, and
-`triangular_solve_pallas` is a blocked substitution for triangular (or
-packed-LU) systems — the multi-RHS leaf solve without materializing an
-inverse. Panel rows are addressed by slicing the VMEM *refs* at a dynamic
-sublane offset (`pl.ds`); Mosaic has no lowering for a dynamic slice of a
-loaded value.
+solver / leaf-solve path). `blocked_leaf_inverse_pallas` is block
+Gauss-Jordan: the sequential pivot steps run on each panel's t×t diagonal
+block alone, and everything else is static-slice MXU GEMMs (the panel
+loop is unrolled, so no lane offset is dynamic). `triangular_solve_pallas`
+is a blocked substitution for triangular (or packed-LU) systems — the
+multi-RHS leaf solve without materializing an inverse. Row chunks are
+addressed by slicing the VMEM *refs* at a dynamic sublane offset
+(`pl.ds`); Mosaic has no lowering for a dynamic slice of a loaded value.
 
 VMEM budget. A v5e TensorCore has 128 MiB of VMEM, but Mosaic scopes a
 kernel to 16 MiB unless the kernel asks for more. These kernels hold a
@@ -34,7 +35,7 @@ live temporaries), asks for that plus a quarter through
 
     kernel                       bs=512        bs=1024       bs=2048
     leaf_inverse_pallas          18 / 22.5     72 / 90       288: refused
-    blocked_leaf_inverse_pallas  10.3 / 12.8   32.5 / 40.6   113: refused
+    blocked_leaf_inverse_pallas  8.2 / 10.2    25.9 / 32.4   91.4 / 114.3
     triangular_solve (128 cols)  4.5 / 5.6     12.9 / 16.1   41.6 / 52.0
 
 `max_block_size` turns the same reckoning into the largest power-of-two
@@ -54,7 +55,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import VMEM_CAP_BYTES, vmem_compiler_params
 
 __all__ = ["leaf_inverse_pallas", "blocked_leaf_inverse_pallas",
-           "triangular_solve_pallas", "default_panel", "VMEM_CAP_BYTES",
+           "triangular_solve_pallas", "default_panel", "leaf_panel",
+           "VMEM_CAP_BYTES",
            "leaf_inverse_vmem_bytes", "blocked_leaf_inverse_vmem_bytes",
            "triangular_solve_vmem_bytes", "max_block_size"]
 
@@ -62,6 +64,7 @@ _HI = jax.lax.Precision.HIGHEST     # every dot here is f32 (one-hot gathers
                                     # must not round their operand to bf16)
 
 _ROW_CHUNK = 256                    # rows per rank-t update step
+LEAF_PANEL = 128                    # the blocked leaf's widest panel
 
 
 def leaf_inverse_vmem_bytes(bs: int, itemsize: int = 4) -> int:
@@ -72,14 +75,16 @@ def leaf_inverse_vmem_bytes(bs: int, itemsize: int = 4) -> int:
 
 
 def blocked_leaf_inverse_vmem_bytes(bs: int, itemsize: int = 4,
-                                    panel: int = 64) -> int:
-    """Blocked GJ: in/out blocks ×2 buffers, the (bs, 2bs) f32 scratch,
-    the panel and its masks (4 × (t, 2bs)), the one-hot gather (2bs, t),
-    and one row chunk's rank-t update (3 × (R, 2bs))."""
-    r = min(bs, _ROW_CHUNK)
-    return (4 * bs * bs * itemsize + 2 * bs * bs * 4
-            + 4 * panel * 2 * bs * 4 + 2 * bs * panel * 4
-            + 3 * r * 2 * bs * 4)
+                                    panel: int | None = None) -> int:
+    """Blocked GJ: in/out blocks ×2 buffers, the (bs, bs) f32 scratch, the
+    panel's (t, bs) planes (its rows, the first product, the residual and
+    its two products), the diagonal sweep's (t, t) planes (the block, its
+    inverse, two iotas, three masked temporaries), and one row chunk's
+    update (3 × (R, bs): the rows, the product and the result)."""
+    t = panel or leaf_panel(bs)
+    r = default_panel(bs, _ROW_CHUNK)
+    return (4 * bs * bs * itemsize + bs * bs * 4 + 5 * t * bs * 4
+            + 7 * t * t * 4 + 3 * r * bs * 4)
 
 
 def triangular_solve_vmem_bytes(bs: int, itemsize: int = 4,
@@ -180,6 +185,15 @@ def default_panel(bs: int, cap: int = 64) -> int:
     return t
 
 
+def leaf_panel(bs: int) -> int:
+    """Width t of the diagonal blocks `blocked_leaf_inverse_pallas`
+    inverts one at a time: the largest divisor of bs up to 64 for bs up
+    to 256, where the pivot steps dominate, else up to `LEAF_PANEL`,
+    where the GEMMs' depth does (v5e: 36 against 39 µs per leaf at 256,
+    247 against 311 at 1024)."""
+    return default_panel(bs, 64 if bs <= 256 else LEAF_PANEL)
+
+
 def _panel_gj(d: jax.Array, rest: jax.Array, offset) -> tuple[jax.Array,
                                                                 jax.Array]:
     """Unblocked GJ on the t rows of a panel [D | rest]: afterwards D's
@@ -208,58 +222,87 @@ def _panel_gj(d: jax.Array, rest: jax.Array, offset) -> tuple[jax.Array,
     return jax.lax.fori_loop(0, t, mini, (d, rest))
 
 
-def _blocked_gauss_jordan_kernel(a_ref, out_ref, m_ref, *, panel: int) -> None:
-    """Blocked GJ sweep over [A | I]: the scalar elimination of the unblocked
-    kernel runs only INSIDE a t-row panel; everything outside the panel is
-    eliminated with a rank-t update (`factors @ panel` — an MXU GEMM
-    instead of t vector steps), one row chunk at a time. Panel rows are
-    read and written through the scratch ref at a dynamic sublane offset;
-    panel *columns* are gathered by multiplying with a one-hot selector
-    E_p, so no lane-dim dynamic addressing exists.
+def _invert_diagonal(d: jax.Array) -> jax.Array:
+    """D⁻¹ of one (t, t) block by the pivot-free Gauss–Jordan sweep, in
+    place: step j divides row j by the pivot, leaves 1/pivot where the
+    pivot was and −factor/pivot in the rest of column j (the columns of
+    [D | I]'s right half that the sweep fills), so after t steps the block
+    holds D⁻¹. Every step is a masked row and column reduction and a
+    rank-1 update over the (t, t) block alone."""
+    t = d.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+
+    def step(j, d):
+        row_j = jnp.sum(jnp.where(row == j, d, 0.0), axis=0, keepdims=True)
+        piv = jnp.sum(jnp.where(col[:1] == j, row_j, 0.0), axis=1,
+                      keepdims=True)
+        row_n = jnp.where(col[:1] == j, 1.0, row_j) / piv          # (1, t)
+        col_j = jnp.sum(jnp.where(col == j, d, 0.0), axis=1, keepdims=True)
+        factors = jnp.where(row[:, :1] == j, 0.0, col_j)            # (t, 1)
+        d = jnp.where(col == j, 0.0, d) - factors * row_n
+        return jnp.where(row == j, row_n, d)
+
+    return jax.lax.fori_loop(0, t, step, d)
+
+
+def _blocked_gauss_jordan_kernel(a_ref, out_ref, w_ref, *, panel: int) -> None:
+    """Block Gauss–Jordan over [A | I], stored in place in one (bs, bs)
+    scratch W. The panel loop is unrolled, so every panel's columns are a
+    static slice. For panel rows and columns [b, b+t):
+
+    1. D⁻¹ of the diagonal block D = W[b:b+t, b:b+t]: the only sequential
+       part, t pivot steps over a (t, t) array (`_invert_diagonal`);
+    2. the normalised panel P = D⁻¹ · M, M the panel's rows with I_t in
+       place of D (the columns [A | I]'s right half holds there): one MXU
+       GEMM, and one step of refinement, P += D⁻¹ · (M − D · P), two more.
+       Without it the explicit D⁻¹ loses up to twenty times the scalar
+       sweep's accuracy on an ill-conditioned D;
+    3. every other row: W[R, :] −= W[R, b:b+t] · P, the panel's columns
+       cleared first (they end as −W[R, b:b+t] · D⁻¹): one MXU GEMM per
+       row chunk, the panel's own rows masked out.
+
+    In place, W's columns before the panel hold the inverse's columns
+    that [A | I]'s right half has filled and those after it what is left
+    of A: the columns of [A | I] that are identity, or still zero, are
+    never stored, and after the last panel W is A⁻¹. Row chunks are read
+    and written through the ref at a dynamic sublane offset (`pl.ds`).
     """
     bs = a_ref.shape[1]
     t = panel
     r = default_panel(bs, _ROW_CHUNK)
-    m_ref[:, :bs] = a_ref[0].astype(jnp.float32)
-    m_ref[:, bs:] = (jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
-                     == jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
-                     ).astype(jnp.float32)
-
-    e_rows = jax.lax.broadcasted_iota(jnp.int32, (2 * bs, t), 0)
-    e_cols = jax.lax.broadcasted_iota(jnp.int32, (2 * bs, t), 1)
+    w_ref[...] = a_ref[0].astype(jnp.float32)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
     chunk_rows = jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (t, t), 1)
+           ).astype(jnp.float32)
 
-    def panel_step(p, _):
-        base = pl.multiple_of(p * t, t)
-        left, right = _panel_gj(m_ref[pl.ds(base, t), :bs],
-                                m_ref[pl.ds(base, t), bs:], base)
-        # Rank-t elimination of columns [base, base+t) from every other
-        # row, chunk by chunk. E_p gathers those columns by matmul.
-        e = (e_rows == base + e_cols).astype(jnp.float32)
+    def dot(x, y):
+        return jnp.dot(x, y, precision=_HI, preferred_element_type=jnp.float32)
 
-        def chunk(c, _):
+    for b in range(0, bs, t):
+        d = w_ref[b:b + t, b:b + t]
+        dinv = _invert_diagonal(d)
+        w_ref[b:b + t, b:b + t] = eye
+        m = w_ref[b:b + t, :]
+        pan = dot(dinv, m)
+        pan = pan + dot(dinv, m - dot(d, pan))
+        w_ref[b:b + t, :] = pan
+        in_panel = (cols >= b) & (cols < b + t)
+
+        def chunk(c, _, b=b, pan=pan, in_panel=in_panel):
             row0 = pl.multiple_of(c * r, r)
-            rows = m_ref[pl.ds(row0, r), :]
-            factors = jnp.dot(rows, e, precision=_HI,
-                              preferred_element_type=jnp.float32)   # (r, t)
             ridx = row0 + chunk_rows
-            factors = jnp.where((ridx >= base) & (ridx < base + t), 0.0,
-                                factors)
-            m_ref[pl.ds(row0, r), :bs] = rows[:, :bs] - jnp.dot(
-                factors, left, precision=_HI,
-                preferred_element_type=jnp.float32)
-            m_ref[pl.ds(row0, r), bs:] = rows[:, bs:] - jnp.dot(
-                factors, right, precision=_HI,
-                preferred_element_type=jnp.float32)
+            other = (ridx < b) | (ridx >= b + t)
+            rows = w_ref[pl.ds(row0, r), :]
+            factors = jnp.where(other, w_ref[pl.ds(row0, r), b:b + t], 0.0)
+            w_ref[pl.ds(row0, r), :] = (jnp.where(in_panel & other, 0.0, rows)
+                                        - dot(factors, pan))
             return 0
 
         jax.lax.fori_loop(0, bs // r, chunk, 0)
-        m_ref[pl.ds(base, t), :bs] = left
-        m_ref[pl.ds(base, t), bs:] = right
-        return 0
-
-    jax.lax.fori_loop(0, bs // t, panel_step, 0)
-    out_ref[0] = m_ref[:, bs:].astype(out_ref.dtype)
+    out_ref[0] = w_ref[...].astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("panel", "interpret", "out_dtype"))
@@ -268,13 +311,15 @@ def blocked_leaf_inverse_pallas(blocks: jax.Array, panel: int | None = None,
                                 out_dtype=None) -> jax.Array:
     """Blocked-GJ inverse of a batch of blocks: (batch, bs, bs) -> same.
 
-    out_dtype (default: the blocks' dtype) is what the f32 panel sweep is
-    cast to on the final write, matching `leaf_inverse_pallas`.
+    panel (default `leaf_panel(bs)`) is the width t of the diagonal blocks
+    the sweep inverts one at a time. out_dtype (default: the blocks' dtype)
+    is what the f32 sweep is cast to on the final write, matching
+    `leaf_inverse_pallas`.
     """
     if blocks.ndim != 3 or blocks.shape[1] != blocks.shape[2]:
         raise ValueError(f"expected (batch, bs, bs), got {blocks.shape}")
     batch, bs, _ = blocks.shape
-    t = panel or default_panel(bs)
+    t = panel or leaf_panel(bs)
     if bs % t:
         raise ValueError(f"panel={t} must divide block size {bs}")
     out_dtype = out_dtype or blocks.dtype
@@ -285,7 +330,7 @@ def blocked_leaf_inverse_pallas(blocks: jax.Array, panel: int | None = None,
         in_specs=[pl.BlockSpec((1, bs, bs), lambda b: (b, 0, 0))],
         out_specs=pl.BlockSpec((1, bs, bs), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(blocks.shape, out_dtype),
-        scratch_shapes=[pltpu.VMEM((bs, 2 * bs), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bs, bs), jnp.float32)],
         compiler_params=vmem_compiler_params(
             blocked_leaf_inverse_vmem_bytes(bs, itemsize, t), ("parallel",)),
         interpret=interpret,

@@ -3,6 +3,10 @@
 Interpret mode is resolved through the package-wide policy
 (`repro.kernels.pallas_interpret_default`): compiled on TPU, interpreted
 elsewhere, overridable with ``SPIN_PALLAS_INTERPRET=1``.
+
+`batched_blocked_leaf_inverse` books the diagonal blocks its kernel
+inverts, bs/t per block, as `leaf_panels` in an active `count_ops`
+context (trace time only).
 """
 
 from __future__ import annotations
@@ -11,9 +15,11 @@ import functools
 
 import jax
 
+from repro.core.blockmatrix import _bump
+
 from .. import mesh_safe, pallas_interpret_default
 from .kernel import (blocked_leaf_inverse_pallas, leaf_inverse_pallas,
-                     triangular_solve_pallas)
+                     leaf_panel, triangular_solve_pallas)
 
 __all__ = ["leaf_inverse", "batched_leaf_inverse", "blocked_leaf_inverse",
            "batched_blocked_leaf_inverse", "triangular_solve"]
@@ -45,8 +51,10 @@ def blocked_leaf_inverse(block: jax.Array, panel: int | None = None,
 def batched_blocked_leaf_inverse(blocks: jax.Array, panel: int | None = None,
                                  out_dtype=None) -> jax.Array:
     """Blocked-GJ inverse of (batch, bs, bs) blocks."""
+    t = panel or leaf_panel(blocks.shape[-1])
+    _bump("leaf_panels", blocks.shape[0] * (blocks.shape[-1] // t))
     return mesh_safe(functools.partial(
-        blocked_leaf_inverse_pallas, panel=panel,
+        blocked_leaf_inverse_pallas, panel=t,
         interpret=pallas_interpret_default(), out_dtype=out_dtype))(blocks)
 
 

@@ -43,43 +43,42 @@ def gauss_jordan_ref(blocks: jax.Array) -> jax.Array:
 
 
 def blocked_gauss_jordan_ref(blocks: jax.Array, panel: int) -> jax.Array:
-    """Step-exact oracle for the BLOCKED GJ kernel: same panel mini-sweeps
-    and rank-t updates in pure jnp (same op order, so same rounding)."""
+    """Step-exact oracle for the BLOCKED GJ kernel, written as the textbook
+    block sweep over the whole augmented [A | I]: per panel, D⁻¹ of its
+    t×t diagonal block by the scalar sweep over [D | I_t], the normalised
+    panel P = D⁻¹ · M[panel, :] with one step of refinement, and
+    M[R, :] −= M[R, panel cols] · P for every other row. The kernel stores
+    [A | I] in place and skips its identity and zero columns; the
+    arithmetic on every entry it keeps is this one, so the two agree step
+    by step."""
+    def dot(x, y):
+        return jnp.dot(x, y, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+    def inv_diag(d: jax.Array) -> jax.Array:
+        t = d.shape[0]
+        m = jnp.concatenate([d, jnp.eye(t, dtype=jnp.float32)], axis=1)
+        rows_i = jax.lax.broadcasted_iota(jnp.int32, (t, 2 * t), 0)
+
+        def step(j, m):
+            row_n = m[j] / m[j, j]
+            factors = jnp.where(jnp.arange(t) == j, 0.0, m[:, j])
+            m = m - factors[:, None] * row_n[None, :]
+            return jnp.where(rows_i == j, row_n[None, :], m)
+
+        return jax.lax.fori_loop(0, t, step, m)[:, t:]
 
     def one(a: jax.Array) -> jax.Array:
         bs = a.shape[0]
         t = panel
         m = jnp.concatenate(
             [a.astype(jnp.float32), jnp.eye(bs, dtype=jnp.float32)], axis=1)
-        prow = jax.lax.broadcasted_iota(jnp.int32, (t, 2 * bs), 0)
-        pcol = jax.lax.broadcasted_iota(jnp.int32, (t, 2 * bs), 1)
-        e_rows = jax.lax.broadcasted_iota(jnp.int32, (2 * bs, t), 0)
-        e_cols = jax.lax.broadcasted_iota(jnp.int32, (2 * bs, t), 1)
-
-        def panel_step(p, m):
-            base = p * t
-            pan = jax.lax.dynamic_slice(m, (base, 0), (t, 2 * bs))
-
-            def mini(j, pan):
-                row_j = jnp.sum(jnp.where(prow == j, pan, 0.0), axis=0)
-                piv = jnp.sum(jnp.where(pcol[0] == base + j, row_j, 0.0))
-                row_n = row_j / piv
-                colv = jnp.sum(jnp.where(pcol == base + j, pan, 0.0), axis=1)
-                sel = jnp.arange(t) == j
-                factors = jnp.where(sel, 0.0, colv)
-                pan = pan - factors[:, None] * row_n[None, :]
-                return jnp.where(prow == j, row_n[None, :], pan)
-
-            pan = jax.lax.fori_loop(0, t, mini, pan)
-            e = (e_rows == base + e_cols).astype(jnp.float32)
-            factors = jnp.dot(m, e, preferred_element_type=jnp.float32)
-            ridx = jnp.arange(bs)
-            in_panel = (ridx >= base) & (ridx < base + t)
-            factors = jnp.where(in_panel[:, None], 0.0, factors)
-            m = m - jnp.dot(factors, pan, preferred_element_type=jnp.float32)
-            return jax.lax.dynamic_update_slice(m, pan, (base, 0))
-
-        m = jax.lax.fori_loop(0, bs // t, panel_step, m)
+        for b in range(0, bs, t):
+            d = m[b:b + t, b:b + t]
+            dinv = inv_diag(d)
+            pan = dot(dinv, m[b:b + t])
+            pan = pan + dot(dinv, m[b:b + t] - dot(d, pan))
+            m = (m - dot(m[:, b:b + t], pan)).at[b:b + t].set(pan)
         return m[:, bs:].astype(a.dtype)
 
     return jax.vmap(one)(blocks)

@@ -3,11 +3,13 @@
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core.testing import make_spd
-from repro.kernels.leaf_inverse import ops as gj_ops, ref as gj_ref
+from repro.kernels.leaf_inverse import (kernel as gj_kernel, ops as gj_ops,
+                                        ref as gj_ref)
 from repro.kernels.matmul import kernel as mm_kernel, ops as mm_ops, ref as mm_ref
 
 
@@ -222,20 +224,56 @@ def test_pallas_grid_steps_count_the_rule_over_a_traced_inverse():
     assert counts.pallas_grid_steps == want
 
 
+@pytest.mark.parametrize("leaf", ["pallas", "linalg"])
+def test_leaf_panels_count_bs_over_t_per_pallas_leaf(leaf):
+    """`leaf_panels` books bs/t diagonal blocks for every leaf the `pallas`
+    leaf's blocked kernel inverts and none for another solver; the paper's
+    op counts, which leave it out, still hold."""
+    from repro.core import BlockMatrix, count_ops, spin_inverse, verify
+
+    n, grid = 1024, 4
+    bs = n // grid
+    with count_ops() as counts:
+        jax.eval_shape(
+            lambda x: spin_inverse(BlockMatrix.from_dense(x, bs),
+                                   leaf_solver=leaf).blocks,
+            jax.ShapeDtypeStruct((n, n), jnp.float32))
+    per_leaf = bs // gj_kernel.leaf_panel(bs) if leaf == "pallas" else 0
+    assert counts.leaf_inversions == grid
+    assert counts.leaf_panels == grid * per_leaf
+    verify.assert_paper_op_counts(grid, counts)
+
+
 # ------------------------------------------------- blocked Gauss-Jordan
 
 
 @pytest.mark.parametrize("bs,panel", [(32, 8), (64, 16), (64, 64), (96, 32),
-                                      (128, 32)])
+                                      (128, 32), (128, 128), (256, 128),
+                                      (256, None)])
 def test_blocked_gauss_jordan_sweep(bs, panel):
-    a = make_spd(bs, jax.random.PRNGKey(bs + panel))
+    a = make_spd(bs, jax.random.PRNGKey(bs + (panel or 0)))
     got = gj_ops.blocked_leaf_inverse(a, panel=panel)
     want = gj_ref.leaf_inverse_ref(a[None])[0]
     rel = jnp.linalg.norm(got - want) / jnp.linalg.norm(want)
     assert float(rel) < 1e-4
     # step-exact against the pure-jnp twin of the same blocked elimination
-    twin = gj_ref.blocked_gauss_jordan_ref(a[None], panel)[0]
-    assert jnp.allclose(got, twin, atol=1e-6)
+    twin = gj_ref.blocked_gauss_jordan_ref(a[None],
+                                           panel or gj_kernel.leaf_panel(bs))
+    assert jnp.allclose(got, twin[0], atol=1e-6)
+
+
+def test_blocked_gauss_jordan_on_a_damped_low_rank_gram_block():
+    """A damped Gram block of rank bs/2 (condition about 1.1e3), the kind
+    of leaf the K-FAC refresh inverts, against a float64 inverse. The
+    kernel that inverted the whole panel rows by scalar pivot steps read
+    3.58e-5 here; the tolerance is about twice that."""
+    bs = 256
+    b = jax.random.normal(jax.random.PRNGKey(0), (bs, bs // 2))
+    g = jnp.matmul(b, b.T, precision=jax.lax.Precision.HIGHEST) / (bs // 2)
+    a = g + 5e-3 * jnp.trace(g) / bs * jnp.eye(bs)
+    want = np.linalg.inv(np.asarray(a, np.float64))
+    got = np.asarray(gj_ops.blocked_leaf_inverse(a), np.float64)
+    assert np.linalg.norm(got - want) / np.linalg.norm(want) < 7.5e-5
 
 
 def test_blocked_gauss_jordan_batched_and_panel_validation():

@@ -100,9 +100,21 @@ def test_gemm_kernels_compile_with_their_default_tiles(fn, n, dtype, name,
 ])
 def test_leaf_kernel_compiles_at_planner_max(kernel, fn, one_chip):
     bs = leaf.max_block_size(kernel)
-    assert bs >= 512
+    assert bs >= 1024
     text = _compiled_text(fn, (1, bs, bs), sharding=one_chip)
     assert "tpu_custom_call" in text
+
+
+def test_blocked_leaf_is_one_kernel_at_the_refresh_block(one_chip):
+    """At the K-FAC refresh cell's block, 256, the unrolled panel sweep
+    compiles to one Mosaic kernel per leaf, under the name the benchmark's
+    leaf marker matches."""
+    text = _compiled_text(leaf.blocked_leaf_inverse_pallas, (1, 256, 256),
+                          sharding=one_chip)
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1
+    assert _kernel_names(text) == {"blocked_leaf_inverse_pallas"}
 
 
 def test_triangular_solve_compiles(one_chip):
